@@ -41,21 +41,11 @@ def _ensure_devices() -> None:
     with 8 virtual devices. A backend someone else already initialized
     (pytest's conftest, a trainer in the same process) is left alone —
     contracts adapt to whatever mesh exists and budgets are maxima."""
-    import sys
-
-    if "jax" in sys.modules:
-        import jax
-        try:
-            if getattr(jax._src.xla_bridge, "_backends", None):
-                return     # initialized; reconfiguring now would fail
-        except Exception:  # noqa: BLE001 - private API moved: just pin env
-            pass
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count"
-                    f"={ANALYSIS_DEVICE_COUNT}")
+    import jax
+    if jax._src.xla_bridge._backends:
+        return     # initialized; reconfiguring now would fail
+    jax.config.update("jax_num_cpu_devices", ANALYSIS_DEVICE_COUNT)
 
 
 def _suppression_module(path: str, root: str) -> Optional[Module]:

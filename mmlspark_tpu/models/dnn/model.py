@@ -69,7 +69,6 @@ class DNNModel(Model):
 
     def _set_state(self, s):
         import jax
-        import jax.export  # module import: not a lazy attr on older jax
         n = int(np.asarray(s.get("n_leaves", 0)))
         if n:
             leaves = [np.asarray(s[f"leaf_{i}"]) for i in range(n)]
@@ -87,7 +86,6 @@ class DNNModel(Model):
         artifact (jax.export) — the deep-net graph as bytes, like the
         reference ships CNTK protobufs."""
         import jax
-        import jax.export  # module import: not a lazy attr on older jax
         import jax.numpy as jnp
         if self._apply_fn is None:
             raise ValueError("no apply_fn to export")

@@ -25,9 +25,8 @@ from typing import Optional
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ...parallel.shard import shard_map
 
 from ...parallel import DATA_AXIS, data_mesh, pad_to_multiple
 from . import trainer
@@ -68,7 +67,7 @@ def _compiled_tree_fn(mesh, cfg, voting: Optional[int]):
                   P(DATA_AXIS)),
         out_specs=(trainer.Tree(P(), P(), P(), P(), P(), P(), P()),
                    P(DATA_AXIS)),
-        check_rep=False)
+        check_vma=False)
     mode = "voting_parallel" if voting is not None else "data_parallel"
     # fingerprint carries the builder key: a DIFFERENT cfg compiling at
     # the same shapes is a new executable, not a recompile of this one
@@ -108,7 +107,7 @@ def _compiled_chunk_fn(mesh, p, cfg, chunk_len: int, k_out: int,
                   P(DATA_AXIS), margin_spec, margin_spec, P(), P(), P(), P(),
                   P()),
         out_specs=(margin_spec, P(), P(), P(), P(), P(), P(), P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
     # same AOT-through-the-compile-log treatment as the tree grower (see
     # _compiled_tree_fn): the fused chunk's collectives become records
     from ...telemetry.perf import AotCache

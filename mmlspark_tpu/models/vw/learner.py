@@ -278,7 +278,7 @@ def fit_vw(idx: np.ndarray, val: np.ndarray, y: np.ndarray,
             y_p, _ = pad_to_multiple(np.asarray(y, np.float32), nsh)
             wr_p, _ = pad_to_multiple(w_row, nsh)  # pad weight 0 -> no loss
             from jax.sharding import PartitionSpec as P
-            from ...parallel.shard import shard_map as _smap
+            from jax import shard_map as _smap
 
             def local_fit(li, lv, ly, lw):
                 bi, bv, by, bw, nb_l = _jitless_batches(li, lv, ly, lw,
@@ -290,7 +290,7 @@ def fit_vw(idx: np.ndarray, val: np.ndarray, y: np.ndarray,
                 local_fit, mesh=mesh,
                 in_specs=(P(DATA_AXIS, None), P(DATA_AXIS, None),
                           P(DATA_AXIS), P(DATA_AXIS)),
-                out_specs=(P(), P(), P()), check_rep=False)
+                out_specs=(P(), P(), P()), check_vma=False)
             w_out, b_out, losses = jax.jit(mapped)(
                 jnp.asarray(idx_p), jnp.asarray(val_p), jnp.asarray(y_p),
                 jnp.asarray(wr_p))

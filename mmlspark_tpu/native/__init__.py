@@ -3,12 +3,16 @@
 Role-equivalent to the reference's native host layer (SURVEY.md §2.9 item 6 —
 LightGBM's C++ dataset construction). The shared library is compiled from
 kernels.cpp with the system toolchain on first use and cached next to the
-package; every entry point has a pure-Python fallback so the framework works
-without a compiler (`available()` reports which path is active).
+package under a name keyed on the CONTENT of kernels.cpp — a copied checkout
+does not keep mtimes honest, and a library built from another source text
+must never be loaded. Every entry point has a pure-Python fallback so the
+framework works without a compiler (`available()` reports which path is
+active).
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -21,22 +25,35 @@ _HERE = os.path.dirname(__file__)
 # meta-test) import every module in package dirs, and a raw shared object is
 # not a CPython extension module
 _BUILD_DIR = os.path.join(_HERE, "build")
-_SO_PATH = os.path.join(_BUILD_DIR, "_native.so")
+_SRC = os.path.join(_HERE, "kernels.cpp")
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"_native-{digest}.so")
+
+
+def _build(so_path: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    src = os.path.join(_HERE, "kernels.cpp")
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", src,
-           "-o", _SO_PATH]
+    # compile to a per-process name, then rename: spawned ingest workers
+    # may race this build, and a half-written library must never load
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-        return res.returncode == 0
+        if res.returncode != 0:
+            return False
+        os.replace(tmp, so_path)
+        return True
     except (OSError, subprocess.TimeoutExpired):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _load():
@@ -44,14 +61,12 @@ def _load():
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_SO_PATH) or (
-                os.path.getmtime(_SO_PATH)
-                < os.path.getmtime(os.path.join(_HERE, "kernels.cpp"))):
-            if not _build():
-                _build_failed = True
-                return None
+        so_path = _so_path()
+        if not os.path.exists(so_path) and not _build(so_path):
+            _build_failed = True
+            return None
         try:
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(so_path)
         except OSError:
             _build_failed = True
             return None

@@ -29,7 +29,24 @@ gradients and OOMs. Perf notes: per-grid-cell overhead dominates below
 dtype. `flash_attention_stats`' VJP is ALSO flash (the same two kernels
 with lse := m and dsum := -dl — see _flash_stats_bwd's shift-invariance
 derivation), so context-parallel ring training is O(block) memory in
-both directions.
+both directions. (The timings above are round-5 history: an older JAX and
+device path. PERF.md carries what has been measured since.)
+
+PRECISION CONTRACT. Softmax statistics and every accumulation are f32.
+The matmul PRODUCTS follow the input dtype (`_mxu_dot`): bf16 q/k/v take
+the MXU's single bf16 pass; f32 q/k/v ask Mosaic for its fp32 contract
+precision (`Precision.HIGHEST`), because at the default Mosaic rounds f32
+operands to one bf16 pass and an f32 caller would get bf16 products
+unasked (f32 rows then read 2.4e-3 to 6.4e-3 on the chip, the bf16 band).
+Against `reference_attention` at
+`jax.default_matmul_precision("highest")` on the chip (PR 21, TPU v5 lite,
+S=2048, causal, max error over max |reference|): bf16 forward 7e-4 to
+2.4e-3, gradients 3e-3 to 6e-3; f32 forward 1.3e-7 to 2.4e-7, gradients
+3e-7 to 5e-5 at every block size from 256 to 1024. The f32 products cost
+about 3.5x the single pass (8192 x 8 x 128 causal, one run of 10 reps:
+forward 5.7 vs 1.7 ms, forward+backward 23.9 vs 6.9 ms; bf16 1.3 / 5.0
+ms), so a trainer that wants the MXU's rate says compute_dtype="bfloat16".
+In interpret mode (off-TPU) f32 is exact to ~1e-6, as on the chip.
 """
 from __future__ import annotations
 
@@ -41,12 +58,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams/TPUMemorySpace to CompilerParams/MemorySpace;
-# resolve whichever this jax ships so both sides of the rename run
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-_MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
 
 BLOCK_Q = 256
 BLOCK_K = 256
@@ -82,6 +93,21 @@ def _auto_blocks(seq_q: int, seq_k: int, dtype) -> tuple:
     bwd_cap = (_BWD_BLOCK_BF16 if jnp.dtype(dtype) == jnp.bfloat16
                else _BWD_BLOCK_F32)
     return bq, bk, min(bq, bwd_cap), min(bk, bwd_cap)
+
+
+def _mxu_dot(a, b, contract, exact: bool):
+    """In-kernel matmul with f32 accumulation, contracting dimension
+    `contract[0]` of `a` with `contract[1]` of `b`. `exact` is "the
+    kernel's q/k/v are f32": those dots ask for Precision.HIGHEST
+    (Mosaic's fp32 contract precision), because at the default Mosaic
+    rounds f32 operands to ONE bf16 pass and an f32 caller would silently
+    get bf16 products. bf16 kernels keep the default single pass for every
+    dot, the f32-cotangent ones of the stats backward included (see
+    PRECISION CONTRACT in the module docstring)."""
+    return jax.lax.dot_general(
+        a, b, (((contract[0],), (contract[1],)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST if exact else None,
+        preferred_element_type=jnp.float32)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -122,8 +148,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)   # (Bq, D)
         k = k_ref[0]                                     # (Bk, D)
         v = v_ref[0]                                     # (Bk, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        exact = q_ref.dtype == jnp.float32
+        s = _mxu_dot(q, k, (1, 1), exact)
         if masked:
             # sublane/lane iotas broadcast in the compare: no (Bq, Bk)
             # iota materialization
@@ -154,9 +180,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         alpha = jnp.exp(m_prev - m_new)               # rescale old carry
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[...] = (acc_ref[...] * alpha
-                        + jax.lax.dot_general(
-                            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+                        + _mxu_dot(p.astype(v.dtype), v, (1, 0), exact))
         m_ref[...] = m_new
         l_ref[...] = l_new
 
@@ -202,7 +226,7 @@ _COMPILER_PARAMS = None
 def _compiler_params():
     global _COMPILER_PARAMS
     if _COMPILER_PARAMS is None:
-        _COMPILER_PARAMS = _CompilerParams(
+        _COMPILER_PARAMS = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return _COMPILER_PARAMS
 
@@ -374,8 +398,8 @@ def _flash_stats_forward(q, k, v, q_offset, k_offset, causal, scale,
         kernel,
         grid=(h, n_q, n_k),
         in_specs=[
-            pl.BlockSpec(memory_space=_MemorySpace.SMEM),
-            pl.BlockSpec(memory_space=_MemorySpace.SMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM),
             pl.BlockSpec((1, block_q, d), lambda hh, qb, kb: (hh, qb, 0)),
             pl.BlockSpec((1, block_k, d), lambda hh, qb, kb: (hh, kb, 0)),
             pl.BlockSpec((1, block_k, d), lambda hh, qb, kb: (hh, kb, 0)),
@@ -456,8 +480,8 @@ def _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, qb, kb, *,
     k = k_ref[0]
     v = v_ref[0]
     do = do_ref[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    exact = q_ref.dtype == jnp.float32
+    s = _mxu_dot(q, k, (1, 1), exact)
     if masked:
         q_pos = q_offset + qb * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, 1), 0)
@@ -469,8 +493,7 @@ def _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, qb, kb, *,
         s = jnp.where(valid, s, -1e30)
     # padded q rows carry lse=+inf (set by the caller) -> p exactly 0
     p = jnp.exp(s - lse_ref[0])                       # (Bq, Bk)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    dp = _mxu_dot(do, v, (1, 1), exact)
     ds = p * (dp - dsum_ref[0])                       # (Bq, Bk)
     return p, ds, do
 
@@ -493,9 +516,8 @@ def _flash_bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                                k_end=koff + k_end, q_offset=qoff,
                                k_offset=koff, masked=masked)
         k = k_ref[0]
-        acc_ref[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[...] += _mxu_dot(ds.astype(k.dtype), k, (1, 0),
+                                 k.dtype == jnp.float32)
 
     full = _bwd_full_t(qb, kb, block_q, block_k, causal, k_end, qoff, koff)
     visible = _bwd_visible_t(qb, kb, block_q, block_k, causal, qoff, koff)
@@ -532,12 +554,9 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, k_ref, v_ref, q_ref, do_ref,
                                 k_end=koff + k_end, q_offset=qoff,
                                 k_offset=koff, masked=masked)
         q = q_ref[0]
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        exact = q.dtype == jnp.float32
+        dv_acc[...] += _mxu_dot(p.astype(do.dtype), do, (0, 0), exact)
+        dk_acc[...] += _mxu_dot(ds.astype(q.dtype), q, (0, 0), exact)
 
     full = _bwd_full_t(qb, kb, block_q, block_k, causal, k_end, qoff, koff)
     visible = _bwd_visible_t(qb, kb, block_q, block_k, causal, qoff, koff)
@@ -613,7 +632,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                     constant_values=jnp.inf) if pad_q else lse
     qoff_arr = jnp.asarray(q_offset, jnp.int32).reshape(1)
     koff_arr = jnp.asarray(k_offset, jnp.int32).reshape(1)
-    smem = pl.BlockSpec(memory_space=_MemorySpace.SMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.MemorySpace.SMEM)
 
     row_spec_q = pl.BlockSpec((1, block_q, d), lambda hh, qb, kb: (hh, qb, 0))
     col_spec_k = pl.BlockSpec((1, block_k, d), lambda hh, qb, kb: (hh, kb, 0))

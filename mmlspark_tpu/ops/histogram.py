@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from ..reliability.metrics import reliability_metrics
 from ..telemetry import names as tnames
+from .histogram_pallas import M_MAX, kernel_route, pallas_hist
 
 
 def _xla_hist(bins, grad, hess, node_local, active, n_nodes: int, n_bins: int,
@@ -72,19 +73,8 @@ def node_feature_histograms(bins, grad, hess, node_local, active,
     planes (histogram_pallas.build_hist_plan) — routes shallow levels
     through the plane-streaming kernel when present."""
     impl = os.environ.get("MMLSPARK_TPU_HIST", "auto")
-    use_pallas = (impl in ("pallas", "planes")
-                  or (impl == "auto" and _should_use_pallas(n_nodes)))
-    if use_pallas:
-        try:
-            from .histogram_pallas import kernel_route, pallas_hist
-        except ImportError as e:
-            if impl in ("pallas", "planes"):
-                raise NotImplementedError(
-                    f"MMLSPARK_TPU_HIST={impl} requested but the Pallas "
-                    "histogram kernel failed to import; unset the env var to "
-                    "use the XLA scatter path") from e
-            use_pallas = False
-    if use_pallas:
+    if impl in ("pallas", "planes") or (impl == "auto"
+                                        and _should_use_pallas(n_nodes)):
         has_planes = lo_planes is not None and plane_lo > 0
         kind, _lo = kernel_route(n_nodes, n_bins, has_planes=has_planes)
         # trace-time routing record: one count per compiled (m, B) kernel
@@ -106,18 +96,9 @@ def node_feature_histograms(bins, grad, hess, node_local, active,
 
 def _should_use_pallas(n_nodes: int) -> bool:
     """Pallas matmul-histogram on TPU (the XLA scatter is serialized there);
-    the node-onehot trick is VMEM-bounded, so very deep levels fall back."""
-    try:
-        from .histogram_pallas import M_MAX
-    except ImportError:
-        return False
-    if n_nodes > M_MAX:
-        return False
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    the node-onehot trick is VMEM-bounded, so levels past M_MAX nodes take
+    the scatter (counted as `gbdt.hist.route.xla`, never silent)."""
+    return n_nodes <= M_MAX and jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------- semantic contract

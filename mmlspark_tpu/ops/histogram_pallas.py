@@ -36,22 +36,29 @@ for a (mB/LO, T) hi build plus a 3 x (mB/LO) x T stat lift. The per-
 (feature, tile) VPU-unit model is ~(2*LO + 5*mB/LO), minimized near
 LO = sqrt(2.5*mB) — hardware-friendly LO comes from the table below.
 
-    measured on v5e, 1M x 128 x 256, ms/call (rounds 4-5, 10-rep steady):
+    measured on v5e, 1M x 128 x 256, ms/call (rounds 4-5, 10-rep steady;
+    an older kernel, JAX and device path — history, not a current figure):
     m        1      2      4      8      16     (32+)
     direct   26.8   26.8   26.8   26.8   26.8   26.8
     joint64  12.0   11.7   13.6   25.6   42.4
     joint128 16.5   17.2   21.8   17.8   23.1
 
     routing table (kernel_route): per (m, B) -> LO, None = direct
-    B >= 128 (measured):   m <= 4 -> 64;  m in (4, 16] -> 128; else direct
-    64 <= B < 128 (analytic, round 6 — BENCH_MODE=hist measures the
-    grid so the next round can pin measured values):
-                           m <= 2 -> 16;  m in (2, 4]  -> 32;  else direct
+    B >= 128 (from the table above): m <= 4 -> 64;  m in (4, 16] -> 128;
+                                     else direct
+    64 <= B < 128 (analytic):        m <= 2 -> 16;  m in (2, 4]  -> 32;
+                                     else direct
     B < 64: direct (the bin one-hot is already small next to the lift).
-    MMLSPARK_TPU_HIST_JOINT64=0 disables every narrow-lane (LO < 64)
-    route — the B < 128 joint rows AND the LO=16/32 planes rows — falling
-    back to direct: the escape hatch if Mosaic rejects those layouts on
-    some TPU generation.
+
+    COMPILES ON THE CHIP (PR 21: TPU v5 lite, jax 0.9.0, libtpu 0.0.34).
+    chip_smoke.py's kernel phase compiles every row this table selects
+    for B in {64, 255} x m in {1..64} with Mosaic and holds each inside
+    the bf16 contract below against the XLA scatter — including the
+    narrow-lane LO=16/32 joint layouts at B=64 and the direct kernel at
+    m=64, B=255 under the default scoped-VMEM limit. No row has been
+    TIMED since the kernel was rewritten: which LO wins where is still the
+    old measurement (B >= 128) or the analytic model (B < 128), and
+    BENCH_MODE=hist is the grid that re-measures it.
 
 LEVEL-INVARIANT ONE-HOT REUSE (round 6). The lo digit of the joint key is
 bin % LO whenever LO divides B — independent of the node assignment, i.e.
@@ -61,13 +68,16 @@ resident in HBM; `_hist_kernel_planes` streams them straight into the MXU
 (one int8->bf16 convert per element instead of compare+select+convert),
 leaving only the hi digit (mB/LO rows) built per level. Per-element VPU
 model ~(LO + 5*mB/LO); HBM traffic grows to F*n*(1+LO) bytes per level —
-this deliberately spends the ~50x memory headroom (hbm_utilization 0.018
-at round 5) to buy VPU time. Planes require LO | B (plan_lo_bins), so the
-wide 255-bin shape cannot take this route. Opt-in via
-MMLSPARK_TPU_HIST=planes until the v5e A/B (emitted by bench.py into
-BENCH_EXTRA_r06.json) proves a win: the analytic model puts planes within
-~10-20% of the computed joint at the 8M x 32 x 64 headline because the
-VPU saving is partially repaid as plane streaming (4 GB/level at LO=16).
+this deliberately spends memory headroom to buy VPU time. Planes require
+LO | B (plan_lo_bins) and exist at LO=16 only, i.e. for 64 <= B < 128: the
+LO=64 plane block that B >= 128 would need — (32, 64, 4096) int8, double-
+buffered — exhausts v5e's scoped VMEM at compile time (PR 21 chip run), so
+those shapes take the computed joint route with or without a plan. The
+LO=16 planes kernel compiles and matches the scatter on the chip. Opt-in
+via MMLSPARK_TPU_HIST=planes until a chip A/B (bench.py emits it) proves a
+win: the analytic model puts planes within ~10-20% of the computed joint
+at 8M x 32 x 64 because the VPU saving is partially repaid as plane
+streaming (4 GB/level at LO=16).
 
 Measured-and-REJECTED ledger (rounds 3-6):
 - separate-node factored radix (round 4, b = hi*LO + lo with a 3m-row
@@ -101,21 +111,16 @@ Measured-and-REJECTED ledger (rounds 3-6):
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; run on both sides
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-# tile sweep on v5e (1M-4M rows x 32 features x 64 bins): 8192/32 is ~5%
-# faster than 4096/16; the VMEM worst case (m = M_MAX = 64 nodes with 256
-# bins: 3x(32,64,256) f32 outputs + (256,8192) bf16 bin one-hot +
-# (192,8192) bf16 stat rows) verified to compile and run on v5e
+# tile sweep on v5e (rounds 4-5): 8192/32 was ~5% faster than 4096/16. The
+# VMEM worst case (m = M_MAX = 64 nodes with 255 bins: 3x(32,64,255) f32
+# outputs + (255,8192) bf16 bin one-hot + (192,8192) bf16 stat rows)
+# compiles and runs on v5e under the default scoped-VMEM limit (PR 21)
 TILE_ROWS = 8192
 FEATURE_BLOCK = 32
 M_MAX = 64  # max nodes per level handled here (VMEM bound on the 3m columns)
@@ -125,25 +130,20 @@ JOINT_M_MAX = 16      # beyond this the hi one-hot outgrows the saving
 
 # precomputed-plane route: (FEATURE_BLOCK, LO, T) int8 blocks are double-
 # buffered by the pallas pipeline, so the plane route halves the row tile
-# to keep 2 x FEATURE_BLOCK x LO x T int8 inside the VMEM budget
+# to keep 2 x FEATURE_BLOCK x LO x T int8 (4 MiB at LO=16) inside VMEM
 PLANES_TILE_ROWS = 4096
 PLANES_M_MAX = 4      # deeper levels: the hi lift dominates, direct wins
-
-
-def _env_joint64_enabled() -> bool:
-    return os.environ.get("MMLSPARK_TPU_HIST_JOINT64", "1") != "0"
 
 
 def plan_lo_bins(n_bins: int) -> int:
     """LO digit width for the precomputed-plane route (0 = unavailable).
     Planes need LO | B so that (node*B + bin) % LO == bin % LO is level-
-    invariant — non-divisible bin counts (e.g. 255) cannot take the
-    route — and LO < B (LO == B is the rejected full-plane form). B >= 128
-    pairs with LO=64 (the measured joint64's digit); 64 <= B < 128 with
-    LO=16 (the analytic optimum at the shallow m the route covers)."""
-    if n_bins >= 128:
-        return 64 if n_bins % 64 == 0 else 0
-    if n_bins >= JOINT_MIN_BINS and n_bins % 16 == 0:
+    invariant — non-divisible bin counts cannot take the route — and
+    LO < B (LO == B is the rejected full-plane form). 64 <= B < 128 pairs
+    with LO=16 (the analytic optimum at the shallow m the route covers);
+    B >= 128 has no plane digit: its LO=64 plane block does not fit
+    scoped VMEM (see the module docstring)."""
+    if JOINT_MIN_BINS <= n_bins < 128 and n_bins % 16 == 0:
         return 16
     return 0
 
@@ -157,14 +157,11 @@ def kernel_route(n_nodes: int, n_bins: int, has_planes: bool = False):
     level-invariant lo one-hot planes (build_hist_plan)."""
     if has_planes and n_nodes <= PLANES_M_MAX:
         lo = plan_lo_bins(n_bins)
-        # the narrow-lane escape hatch covers planes too: LO=16/32 plane
-        # blocks use the same unproven lane widths as the B<128 joint rows
-        if lo and (lo >= 64 or _env_joint64_enabled()):
+        if lo:
             return ("planes", lo)
     if n_bins >= 128 and n_nodes <= JOINT_M_MAX:
         return ("joint", 64 if n_nodes <= 4 else 128)
-    if 128 > n_bins >= JOINT_MIN_BINS and n_nodes <= 4 \
-            and _env_joint64_enabled():
+    if 128 > n_bins >= JOINT_MIN_BINS and n_nodes <= 4:
         return ("joint", 16 if n_nodes <= 2 else 32)
     return ("direct", n_bins)
 
@@ -380,7 +377,7 @@ def pallas_hist(bins, grad, hess, node_local, active, n_nodes: int,
         pl.BlockSpec((FEATURE_BLOCK, tile_rows), lambda fb, t: (fb, t)),
         row_spec, row_spec, row_spec, row_spec,
     ]
-    cparams = _CompilerParams(
+    cparams = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))
 
     if kind == "planes":

@@ -6,7 +6,6 @@ from .mesh import (DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS,
                    data_mesh, grid_mesh,
                    full_mesh, row_sharding, replicated, pad_to_multiple,
                    shard_rows, valid_row_mask, device_count)
-from .shard import shard_map
 
 __all__ = ["DATA_AXIS", "MODEL_AXIS", "PIPE_AXIS", "SEQ_AXIS",
            "ClusterInfo", "Heartbeat", "barrier",
@@ -14,5 +13,4 @@ __all__ = ["DATA_AXIS", "MODEL_AXIS", "PIPE_AXIS", "SEQ_AXIS",
            "full_mesh", "global_array", "initialize_cluster",
            "pad_to_multiple", "padded_process_rows", "process_row_range",
            "replicated",
-           "row_sharding", "shard_rows", "valid_row_mask", "device_count",
-           "shard_map"]
+           "row_sharding", "shard_rows", "valid_row_mask", "device_count"]

@@ -28,10 +28,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from . import DATA_AXIS
-from .shard import shard_map  # version-tolerant wrapper
-from jax.sharding import PartitionSpec as P
 
 
 def _block_attend(q, k, v, mask):
@@ -145,7 +145,7 @@ def ring_attention(q, k, v, mesh=None, axis: str = DATA_AXIS,
                            block_impl=block_impl)
     mapped = shard_map(fn, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis)),
-                       out_specs=P(axis), check_rep=False)
+                       out_specs=P(axis), check_vma=False)
     return jax.jit(mapped)(q, k, v)
 
 
@@ -199,7 +199,7 @@ def ulysses_attention(q, k, v, mesh=None, axis: str = DATA_AXIS,
                            scale=scale, n_dev=n_dev)
     mapped = shard_map(fn, mesh=mesh,
                        in_specs=(P(axis), P(axis), P(axis)),
-                       out_specs=P(axis), check_rep=False)
+                       out_specs=P(axis), check_vma=False)
     return jax.jit(mapped)(q, k, v)
 
 

@@ -46,20 +46,20 @@ a regression even if raw req/s improved).
 
 Backend gating (round 11): records carry a ``backend`` annotation (from
 the record itself, or a round file's top-level ``backend`` declaration —
-bench.py stamps ``jax.default_backend()``); records measured on a
+bench.py stamps the device's platform); records measured on a
 non-TPU backend are excluded from both trajectories and gates and
-reported as excluded — BENCH_EXTRA_r06 is CPU-only (route fallback
-``xla``) and must not read as a perf datapoint. BENCH_EXTRA-style
-artifacts (records nested as top-level values) are harvested too.
+reported as excluded — a CPU run must not read as a perf datapoint.
+Extras-style artifacts (a JSON object whose top-level values are whole
+records, or lists of them) are harvested too.
 
-It also reads the ``MULTICHIP_r0N.json`` wrapper format (a driver
+It also reads the multichip wrapper format (a driver ``{"n", "tail"}``
 object whose ``tail`` holds ``GPIPE_MSWEEP {json}`` / ``TRAFFIC
 {json}`` lines): the GPipe microbatch sweep becomes
 ``gpipe_m<M>_{s_per_step,bubble_fraction}`` records and the collective
 account becomes ``comm.<program>.<kind>.{ops,bytes}`` records — all
 marked lower-is-better on the record itself (``"lower_better": true``),
 so bubble-fraction and collective-bytes trajectories gate exactly like
-BENCH_rNN metrics:
+headline metrics:
 
     python -m mmlspark_tpu.telemetry.benchdiff --threshold 0.1 \\
         MULTICHIP_r*.json

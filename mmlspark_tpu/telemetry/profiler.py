@@ -3,8 +3,8 @@ parse, and per-region roofline attribution.
 
 ROADMAP item 1 made `hbm_utilization` the honesty metric of the
 histogram roofline chase, but the tree could only compute it for the
-WHOLE fit — 1.8% at BENCH_r05 with nothing able to say which op burns
-the other 98%. This module is the fourth observability tier
+WHOLE fit — a percent or two, with nothing able to say which op burns
+the rest. This module is the fourth observability tier
 (docs/observability.md "Device profiling & roofline"): the sensors that
 turn "the fit is memory-idle" into "gbdt.hist achieves X% of peak HBM
 and gbdt.route none of it" — the per-op (cost-analysis, measured-time)
@@ -115,7 +115,7 @@ def peak_hbm_from_env() -> Optional[float]:
     return gbps * 1e9 if gbps > 0 else None
 
 
-def _chip_peaks() -> Optional[tuple]:
+def chip_peaks() -> Optional[tuple]:
     """(flops_per_s, hbm_bytes_per_s, kind) from the local device kind —
     only consulted when jax is ALREADY imported (a passive read must
     never pay a cold jax import), and only for kinds in CHIP_PEAKS."""
@@ -156,7 +156,7 @@ def resolve_peaks(peaks: Optional[dict] = None) -> dict:
         out["hbm_bytes_per_s"] = env_hbm
         out["source"] = out["source"] or "env"
     if out["flops_per_s"] is None or out["hbm_bytes_per_s"] is None:
-        chip = _chip_peaks()
+        chip = chip_peaks()
         if chip is not None:
             if out["flops_per_s"] is None:
                 out["flops_per_s"] = chip[0]

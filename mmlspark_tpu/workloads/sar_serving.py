@@ -65,7 +65,7 @@ def _compiled_recommend_fn(mesh, n_items_pad: int, k: int):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.shard import shard_map
+    from jax import shard_map
     from ..telemetry.perf import AotCache
 
     def fn(a, s, pen):
@@ -78,7 +78,7 @@ def _compiled_recommend_fn(mesh, n_items_pad: int, k: int):
                        in_specs=(P(None, DATA_AXIS), P(DATA_AXIS, None),
                                  P(None, None)),
                        out_specs=(P(None, None), P(None, None)),
-                       check_rep=False)
+                       check_vma=False)
     return AotCache(
         mapped, label="workloads.sar.recommend",
         fingerprint="workloads.sar.recommend#"

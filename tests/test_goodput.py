@@ -347,12 +347,12 @@ def test_aot_cache_records_collectives_once_per_signature():
         pytest.skip("collective recording needs a multi-device mesh")
     from jax.sharding import PartitionSpec as P
     from mmlspark_tpu.parallel import DATA_AXIS, data_mesh
-    from mmlspark_tpu.parallel.shard import shard_map
+    from jax import shard_map
 
     mesh = data_mesh()
     mapped = shard_map(lambda x: jax.lax.psum(x, DATA_AXIS), mesh=mesh,
                        in_specs=(P(DATA_AXIS),), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
     reg = MetricsRegistry()
     log = tperf.CompileLog(registry=reg)
     cache = tperf.AotCache(mapped, label="test.psum", log=log)

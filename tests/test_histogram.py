@@ -63,9 +63,9 @@ def test_every_route_matches_xla_with_count_w(route):
     _assert_parity(a, p, tag=f"{route} ")
 
 
+# (1500, 3, 4, 128) went with the LO=64 plane route (plan_lo_bins)
 @pytest.mark.parametrize("n,f,m,b", [(3000, 5, 1, 64), (2500, 4, 2, 64),
-                                     (2000, 6, 4, 64), (1500, 3, 4, 128),
-                                     (900, 3, 2, 96)])
+                                     (2000, 6, 4, 64), (900, 3, 2, 96)])
 def test_planes_route_matches_xla(n, f, m, b):
     """Precomputed level-invariant plane route: build_hist_plan once, then
     parity against the scatter path — incl. the padded-row edge (n is
@@ -173,28 +173,22 @@ def test_kernel_route_table_pinned():
     assert got == expect
 
 
-def test_kernel_route_planes_and_env(monkeypatch):
+def test_kernel_route_planes():
     # planes route only with a plan, only at shallow m, only when LO | B
     assert kernel_route(1, 64, has_planes=True) == ("planes", 16)
-    assert kernel_route(4, 256, has_planes=True) == ("planes", 64)
+    assert kernel_route(4, 96, has_planes=True) == ("planes", 16)
+    # B >= 128: the LO=64 plane block does not fit v5e's scoped VMEM
+    assert kernel_route(4, 256, has_planes=True) == ("joint", 64)
     assert kernel_route(8, 64, has_planes=True) == ("direct", 64)
     assert kernel_route(4, 255, has_planes=True) == ("joint", 64)
     assert kernel_route(16, 256, has_planes=True) == ("joint", 128)
-    # the escape hatch retires the unmeasured narrow-lane (LO < 64)
-    # routes — joint AND planes — but not the measured LO=64 planes
-    monkeypatch.setenv("MMLSPARK_TPU_HIST_JOINT64", "0")
-    assert kernel_route(1, 64) == ("direct", 64)
-    assert kernel_route(4, 96) == ("direct", 96)
-    assert kernel_route(1, 256) == ("joint", 64)
-    assert kernel_route(1, 64, has_planes=True) == ("direct", 64)
-    assert kernel_route(1, 256, has_planes=True) == ("planes", 64)
 
 
 def test_plan_lo_bins_pinned():
     assert plan_lo_bins(64) == 16
     assert plan_lo_bins(96) == 16
-    assert plan_lo_bins(128) == 64
-    assert plan_lo_bins(256) == 64
+    assert plan_lo_bins(128) == 0    # LO=64 planes exceed scoped VMEM
+    assert plan_lo_bins(256) == 0
     assert plan_lo_bins(255) == 0    # no LO divides 255: route unavailable
     assert plan_lo_bins(63) == 0     # below the radix family
     assert hp.PLANES_M_MAX == 4

@@ -18,27 +18,13 @@ import textwrap
 import numpy as np
 import pytest
 
-# Cross-process CPU collectives need a jax whose CPU backend implements
-# multiprocess computations; older jaxlibs raise "Multiprocess computations
-# aren't implemented on the CPU backend". The `jax_num_cpu_devices` config
-# option arrived with that capability, so probe it as the feature gate.
-import jax  # noqa: E402
-
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.config, "jax_num_cpu_devices"),
-    reason="this jax's CPU backend lacks multiprocess collectives")
-
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _PRELUDE = """
 import json, os, sys
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:  # older jax spells the count as an XLA flag
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=2")
+jax.config.update("jax_num_cpu_devices", 2)
 import numpy as np
 pid = int(sys.argv[1]); port = sys.argv[2]
 from mmlspark_tpu.parallel import cluster
@@ -100,7 +86,7 @@ def test_cluster_primitives_two_processes(tmp_path):
     outs = _run_pair("""
     from jax.sharding import PartitionSpec as P
     from mmlspark_tpu.parallel import DATA_AXIS, data_mesh
-    from mmlspark_tpu.parallel.shard import shard_map
+    from jax import shard_map
 
     n = 103  # ragged on purpose: padded_process_rows must even it out
     mesh = data_mesh()

@@ -677,16 +677,11 @@ def test_benchdiff_fleet_gates(tmp_path, capsys):
     capsys.readouterr()
 
 
-def test_benchdiff_gbdt_gates_on_real_rounds():
-    """The committed BENCH_r0N.json history must parse and synthesize the
-    derived gate records without error (threshold-free informational
+def test_benchdiff_gbdt_gates_on_driver_rounds(bench_rounds):
+    """Driver-format headline rounds must parse and synthesize the derived
+    per-shape gate records without error (threshold-free informational
     run)."""
-    import glob
-    files = sorted(glob.glob(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "BENCH_r0*.json")))
-    if len(files) < 2:
-        pytest.skip("no committed bench rounds")
+    files, _ = bench_rounds
     rounds = [benchdiff.load_round(f) for f in files]
     labeled = [(f"r{i}", by) for i, (_, by) in enumerate(rounds)]
     lines, _ = benchdiff.diff_rounds(labeled)

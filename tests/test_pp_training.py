@@ -55,14 +55,9 @@ def test_sgd_gradient_parity_across_pp_degrees():
     import subprocess
     import sys
     body = """
-import os
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:  # older jax spells the count as an XLA flag
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
+jax.config.update("jax_num_cpu_devices", 8)
 import numpy as np
 from mmlspark_tpu.parallel import DATA_AXIS, PIPE_AXIS, grid_mesh
 from mmlspark_tpu.models.dnn.pp_training import PipelinedLMTrainer
@@ -140,10 +135,12 @@ def test_run_multi_step_matches_step_loop():
 
 
 def test_layers_are_stage_sharded():
-    """The point of PP: each device materializes only its stage's layers."""
-    pp = PipelinedLMTrainer(
-        mesh=grid_mesh((2, 4), (DATA_AXIS, PIPE_AXIS)),
-        n_microbatches=2, **_KW)
+    """The point of PP: each device materializes only its stage's layers.
+    (M = 2 < P = 4 builds, but says it is mostly bubble.)"""
+    with pytest.warns(UserWarning, match=r"n_microbatches \(2\) < pipe"):
+        pp = PipelinedLMTrainer(
+            mesh=grid_mesh((2, 4), (DATA_AXIS, PIPE_AXIS)),
+            n_microbatches=2, **_KW)
     wq = pp.params["layers"]["wq"]          # (4, d, d) global
     assert wq.shape[0] == 4
     assert {s.data.shape[0] for s in wq.addressable_shards} == {1}

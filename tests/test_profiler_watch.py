@@ -596,13 +596,12 @@ def test_benchdiff_excludes_non_tpu_rounds_from_gates(tmp_path, capsys):
     capsys.readouterr()
 
 
-def test_benchdiff_real_rounds_with_cpu_extra_excluded(capsys):
-    """Over the REAL committed rounds: BENCH_EXTRA_r06 (backend=cpu,
-    route fallback xla) is harvested, visibly excluded, and contributes
-    nothing to trajectories or gates — r01->r05 gate exactly as without
-    it (including the known r04->r05 hbm_utilization dip)."""
-    files = [os.path.join(_REPO, f"BENCH_r0{i}.json") for i in range(1, 6)]
-    extra = os.path.join(_REPO, "BENCH_EXTRA_r06.json")
+def test_benchdiff_rounds_with_cpu_extra_excluded(bench_rounds, capsys):
+    """Over driver-format rounds: an extras file from a CPU run
+    (backend=cpu) is harvested, visibly excluded, and contributes nothing
+    to trajectories or gates — r01->r05 gate exactly as without it
+    (including the r04->r05 hbm_utilization dip)."""
+    files, extra = bench_rounds
     rc = benchdiff.main(files + [extra, "--threshold", "0.1"])
     out = capsys.readouterr().out
     assert "excluded from perf gates (non-TPU backend)" in out
@@ -611,7 +610,7 @@ def test_benchdiff_real_rounds_with_cpu_extra_excluded(capsys):
     for line in out.splitlines():
         if line.startswith("gbdt_train_rows_iters_per_sec"):
             assert "48931" not in line
-    # gates identical to the r01->r05 run (the real hbm dip still fires)
+    # gates identical to the r01->r05 run (the hbm dip still fires)
     rc_without = benchdiff.main(files + ["--threshold", "0.1"])
     capsys.readouterr()
     assert rc == rc_without == 1

@@ -212,7 +212,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from mmlspark_tpu.analysis.semantic import Case, hot_path_contract
 from mmlspark_tpu.parallel.mesh import data_mesh
-from mmlspark_tpu.parallel.shard import shard_map
+from jax import shard_map
 
 @hot_path_contract({disable}
     "fix.collective", collective_budget={budget})

@@ -424,10 +424,8 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np
 sys.path.insert(0, {repo!r})
-from mmlspark_tpu.utils.hostcache import host_cache_dir
-jax.config.update("jax_compilation_cache_dir",
-                  host_cache_dir(os.path.join({repo!r}, ".jax_cache")))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from mmlspark_tpu.utils.hostcache import enable_compile_cache
+enable_compile_cache()
 from mmlspark_tpu import Table
 from mmlspark_tpu.models.gbdt import GBDTRegressor
 from mmlspark_tpu.utils.checkpoint import CheckpointManager
