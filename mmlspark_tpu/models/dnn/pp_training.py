@@ -27,6 +27,10 @@ from __future__ import annotations
 
 import numpy as np
 
+from ...reliability.metrics import reliability_metrics
+from ...telemetry import names as tnames
+from ...telemetry.perf import register_program
+from ...utils.tracing import annotate
 from .transformer import init_transformer
 
 
@@ -103,49 +107,56 @@ def _block_attn(x, lp, h: int, dh: int, attention: str = "dense",
     leaves arrive column-sliced (wq/wk/wv/w1 on outputs, wo/w2 on inputs
     — h must be the LOCAL head count), activations stay replicated, and
     one psum over tp_axis closes each of the two row-parallel matmuls."""
+    import jax
     from ...parallel.ring_attention import reference_attention
     from .transformer import _layer_norm
 
     seq, d = x.shape
-    y = _layer_norm(x, lp["ln1"])
-    if tp_axis is not None:
-        y = _tp_f(tp_axis)(y)
-    q = (y @ lp["wq"]).reshape(seq, h, dh)
-    k = (y @ lp["wk"]).reshape(seq, h, dh)
-    v = (y @ lp["wv"]).reshape(seq, h, dh)
-    if cp_axis is not None:
-        # context parallelism: the sequence is SHARDED over cp_axis; ring
-        # attention rotates K/V blocks around that axis with the global
-        # causal geometry carried by block offsets. attention="flash"
-        # streams each rotating block through the Pallas kernel.
-        from ...parallel.ring_attention import _ring_attention_sharded
-        a = _ring_attention_sharded(
-            q, k, v, axis_name=cp_axis, causal=True,
-            scale=1.0 / float(np.sqrt(dh)),
-            block_impl="flash" if attention == "flash" else "dense")
-    elif attention == "flash":
-        from ...ops.flash_attention import flash_attention
-        a = flash_attention(q, k, v, causal=True)
-    else:
-        a = reference_attention(q, k, v, causal=True)
-    att = a.reshape(seq, h * dh) @ lp["wo"]
-    if tp_axis is not None:
-        att = _tp_g(tp_axis)(att)
-    return x + att
+    with jax.named_scope(tnames.LM_ATTN):
+        y = _layer_norm(x, lp["ln1"])
+        if tp_axis is not None:
+            y = _tp_f(tp_axis)(y)
+        q = (y @ lp["wq"]).reshape(seq, h, dh)
+        k = (y @ lp["wk"]).reshape(seq, h, dh)
+        v = (y @ lp["wv"]).reshape(seq, h, dh)
+        if cp_axis is not None:
+            # context parallelism: the sequence is SHARDED over cp_axis;
+            # ring attention rotates K/V blocks around that axis with the
+            # global causal geometry carried by block offsets.
+            # attention="flash" streams each rotating block through the
+            # Pallas kernel.
+            from ...parallel.ring_attention import _ring_attention_sharded
+            with jax.named_scope(tnames.LM_ATTN_FLASH):
+                a = _ring_attention_sharded(
+                    q, k, v, axis_name=cp_axis, causal=True,
+                    scale=1.0 / float(np.sqrt(dh)),
+                    block_impl="flash" if attention == "flash" else "dense")
+        elif attention == "flash":
+            from ...ops.flash_attention import flash_attention
+            with jax.named_scope(tnames.LM_ATTN_FLASH):
+                a = flash_attention(q, k, v, causal=True)
+        else:
+            a = reference_attention(q, k, v, causal=True)
+        att = a.reshape(seq, h * dh) @ lp["wo"]
+        if tp_axis is not None:
+            att = _tp_g(tp_axis)(att)
+        return x + att
 
 
 def _block_ff(x, lp, tp_axis=None):
     """Feed-forward sublayer: ln2 -> gelu MLP -> residual add."""
     import jax
     from .transformer import _layer_norm
-    y = _layer_norm(x, lp["ln2"])
-    if tp_axis is not None:
-        y = _tp_f(tp_axis)(y)
-    ff = jax.nn.gelu(y @ lp["w1"] + lp["b1"]) @ lp["w2"]
-    if tp_axis is not None:
-        ff = _tp_g(tp_axis)(ff)
-    # b2 is replicated across tp: add OUTSIDE the psum or it counts tp x
-    return x + ff + lp["b2"]
+    with jax.named_scope(tnames.LM_MLP):
+        y = _layer_norm(x, lp["ln2"])
+        if tp_axis is not None:
+            y = _tp_f(tp_axis)(y)
+        ff = jax.nn.gelu(y @ lp["w1"] + lp["b1"]) @ lp["w2"]
+        if tp_axis is not None:
+            ff = _tp_g(tp_axis)(ff)
+        # b2 is replicated across tp: add OUTSIDE the psum or it counts
+        # tp x
+        return x + ff + lp["b2"]
 
 
 def _block(x, lp, h: int, dh: int, attention: str = "dense",
@@ -331,9 +342,10 @@ class PipelinedLMTrainer:
                 # the f32 masters through the cast's transpose. Layer-norm
                 # scale/bias ride along in bf16 — _layer_norm upcasts its
                 # math to f32 internally either way
-                p = jax.tree_util.tree_map(
-                    lambda a: a.astype(cdt)
-                    if a.dtype == jnp.float32 else a, p)
+                with jax.named_scope(tnames.LM_CAST):
+                    p = jax.tree_util.tree_map(
+                        lambda a: a.astype(cdt)
+                        if a.dtype == jnp.float32 else a, p)
             s_idx = jax.lax.axis_index(PIPE_AXIS)
             b_loc, S_loc = tokens.shape
             mb = b_loc // M
@@ -386,22 +398,25 @@ class PipelinedLMTrainer:
                 return x
 
             def embed_mb(tok):       # (mb, S) -> (mb, S, d)
-                pos = jax.lax.dynamic_slice_in_dim(
-                    p["pos"], seq_off, S_loc, axis=0)
-                return p["embed"][tok] + pos
+                with jax.named_scope(tnames.LM_EMBED):
+                    pos = jax.lax.dynamic_slice_in_dim(
+                        p["pos"], seq_off, S_loc, axis=0)
+                    return p["embed"][tok] + pos
 
             def mb_loss(y, tgt):     # final-stage head: local masked SUM
                 from .transformer import _layer_norm
-                z = _layer_norm(y, p["final_ln"])
-                # tied softmax head: bf16 operands at the MXU's bf16 rate,
-                # but logits ACCUMULATE f32 (bf16 logits would feed
-                # log_softmax 8-bit mantissas at vocab-size dynamic range)
-                logits = jnp.einsum("msd,vd->msv", z, p["embed"],
-                                    preferred_element_type=jnp.float32)
-                logp = jax.nn.log_softmax(logits, axis=-1)
-                nll = -jnp.take_along_axis(logp, tgt[..., None],
-                                           axis=-1)[..., 0]
-                return (nll * pos_mask).sum()
+                with jax.named_scope(tnames.LM_HEAD):
+                    z = _layer_norm(y, p["final_ln"])
+                    # tied softmax head: bf16 operands at the MXU's bf16
+                    # rate, but logits ACCUMULATE f32 (bf16 logits would
+                    # feed log_softmax 8-bit mantissas at vocab-size
+                    # dynamic range)
+                    logits = jnp.einsum("msd,vd->msv", z, p["embed"],
+                                        preferred_element_type=jnp.float32)
+                    logp = jax.nn.log_softmax(logits, axis=-1)
+                    nll = -jnp.take_along_axis(logp, tgt[..., None],
+                                               axis=-1)[..., 0]
+                    return (nll * pos_mask).sum()
 
             def tick(carry, t):
                 act, acc = carry
@@ -479,13 +494,16 @@ class PipelinedLMTrainer:
 
         def train_step(params, opt_state, tokens):
             loss, grads = mapped(params, tokens)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state, loss
+            with jax.named_scope(tnames.LM_OPT):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+            return params, opt_state, loss
 
         # raw step kept for run()'s fori_loop body; jitted once here
         self._step_fn = train_step
         self._step = jax.jit(train_step, donate_argnums=self._donate)
         self._multi = None   # lazily-built multi-step executable (run())
+        self._step_shape = None   # token shape of the last step() call
 
     def run(self, tokens: np.ndarray, n_steps: int) -> float:
         """n_steps chained updates with ONE host sync; returns the final
@@ -529,11 +547,48 @@ class PipelinedLMTrainer:
                 f"seq axis ({self.cp})")
 
     def step(self, tokens: np.ndarray) -> float:
-        """One dp x pp (x tp) (x cp) update; returns the batch loss."""
+        """One dp x pp (x tp) (x cp) update; returns the batch loss.
+
+        Three host spans at the step's layer boundaries (`lm.step.h2d`,
+        `lm.step.dispatch`, `lm.step.wait`; none adds a device sync) and
+        the `lm.step.compiles` counter: what the step program itself
+        compiled during the dispatch, by its own jit cache."""
         self._check_batch(tokens)
-        self.params, self.opt_state, loss = self._step(
-            self.params, self.opt_state, self._to_device(tokens))
-        return float(loss)
+        with annotate(tnames.LM_STEP_H2D):
+            d_tokens = self._to_device(tokens)
+        if d_tokens.shape != self._step_shape:
+            self._step_shape = d_tokens.shape
+            self._register_step_program()
+        held = self._step._cache_size()
+        with annotate(tnames.LM_STEP_DISPATCH):
+            self.params, self.opt_state, loss = self._step(
+                self.params, self.opt_state, d_tokens)
+        compiled = self._step._cache_size() - held
+        if compiled:
+            reliability_metrics.inc(tnames.LM_STEP_COMPILES, compiled)
+        with annotate(tnames.LM_STEP_WAIT):
+            return float(loss)
+
+    def _register_step_program(self) -> None:
+        """Name the step program of the last `step()` call's shapes for
+        readers of captures (`telemetry.perf.scope_maps`): one dict insert
+        per change of shape. The thunk keeps the jitted step and the
+        shapes of its arguments, not the trainer or an array, and lowers
+        and compiles only when a reader asks (with a persistent compile
+        cache that is a fetch), so it outlives the trainer at no device
+        memory: a benchmark reads its capture after the driver returned."""
+        import jax
+        import jax.numpy as jnp
+        step = self._step
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding),
+            (self.params, self.opt_state))
+        tokens = jax.ShapeDtypeStruct(self._step_shape, jnp.int32,
+                                      sharding=self._batch_sharding)
+        register_program(
+            f"lm.step#{id(self):x}",
+            lambda: step.lower(*args, tokens).compile().as_text())
 
     # -- checkpoint/resume ---------------------------------------------------
     # Shared implementation with ShardedLMTrainer (one format, one code

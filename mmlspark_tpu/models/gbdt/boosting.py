@@ -27,6 +27,7 @@ from ...ops import binning
 from ...reliability.metrics import reliability_metrics
 from ...telemetry.spans import get_tracer
 from ...telemetry import names as tnames
+from ...telemetry.perf import register_program
 from ...utils import tracing
 from . import objectives as obj_mod
 from . import trainer
@@ -245,17 +246,19 @@ def _boost_chunk(d_bins, y_j, w_j, pres_j, margin, init_margin, v_bins, vy,
             k_bag = jax.random.fold_in(k_bag, jax.lax.axis_index(axis_name))
         # rf trees are independent: gradients always at the initial margin
         g_margin = init_margin if rf else margin
-        grad, hess = _grad_hess(p, g_margin, y_j, y_onehot, None)
-        if w_j is not None:
-            grad = grad * (w_j[:, None] if multiclass else w_j)
-            hess = hess * (w_j[:, None] if multiclass else w_j)
-        row_w = _row_weights(p, grad, k_bag, it, multiclass)
-        if row_w is not None:
-            grad = grad * (row_w[:, None] if multiclass else row_w)
-            hess = hess * (row_w[:, None] if multiclass else row_w)
-        # presence indicator for min_data_in_leaf: bagged-out + padding rows
-        # are absent; genuine rows count 1 regardless of sample weight
-        count_w = _presence(pres_j, row_w)
+        with jax.named_scope(tnames.GBDT_OBJECTIVE):
+            grad, hess = _grad_hess(p, g_margin, y_j, y_onehot, None)
+            if w_j is not None:
+                grad = grad * (w_j[:, None] if multiclass else w_j)
+                hess = hess * (w_j[:, None] if multiclass else w_j)
+            row_w = _row_weights(p, grad, k_bag, it, multiclass)
+            if row_w is not None:
+                grad = grad * (row_w[:, None] if multiclass else row_w)
+                hess = hess * (row_w[:, None] if multiclass else row_w)
+            # presence indicator for min_data_in_leaf: bagged-out + padding
+            # rows are absent; genuine rows count 1 regardless of sample
+            # weight
+            count_w = _presence(pres_j, row_w)
         fmask = _feature_mask(p, k_feat, cfg.n_features)
 
         sfs, sbs, lvs, gns, cvs, ics, cws = [], [], [], [], [], [], []
@@ -275,10 +278,11 @@ def _boost_chunk(d_bins, y_j, w_j, pres_j, margin, init_margin, v_bins, vy,
             cvs.append(tree.cover)
             ics.append(tree.split_is_cat)
             cws.append(tree.cat_words)
-            if multiclass:
-                margin = margin.at[:, k].add(delta)
-            else:
-                margin = margin + delta
+            with jax.named_scope(tnames.GBDT_OBJECTIVE):
+                if multiclass:
+                    margin = margin.at[:, k].add(delta)
+                else:
+                    margin = margin + delta
             if has_valid:
                 vd = trainer.predict_binned(v_bins, tree.split_feature,
                                             tree.split_bin, tree.leaf_value,
@@ -314,6 +318,18 @@ def _boost_chunk(d_bins, y_j, w_j, pres_j, margin, init_margin, v_bins, vy,
     # categorical features) would divide by zero
     cw = cw.reshape(cw.shape[0] * cw.shape[1], cw.shape[2], cw.shape[3])
     return margin, v_margin, sf, sb, lv, gn, cv, ic, cw, metrics
+
+
+def _chunk_program_text(args, kwargs):
+    """A thunk for `telemetry.perf.register_program`: the optimized HLO of
+    `_boost_chunk` for the shapes (not the arrays) of one call, lowered
+    and compiled again only when a reader of captures asks."""
+    def abstract(a):
+        return (jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                     sharding=getattr(a, "sharding", None))
+                if isinstance(a, (jax.Array, np.ndarray)) else a)
+    args, kwargs = jax.tree_util.tree_map(abstract, (args, kwargs))
+    return lambda: _boost_chunk.lower(*args, **kwargs).compile().as_text()
 
 
 def _fetch_packed(parts):
@@ -505,7 +521,7 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
             b, g, h, fm, cfg, count_w=cw, lo_planes=_hist_planes,
             plane_lo=_hist_plane_lo)
 
-    staged_y = None
+    staged_y = y_j = None
     if prebinned is not None:
         # (mapper, device_bins[, device_y]): data already staged on device
         # — training throughput can then be measured without the
@@ -518,8 +534,9 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
             mapper, d_bins = prebinned
         d_bins = put(d_bins)
     else:
-        with tracing.wall_clock(tnames.DATA_FIT_BINS,
-                                sink=reliability_metrics.observe):
+        with tracing.annotate(tnames.GBDT_FIT_FIT_BINS), \
+                tracing.wall_clock(tnames.DATA_FIT_BINS,
+                                   sink=reliability_metrics.observe):
             mapper = binning.fit_bins(
                 x, max_bin=p.max_bin, seed=p.seed,
                 categorical_features=p.categorical_features)
@@ -541,7 +558,15 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
                 # once (per-chunk placement would fight the row sharding)
                 d_bins = put(parallel_apply_bins(mapper, x, ingest))
         else:
-            d_bins = put(binning.apply_bins_device(mapper, x))
+            # host dispatch and H2D of the default path; the device side
+            # is the gbdt.bin scope of the capture
+            with tracing.annotate(tnames.GBDT_FIT_BIN_DISPATCH):
+                d_bins = put(binning.apply_bins_device(mapper, x))
+                y_j = put(np.asarray(y, dtype=np.float32))
+            register_program(
+                f"gbdt.bin[{n}x{n_features}]",
+                lambda: binning.assign_bins_program_text(
+                    mapper, (n, n_features)))
     if (os.environ.get("MMLSPARK_TPU_HIST") == "planes"
             and not custom_tree_fn and chunk_fn is None and put_fn is None):
         # precompute the level-invariant lo one-hot planes once per fit;
@@ -554,8 +579,9 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
             _hist_plane_lo = _lo
             reliability_metrics.set_gauge(tnames.GBDT_HIST_PLAN_BYTES,
                                           float(_hist_planes.nbytes))
-    y_j = (put(staged_y.astype(jnp.float32)) if staged_y is not None
-           else put(np.asarray(y, dtype=np.float32)))
+    if y_j is None:
+        y_j = (put(staged_y.astype(jnp.float32)) if staged_y is not None
+               else put(np.asarray(y, dtype=np.float32)))
     w_j = None if weights is None else put(np.asarray(weights, dtype=np.float32))
     # physical-row indicator (0 = distributed padding); user weights must not
     # affect min_data_in_leaf counts, so this is a separate channel
@@ -570,7 +596,8 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
         # its base (init_base) carries over instead of recomputing the mean
         base = float(init_base)
     elif p.boost_from_average and init_scores is None and not multiclass:
-        base = obj_mod.init_score(p.objective, y, weights=weights)
+        with tracing.annotate(tnames.GBDT_FIT_INIT_SCORE):
+            base = obj_mod.init_score(p.objective, y, weights=weights)
     init_margin_arr = None
     if init_booster is not None and init_margin is None:
         # resumed-without-saved-margin (legacy checkpoints) / warm starts:
@@ -721,6 +748,7 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
         parts, stop_at = [], None
         best_metric, best_iter, rounds_since = None, -1, 0
         it = 0
+        registered_clen = None
         # rf gradients stay at the pre-loop margin EXCLUDING any restored
         # ensemble: resumed rf trees must fit the same bagged target as the
         # first half, not the half-forest's residuals
@@ -736,12 +764,20 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
             _plane_kw = ({"lo_planes": _hist_planes,
                           "plane_lo": _hist_plane_lo}
                          if _hist_planes is not None else {})
+            chunk_args = (d_bins, y_j, w_j, pres_j, margin, margin_init,
+                          v_bins_, vy_j, v_margin_, kc, it + iter_offset, p,
+                          cfg, clen, k_out)
+            chunk_kw = dict(has_valid=has_valid, **_plane_kw)
+            if fused is _boost_chunk and clen != registered_clen:
+                registered_clen = clen
+                register_program(
+                    f"gbdt.chunk[{n}x{n_features}/{clen}]",
+                    _chunk_program_text(chunk_args, chunk_kw))
             with _clk_step(it):
-                (margin, v_margin_, sf_c, sb_c, lv_c, gn_c, cv_c, ic_c,
-                 cw_c, mts) = fused(
-                    d_bins, y_j, w_j, pres_j, margin, margin_init, v_bins_,
-                    vy_j, v_margin_, kc, it + iter_offset, p, cfg, clen,
-                    k_out, has_valid=has_valid, **_plane_kw)
+                with tracing.annotate(tnames.GBDT_FIT_BOOST,
+                                      iterations=int(clen)):
+                    (margin, v_margin_, sf_c, sb_c, lv_c, gn_c, cv_c, ic_c,
+                     cw_c, mts) = fused(*chunk_args, **chunk_kw)
                 parts.append((sf_c, sb_c, lv_c, gn_c, cv_c, ic_c, cw_c))
                 if checkpoint_fn is not None:
                     # chunk boundary = natural checkpoint step: build the
@@ -792,11 +828,12 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
         # fetch once.
         # This fetch is the loop's block-until-ready boundary — where the
         # async dispatch's device time surfaces for the goodput account.
-        if _clk is not None:
-            sf, sb, lv, gn, cv, ic, cw = _clk.device_block(
-                lambda: _fetch_packed(parts))
-        else:
-            sf, sb, lv, gn, cv, ic, cw = _fetch_packed(parts)
+        with tracing.annotate(tnames.GBDT_FIT_FETCH):
+            if _clk is not None:
+                sf, sb, lv, gn, cv, ic, cw = _clk.device_block(
+                    lambda: _fetch_packed(parts))
+            else:
+                sf, sb, lv, gn, cv, ic, cw = _fetch_packed(parts)
         if stop_at is not None:  # drop trees grown past the stopping point
             keep = stop_at * k_out
             sf, sb, lv = sf[:keep], sb[:keep], lv[:keep]
@@ -812,12 +849,13 @@ def _fit_booster_impl(x: np.ndarray, y: np.ndarray,
                     best_iter, init_booster, base, gain=gn, cover=cv,
                     is_cat=ic, cat_words=cw),
                     base, final=True)
-        tree_classes = np.tile(np.arange(k_out, dtype=np.int32),
-                               sf.shape[0] // max(k_out, 1))
-        booster = _build_booster(
-            sf, sb, lv, tree_classes, mapper, p, k_out, n_features,
-            best_iter if (track and patience > 0) else -1, init_booster, base,
-            gain=gn, cover=cv, is_cat=ic, cat_words=cw)
+        with tracing.annotate(tnames.GBDT_FIT_ASSEMBLE):
+            tree_classes = np.tile(np.arange(k_out, dtype=np.int32),
+                                   sf.shape[0] // max(k_out, 1))
+            booster = _build_booster(
+                sf, sb, lv, tree_classes, mapper, p, k_out, n_features,
+                best_iter if (track and patience > 0) else -1, init_booster,
+                base, gain=gn, cover=cv, is_cat=ic, cat_words=cw)
         return booster, base, eval_history
 
     trees, tree_classes, train_deltas = [], [], []

@@ -21,6 +21,8 @@ import numpy as np
 from ...core import (Estimator, Model, Param, Table, HasFeaturesCol,
                      HasLabelCol, HasWeightCol, HasPredictionCol,
                      HasProbabilitiesCol, one_of, in_range)
+from ...telemetry import names as tnames
+from ...utils import tracing
 from .boosting import BoostParams, Callbacks, fit_booster
 from .booster import Booster
 
@@ -395,32 +397,36 @@ class _GBDTParams(HasFeaturesCol, HasLabelCol, HasWeightCol, HasPredictionCol):
         if not self.quality_profile:
             return model
         try:
-            from ...data.pipeline import profile_columns
-            from ...telemetry import quality as tquality
-            x = np.asarray(table[self.features_col],
-                           np.float32)[:tquality.MAX_REFERENCE_ROWS]
-            y = np.asarray(table[self.label_col],
-                           np.float64)[:tquality.MAX_REFERENCE_ROWS]
-            feature_cols = tquality.matrix_columns(x)
-            categorical = tuple(
-                f"f{int(i)}" for i in (self.categorical_slot_indexes or ()))
-            head = Table({self.features_col: x[:score_rows]})
-            pred = np.asarray(
-                model.transform(head)[self.prediction_col], np.float64)
-            all_cols = dict(feature_cols)
-            all_cols["label"] = y
-            all_cols["prediction"] = pred
-            # grids frozen over the full bounded sample, counts folded
-            # chunk-wise (ingest-shaped, exact-merge path)
-            prof = tquality.DatasetProfile.fit(
-                all_cols, categorical=categorical, observe=False)
-            profile_columns(prof, feature_cols)
-            prof.observe("label", y)
-            prof.observe("prediction", pred)
-            model.quality_profile = prof.state()
+            with tracing.annotate(tnames.GBDT_ESTIMATOR_PROFILE):
+                self._freeze_quality_profile(table, model, score_rows)
         except Exception:  # noqa: BLE001 - observability never fails a fit
             pass
         return model
+
+    def _freeze_quality_profile(self, table: Table, model, score_rows: int):
+        from ...data.pipeline import profile_columns
+        from ...telemetry import quality as tquality
+        x = np.asarray(table[self.features_col],
+                       np.float32)[:tquality.MAX_REFERENCE_ROWS]
+        y = np.asarray(table[self.label_col],
+                       np.float64)[:tquality.MAX_REFERENCE_ROWS]
+        feature_cols = tquality.matrix_columns(x)
+        categorical = tuple(
+            f"f{int(i)}" for i in (self.categorical_slot_indexes or ()))
+        head = Table({self.features_col: x[:score_rows]})
+        pred = np.asarray(
+            model.transform(head)[self.prediction_col], np.float64)
+        all_cols = dict(feature_cols)
+        all_cols["label"] = y
+        all_cols["prediction"] = pred
+        # grids frozen over the full bounded sample, counts folded
+        # chunk-wise (ingest-shaped, exact-merge path)
+        prof = tquality.DatasetProfile.fit(
+            all_cols, categorical=categorical, observe=False)
+        profile_columns(prof, feature_cols)
+        prof.observe("label", y)
+        prof.observe("prediction", pred)
+        model.quality_profile = prof.state()
 
     def _attach_lineage(self, model):
         """Stamp the fit's provenance onto the fitted model — the lineage
@@ -486,6 +492,12 @@ class _GBDTModelBase(Model, HasFeaturesCol, HasPredictionCol):
         super().__init__(**kw)
         self._booster = booster
         self._init_score = init_score
+
+    def _raw_score(self, x):
+        """Raw scores of a transform's rows, under `gbdt.transform.score`
+        (host span; the device scorer's time surfaces inside it)."""
+        with tracing.annotate(tnames.GBDT_TRANSFORM_SCORE):
+            return self._booster.raw_score(x, self._init_score)
 
     def _get_state(self):
         d = self._booster.to_dict()
@@ -592,7 +604,7 @@ class GBDTClassificationModel(_GBDTModelBase, HasProbabilitiesCol):
 
     def _transform(self, t: Table) -> Table:
         x = np.asarray(t[self.features_col], np.float32)
-        raw = self._booster.raw_score(x, self._init_score)
+        raw = self._raw_score(x)
         proba = self._proba_from_raw(raw)
         pred = proba.argmax(axis=1).astype(np.float64)
         t = (t.with_column(self.raw_prediction_col, raw)
@@ -652,7 +664,7 @@ class GBDTRegressionModel(_GBDTModelBase):
 
     def _transform(self, t: Table) -> Table:
         x = np.asarray(t[self.features_col], np.float32)
-        raw = self._booster.raw_score(x, self._init_score)[:, 0]
+        raw = self._raw_score(x)[:, 0]
         t = t.with_column(self.prediction_col, self._link(raw))
         return self._maybe_extra_cols(t, x)
 
@@ -687,7 +699,7 @@ class GBDTRankerModel(_GBDTModelBase):
 
     def _transform(self, t: Table) -> Table:
         x = np.asarray(t[self.features_col], np.float32)
-        raw = self._booster.raw_score(x, self._init_score)[:, 0]
+        raw = self._raw_score(x)[:, 0]
         t = t.with_column(self.prediction_col, raw.astype(np.float64))
         return self._maybe_extra_cols(t, x)
 

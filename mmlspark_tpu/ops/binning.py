@@ -18,6 +18,8 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from ..telemetry import names as tnames
+
 
 class BinMapper(NamedTuple):
     """Per-feature binning decided on (a sample of) the training data."""
@@ -131,11 +133,26 @@ def _get_assign_bins():
                 b = jnp.searchsorted(ub_j, col, side="left")
                 b = jnp.where(jnp.isnan(col), nb_j - 1, b)
                 return jnp.minimum(b, nb_j - 1)
-            out = jax.vmap(one_feature, in_axes=(0, 0, 1), out_axes=1)(ub, nb, xd)
-            return out.astype(jnp.uint8)
+            with jax.named_scope(tnames.GBDT_BIN):
+                out = jax.vmap(one_feature, in_axes=(0, 0, 1),
+                               out_axes=1)(ub, nb, xd)
+                return out.astype(jnp.uint8)
 
         _assign_bins_jit = _assign
     return _assign_bins_jit
+
+
+def assign_bins_program_text(mapper: BinMapper, shape) -> str:
+    """Optimized HLO of the device bin assignment for a float32 table of
+    `shape`, for `telemetry.perf.register_program`: shapes only, nothing
+    is placed on a device."""
+    import jax
+    import jax.numpy as jnp
+    return _get_assign_bins().lower(
+        jax.eval_shape(jnp.asarray, mapper.upper_bounds),
+        jax.eval_shape(jnp.asarray, mapper.n_bins),
+        jax.ShapeDtypeStruct(tuple(shape), jnp.float32)
+    ).compile().as_text()
 
 
 def apply_bins_device(mapper: BinMapper, x):

@@ -71,6 +71,15 @@ _FWD_BLOCK = 1024
 _BWD_BLOCK_BF16 = 1024
 _BWD_BLOCK_F32 = 512
 
+# The kernels' names, which reach the compiled program: a Pallas call's HLO
+# instruction is named after the innermost element of JAX's name stack, so
+# a trace event reads `%flash_dq.N = ... custom-call(` (under `jax.vmap`,
+# `%vmap_flash_dq_.N`) and its `op_name` ends `.../flash_dq/pallas_call`.
+KERNEL_FWD = "flash_fwd"
+KERNEL_DQ = "flash_dq"
+KERNEL_DKV = "flash_dkv"
+KERNEL_STATS_FWD = "flash_stats_fwd"
+
 
 def _pick_block(seq: int) -> int:
     """Largest block in {1024, 512, 256} whose padding waste stays under
@@ -419,6 +428,7 @@ def _flash_stats_forward(q, k, v, q_offset, k_offset, causal, scale,
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=KERNEL_STATS_FWD,
     )(qoff_arr, koff_arr, qh, kh, vh)
     # ring-merge shapes: acc (S, H, D), m/l (H, S)
     return (jnp.moveaxis(acc[:, :s], 0, 1), m[:, :s, 0], l[:, :s, 0])
@@ -461,6 +471,7 @@ def _flash_forward_lse(q, k, v, causal, scale, block_q, block_k, interpret):
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=KERNEL_FWD,
     )(q, k, v)
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     return out[:, :s], lse[:, :s]
@@ -649,6 +660,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=KERNEL_DQ,
     )(qoff_arr, koff_arr, q_p, k_p, v_p, g_p, lse_p, dsum)[:, :s_q]
 
     # dk/dv grid: k-blocks outer, q-blocks inner (accumulated)
@@ -670,6 +682,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=KERNEL_DKV,
     )(qoff_arr, koff_arr, k_p, v_p, q_p, g_p, lse_p, dsum)
     return dq, dk[:, :sk], dv[:, :sk]
 

@@ -109,9 +109,11 @@ _LAZY_NAMES = {
     "configure_profile_session": "profiler",
     "capture_profile": "profiler", "parse_trace": "profiler",
     "get_roofline": "profiler", "resolve_peaks": "profiler",
+    "region_stats": "profiler",
     "WatchRule": "watch", "TelemetryWatcher": "watch",
     "CompileLog": "perf", "FlightRecorder": "perf", "AotCache": "perf",
-    "collective_traffic": "perf",
+    "collective_traffic": "perf", "scope_map": "perf",
+    "register_program": "perf", "scope_maps": "perf",
     "compile_with_analysis": "perf", "executable_analysis": "perf",
     "record_plan_compile": "perf", "get_compile_log": "perf",
     "compile_stats": "perf", "hbm_utilization": "perf",
@@ -152,6 +154,7 @@ __all__ = ["Tracer", "Span", "SpanContext", "get_tracer", "configure",
            "quality_watch_rules", "record_label",
            "StepClock", "StragglerDetector", "flops_from_compile_log",
            "CompileLog", "FlightRecorder", "AotCache", "collective_traffic",
+           "scope_map", "register_program", "scope_maps",
            "compile_with_analysis",
            "executable_analysis", "record_plan_compile", "get_compile_log",
            "compile_stats", "hbm_utilization", "sample_resource_gauges",
@@ -159,5 +162,5 @@ __all__ = ["Tracer", "Span", "SpanContext", "get_tracer", "configure",
            "configure_flight_recorder", "trigger_bundle",
            "ProfileSession", "RooflineLedger", "get_profile_session",
            "configure_profile_session", "capture_profile", "parse_trace",
-           "get_roofline", "resolve_peaks",
+           "get_roofline", "resolve_peaks", "region_stats",
            "WatchRule", "TelemetryWatcher"]

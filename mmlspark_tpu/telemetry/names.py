@@ -78,6 +78,7 @@ TELEMETRY_BUNDLE_SUPPRESSED = "telemetry.bundle.suppressed"
 TELEMETRY_PROFILE_CAPTURES = "telemetry.profile.captures"
 TELEMETRY_PROFILE_SUPPRESSED = "telemetry.profile.suppressed"
 TELEMETRY_PROFILE_STAMP_ERRORS = "telemetry.profile.stamp_errors"
+LM_STEP_COMPILES = "lm.step.compiles"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -198,6 +199,9 @@ COUNTERS = {
                                     "failed (capture kept, stamp lost)",
     TELEMETRY_WATCH_TRIPS: "telemetry watcher rule trip TRANSITIONS "
                            "(threshold or median-shift)",
+    LM_STEP_COMPILES: "compilations of PipelinedLMTrainer's own step "
+                      "program, seen during lm.step.dispatch (a new shape, "
+                      "or the second step's donated layouts)",
     QUALITY_LABELS_JOINED: "delayed labels joined to their served "
                            "prediction (streaming evaluation pairs)",
     QUALITY_LABELS_LATE: "out-of-order labels that arrived BEFORE their "
@@ -306,8 +310,12 @@ CLUSTER_HOSTS_LIVE = "cluster.hosts.live"
 CLUSTER_HOSTS_DEAD = "cluster.hosts.dead"
 WORKLOADS_IFOREST_THRESHOLD = "workloads.iforest.threshold"
 WORKLOADS_SAR_CATALOG_ITEMS = "workloads.sar.catalog.items"
+TELEMETRY_PROFILE_UNSCOPED_SHARE = "telemetry.profile.unscoped_share"
 
 GAUGES = {
+    TELEMETRY_PROFILE_UNSCOPED_SHARE: "share of the last parsed capture's "
+                                      "device self time that no registered "
+                                      "program's scope map puts in a region",
     ANALYSIS_SEMANTIC_CONTRACTS: "hot-path contracts analyzed by the last "
                                  "semantic-tier run",
     ANALYSIS_SEMANTIC_FINDINGS: "findings (incl. contract-import errors) "
@@ -430,7 +438,81 @@ DATA_APPLY_BINS = "data.apply_bins"
 DATA_STAGE_BINNED = "data.stage_binned"
 DATA_TABLE_TRANSFORM = "data.table_transform"
 
+# ------------------------------------------------------------------ regions
+# ONE vocabulary from host span to HLO instruction. A DEVICE region is a
+# `jax.named_scope` inside jitted code: the name lands in the compiled
+# instructions' `op_name` metadata, and `telemetry.perf.scope_map` joins it
+# to a capture's device events by instruction name. A HOST region is a
+# `utils.tracing.annotate` span at a layer boundary: a TraceAnnotation on
+# the profiler's clock, a ring of durations in the roofline ledger, and a
+# wall-clock timing label (TIMINGS below).
+LM_EMBED = "lm.embed"
+LM_ATTN = "lm.attn"
+LM_ATTN_FLASH = "lm.attn.flash"
+LM_MLP = "lm.mlp"
+LM_HEAD = "lm.head"
+LM_OPT = "lm.opt"
+LM_CAST = "lm.cast"
+GBDT_HIST = "gbdt.hist"
+GBDT_SPLIT = "gbdt.split"
+GBDT_ROUTE = "gbdt.route"
+GBDT_OBJECTIVE = "gbdt.objective"
+GBDT_BIN = "gbdt.bin"
+
+DEVICE_REGIONS = {
+    LM_EMBED: "token + position embedding lookup of a microbatch",
+    LM_ATTN: "attention sublayer outside its kernels: ln1, q/k/v/o "
+             "projections, residual",
+    LM_ATTN_FLASH: "the flash / ring attention call (kernels flash_fwd, "
+                   "flash_dq, flash_dkv, flash_stats_fwd and their glue)",
+    LM_MLP: "feed-forward sublayer: ln2, gelu MLP, residual",
+    LM_HEAD: "final layer norm, tied logits, log-softmax, NLL",
+    LM_OPT: "optimizer update + apply_updates over the f32 masters",
+    LM_CAST: "per-step f32 -> compute-dtype cast of the parameters",
+    GBDT_HIST: "node x feature x bin histogram build (and its psum)",
+    GBDT_SPLIT: "best-split search of one level",
+    GBDT_ROUTE: "advance rows to their child nodes",
+    GBDT_OBJECTIVE: "gradient/hessian, row weights and the margin update "
+                    "of one boosting iteration",
+    GBDT_BIN: "device bin assignment (apply_bins_device)",
+}
+
+LM_STEP_H2D = "lm.step.h2d"
+LM_STEP_DISPATCH = "lm.step.dispatch"
+LM_STEP_WAIT = "lm.step.wait"
+GBDT_FIT_FIT_BINS = "gbdt.fit.fit_bins"
+GBDT_FIT_BIN_DISPATCH = "gbdt.fit.bin_dispatch"
+GBDT_FIT_INIT_SCORE = "gbdt.fit.init_score"
+GBDT_FIT_BOOST = "gbdt.fit.boost"
+GBDT_FIT_FETCH = "gbdt.fit.fetch"
+GBDT_FIT_ASSEMBLE = "gbdt.fit.assemble"
+GBDT_ESTIMATOR_PROFILE = "gbdt.estimator.profile"
+GBDT_TRANSFORM_SCORE = "gbdt.transform.score"
+
+HOST_REGIONS = {
+    LM_STEP_H2D: "PipelinedLMTrainer.step: the batch's device_put",
+    LM_STEP_DISPATCH: "PipelinedLMTrainer.step: the step program's "
+                      "dispatch (a recompile or a wait for a donated "
+                      "buffer shows here)",
+    LM_STEP_WAIT: "PipelinedLMTrainer.step: float(loss), the wait for the "
+                  "device",
+    GBDT_FIT_FIT_BINS: "fit_booster: quantile bin fit (what data.fit_bins "
+                       "times)",
+    GBDT_FIT_BIN_DISPATCH: "fit_booster default path: apply_bins_device + "
+                           "put + label upload (host dispatch and H2D; the "
+                           "device side is gbdt.bin)",
+    GBDT_FIT_INIT_SCORE: "fit_booster: host init score from the labels",
+    GBDT_FIT_BOOST: "fit_booster: dispatch of one fused boosting chunk "
+                    "(attribute: its iteration count)",
+    GBDT_FIT_FETCH: "fit_booster: the one D2H of every chunk's trees, "
+                    "where the chunks' device time surfaces",
+    GBDT_FIT_ASSEMBLE: "fit_booster: fetched tree arrays to a Booster",
+    GBDT_ESTIMATOR_PROFILE: "GBDT estimators: the fit-time quality profile",
+    GBDT_TRANSFORM_SCORE: "GBDT models: raw scores of a transform",
+}
+
 TIMINGS = {
+    **HOST_REGIONS,
     DATA_PREFETCH_PUT: "feeder time spent in device_put",
     DATA_BIN_CHUNK: "per-chunk binning transform wall clock",
     DATA_FIT_BINS: "quantile bin fit wall clock",

@@ -64,6 +64,7 @@ import shutil
 import sys
 import threading
 import time
+import weakref
 from collections import OrderedDict, deque
 from typing import Optional
 
@@ -255,6 +256,120 @@ def collective_traffic(hlo_text: str) -> dict:
     return out
 
 
+# ----------------------------------------------------------------- scope map
+# `jax.named_scope` lands in the compiled instructions' metadata
+# (`metadata={op_name="jit(f)/.../vmap(lm.mlp)/dot_general"}`), fusions
+# included, and a device trace names each event by its instruction's text
+# (`%fusion.123 = ...`): the instruction name is the join key between a
+# capture and the regions of telemetry/names.py.
+_SCOPED_INSTRUCTION_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name=\"([^\"]*)\"", re.M)
+FWD, BWD, REMAT = "fwd", "bwd", "remat"
+
+
+def region_of(op_name: str, regions=None) -> Optional[str]:
+    """The region an `op_name` path lies in: scopes nest, so the innermost
+    token that occurs, and where one name starts another at the same place
+    the longer (`lm.attn.flash` over `lm.attn`); None when the path names
+    no region."""
+    best, best_at = None, -1
+    for region in (tnames.DEVICE_REGIONS if regions is None else regions):
+        at = op_name.rfind(region)
+        if at >= 0 and (at, len(region)) > (best_at, len(best or "")):
+            best, best_at = region, at
+    return best
+
+
+def scope_map(hlo_text: str, regions=None) -> dict:
+    """{instruction name: (region, "fwd" | "bwd" | "remat")} of an
+    optimized HLO module. `transpose(` in the path marks the backward pass
+    and `rematted_computation` the forward that `jax.checkpoint` runs
+    again inside it; an instruction whose metadata names no region (or
+    that has none) maps to nothing."""
+    out: dict = {}
+    for name, op_name in _SCOPED_INSTRUCTION_RE.findall(hlo_text):
+        region = region_of(op_name, regions)
+        if region is not None:
+            out[name] = (region,
+                         REMAT if "rematted_computation" in op_name
+                         else BWD if "transpose(" in op_name else FWD)
+    return out
+
+
+def region_instruction_counts(scopes: dict) -> dict:
+    """{region: instructions} of a scope map: what a compile record keeps,
+    so a program whose scopes were lost shows in the compile log."""
+    out: dict = {}
+    for region, _direction in scopes.values():
+        out[region] = out.get(region, 0) + 1
+    return out
+
+
+_MAX_PROGRAMS = 16
+_programs: OrderedDict = OrderedDict()   # label -> [thunk or weak ref, map]
+_programs_lock = threading.Lock()
+
+
+def register_program(label: str, thunk) -> None:
+    """Name a compiled program that a reader of captures may ask about.
+    `thunk()` returns its optimized HLO text, a compiled executable
+    (`as_text()`), a ready scope map, or None once the program is gone;
+    it is not called until `scope_maps()` is, so registering costs one
+    dict insert. A bound method is held weakly: the registry keeps no
+    trainer or cache alive. Registering a label again replaces it; the
+    registry keeps the newest `_MAX_PROGRAMS`."""
+    ref = (weakref.WeakMethod(thunk) if hasattr(thunk, "__func__")
+           else (lambda: thunk))
+    with _programs_lock:
+        _programs.pop(label, None)
+        _programs[label] = [ref, None]
+        while len(_programs) > _MAX_PROGRAMS:
+            _programs.popitem(last=False)
+
+
+def scope_maps() -> dict:
+    """{label: scope map} of the registered programs that are still alive.
+    The first call per program lowers, compiles (with a persistent cache:
+    fetches) and parses; the map is kept. A thunk that fails or whose
+    owner is gone drops out; this never raises."""
+    with _programs_lock:
+        entries = list(_programs.items())
+    out = {}
+    for label, entry in entries:
+        if entry[1] is None:
+            thunk = entry[0]()
+            try:
+                got = thunk() if thunk is not None else None
+                if got is not None and not isinstance(got, (str, dict)):
+                    got = got.as_text()
+                entry[1] = scope_map(got) if isinstance(got, str) else got
+            except Exception:  # noqa: BLE001 - a program that cannot be
+                entry[1] = None  # lowered again has no map
+            if entry[1] is None:
+                with _programs_lock:
+                    if _programs.get(label) is entry:
+                        del _programs[label]
+                continue
+        out[label] = entry[1]
+    return out
+
+
+def merged_scope_map(maps: Optional[dict] = None) -> tuple:
+    """(one {instruction: (region, direction)} over every program,
+    the instruction names that two programs map differently). Those are
+    left out of the map: a capture cannot say which program ran them."""
+    merged: dict = {}
+    conflicts = set()
+    for scopes in (scope_maps() if maps is None else maps).values():
+        for name, where in scopes.items():
+            where = tuple(where)
+            if merged.setdefault(name, where) != where:
+                conflicts.add(name)
+    for name in conflicts:
+        del merged[name]
+    return merged, sorted(conflicts)
+
+
 _ALIAS_PARAM_RE = re.compile(r"\(\s*(\d+)\s*,")
 _MODULE_NAME_RE = re.compile(r"^HloModule [^,\n]*")
 
@@ -307,7 +422,10 @@ def executable_analysis(compiled, collectives: bool = True) -> dict:
     on live bytes, labeled by construction rather than guessed.
     `collectives` (default on) also parses the optimized HLO for the
     per-kind collective ops/bytes account (`collectives` key, only
-    present when the module actually contains collectives)."""
+    present when the module actually contains collectives) and for the
+    instructions per region (`regions` key, `scope_map`; absent when no
+    instruction carries a region, which is how a program whose scopes
+    were lost shows in the compile log)."""
     out: dict = {}
     try:
         ca = compiled.cost_analysis()
@@ -338,11 +456,15 @@ def executable_analysis(compiled, collectives: bool = True) -> dict:
             out["peak_bytes"] = peak
     if collectives:
         try:
-            traffic = collective_traffic(compiled.as_text())
+            text = compiled.as_text()
         except Exception:  # noqa: BLE001 - a backend without HLO text
-            traffic = {}
+            text = ""
+        traffic = collective_traffic(text)
         if traffic:
             out["collectives"] = traffic
+        regions = region_instruction_counts(scope_map(text))
+        if regions:
+            out["regions"] = regions
     return out
 
 
@@ -466,6 +588,15 @@ class AotCache:
             self._compiled[key] = compiled
             while len(self._compiled) > self._max:
                 self._compiled.popitem(last=False)
+        # what the cache already holds, by a weak reference to the cache:
+        # an evicted or collected executable reads as gone
+        cache = weakref.ref(self)
+
+        def text():
+            held = cache()
+            held = held._compiled.get(key) if held is not None else None
+            return held.as_text() if held is not None else None
+        register_program(f"{self.label}[{self._bucket(args)}]", text)
         return compiled
 
 

@@ -22,17 +22,19 @@ the ROADMAP item-4 autotuner's measured rows.
   transition on the flagged host, an SLO burn via the recorder latch
   (`FlightRecorder(profile_on_burn=True)`), and `utils.tracing.trace`
   (the explicit block-capture API, rebased on `session()`).
-- **parse_trace**: the captured trace (TensorBoard trace-event JSON,
-  ``plugins/profile/*/​*.trace.json.gz``) parsed into per-op records
-  ``{op, region, occurrences, self_time_us}`` from the DEVICE planes.
-  Field-by-field graceful degradation, mirroring `executable_analysis`'s
-  never-raise contract: on the CPU backend device planes are absent and
-  the table is empty — capture still succeeds, regions still carry their
-  host-noted walls. `region` resolves by matching the registered region
-  names (`REGIONS`) against op names/metadata — the
-  `jax.named_scope`/`TraceAnnotation` stamps the GBDT tree build
-  (`gbdt.hist`/`gbdt.split`/`gbdt.route`), `serving.plan.run`, and
-  `train.step` now carry.
+- **parse_trace**: the captured trace (the ``.xplane.pb`` under
+  ``plugins/profile/*/``, read through `jax.profiler.ProfileData`) parsed
+  into per-op records ``{op, region, direction, occurrences,
+  self_time_us}`` from the DEVICE planes. Graceful degradation, mirroring
+  `executable_analysis`'s never-raise contract: on the CPU backend device
+  planes are absent and the table is empty — capture still succeeds,
+  regions still carry their host-noted walls. A device event is named by
+  its HLO instruction's text and carries nothing of a `jax.named_scope`
+  (v5e traces, PR 25), so `region` resolves by instruction name through
+  the scope maps of the registered programs
+  (`telemetry.perf.scope_maps`): the LM step's `lm.*` regions, the GBDT
+  tree build's `gbdt.*`. What no map places is `UNSCOPED` and reported as
+  the `telemetry.profile.unscoped_share` gauge.
 - **RooflineLedger**: joins per-region measured time (device-plane
   self-time when a parse provided it, host-noted wall otherwise) with
   `CompileLog` cost analysis into achieved FLOP/s and HBM bytes/s
@@ -45,14 +47,15 @@ the ROADMAP item-4 autotuner's measured rows.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import glob
-import gzip
 import json
 import os
 import re
 import shutil
+import statistics
 import sys
 import threading
 import time
@@ -68,14 +71,18 @@ PROFILE_DIR_ENV = "MMLSPARK_TPU_PROFILE_DIR"
 PROFILE_MS_ENV = "MMLSPARK_TPU_PROFILE_MS"
 PEAK_HBM_ENV = "MMLSPARK_TPU_PEAK_HBM_GBPS"
 
-# Canonical trace-annotation region names: what the parser attributes
-# per-op device time to, and the keys of the roofline ledger / the
-# op.<region>.* gauges. The GBDT tree build stamps its three phases with
-# jax.named_scope (trace-time: the names ride the compiled ops' metadata
-# into the device planes); host-side hot paths stamp
-# utils.tracing.annotate (TraceAnnotation + host wall note).
-REGIONS = ("gbdt.hist", "gbdt.split", "gbdt.route",
-           "serving.plan.run", "train.step")
+# The region vocabulary (names and meanings in telemetry/names.py): what
+# the parser attributes per-op device time to, and the keys of the roofline
+# ledger / the op.<region>.* gauges. Device code stamps a region with
+# jax.named_scope (trace-time only: the name lands in the compiled
+# instructions' `op_name` metadata, which `telemetry.perf.scope_map` joins
+# to a capture's events by instruction name; the events themselves carry
+# the instruction's text and nothing of the scope). Host-side layer
+# boundaries stamp utils.tracing.annotate (TraceAnnotation on the
+# profiler's clock + host wall note).
+REGIONS = (*tnames.DEVICE_REGIONS, *tnames.HOST_REGIONS,
+           tnames.SERVING_PLAN_RUN_SPAN, tnames.TRAIN_STEP_SPAN)
+RING = 256     # durations kept per region
 
 # per-chip peaks (bf16 TFLOP/s, HBM GB/s) keyed on device_kind
 # substrings — the StepClock-style fallback when no env override is set.
@@ -168,88 +175,110 @@ def resolve_peaks(peaks: Optional[dict] = None) -> dict:
 
 # ----------------------------------------------------------- trace parse
 _MAX_OP_RECORDS = 512
+# what `parse_trace` calls device time that no scope map puts in a region;
+# it is reported (the `telemetry.profile.unscoped_share` gauge), never
+# folded into a region, and the ledger does not ingest it
+UNSCOPED = "unscoped"
+_OPS_LINE = "XLA Ops"
+_INSTRUCTION_RE = re.compile(r"^%?([^\s=]+) = ")
 
 
-def _trace_files(log_dir: str) -> list:
-    """The capture's ``*.trace.json.gz`` files, newest profile run first
-    (jax writes ``plugins/profile/<timestamp>/<host>.trace.json.gz``)."""
+def _xplane_files(log_dir: str) -> list:
+    """The capture's ``*.xplane.pb`` files, newest profile run first (jax
+    writes ``plugins/profile/<timestamp>/<host>.xplane.pb``)."""
     runs = sorted(glob.glob(os.path.join(
         log_dir, "plugins", "profile", "*")), reverse=True)
     for run in runs:
-        files = sorted(glob.glob(os.path.join(run, "*.trace.json.gz")))
+        files = sorted(glob.glob(os.path.join(run, "*.xplane.pb")))
         if files:
             return files
     return []
 
 
-def _region_of(name: str, args: Optional[dict]) -> str:
-    """First registered region token found in the op name or its string
-    metadata (named_scope paths ride `long_name`-style args on TPU
-    planes); 'other' when none match."""
-    for region in REGIONS:
-        if region in name:
-            return region
-    if args:
-        for v in args.values():
-            if isinstance(v, str):
-                for region in REGIONS:
-                    if region in v:
-                        return region
-    return "other"
+def instruction_name(event_name: str) -> str:
+    """`%fusion.12 = bf16[...] fusion(...)` -> `fusion.12`: a device
+    event is named by its HLO instruction's text, and the instruction's
+    name is the key of `telemetry.perf.scope_map`."""
+    m = _INSTRUCTION_RE.match(event_name)
+    return m.group(1) if m else event_name
 
 
-def parse_trace(log_dir: str) -> list:
-    """Per-op records from a captured profile's DEVICE planes:
-    ``[{op, region, occurrences, self_time_us}]``, largest self-time
-    first, bounded. NEVER raises (the `executable_analysis` contract):
-    a missing/torn trace file, an unexpected schema, or a backend with
-    no device planes (CPU) all degrade to an empty table field by
-    field."""
+def self_times(events) -> dict:
+    """{event name: [self ns, occurrences]} of one device line's events
+    (`(name, start_ns, duration_ns)`): a `while` or `conditional` event
+    spans its body's events, so an event's own time is its duration less
+    its children's, and the self times add up to the line's busy time."""
+    out: dict = {}
+    stack: list = []     # [end_ns, name, self_ns]
+
+    def close(upto):
+        while stack and stack[-1][0] <= upto:
+            _end, name, own = stack.pop()
+            ent = out.setdefault(name, [0, 0])
+            ent[0] += max(own, 0)
+            ent[1] += 1
+
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        close(start)
+        if stack:
+            stack[-1][2] -= dur
+        stack.append([start + dur, name, dur])
+    close(float("inf"))
+    return out
+
+
+def parse_trace(log_dir: str, scopes: Optional[dict] = None,
+                registry=None) -> list:
+    """Per-op records from a captured profile's DEVICE planes (the
+    ``.xplane.pb`` the profiler writes, line `XLA Ops`):
+    ``[{op, region, direction, occurrences, self_time_us}]``, largest
+    self time first, bounded. `op` is the HLO instruction's name; `region`
+    and `direction` come from the scope maps of the registered programs
+    (`telemetry.perf.scope_maps()`, or `scopes`, a `{label: scope map}`
+    kept from the process that ran, to read a capture elsewhere); an
+    instruction that no map places, or that two programs place
+    differently, is `UNSCOPED`, and its share of the device self time is
+    the `telemetry.profile.unscoped_share` gauge. NEVER raises (the
+    `executable_analysis` contract): a missing or torn file, an
+    unexpected schema, or a backend with no device planes (CPU) all
+    degrade to an empty table."""
+    from .perf import merged_scope_map
     ops: dict = {}
-    for path in _trace_files(log_dir):
+    for path in _xplane_files(log_dir):
         try:
-            with gzip.open(path, "rt") as f:
-                obj = json.load(f)
+            import jax
+            planes = jax.profiler.ProfileData.from_file(path).planes
+            lines = [[(e.name, int(e.start_ns), int(e.duration_ns))
+                      for e in line.events]
+                     for plane in planes if plane.name.startswith("/device:")
+                     for line in plane.lines if line.name == _OPS_LINE]
         except Exception:  # noqa: BLE001 - torn capture: skip the file
             continue
-        events = obj.get("traceEvents") if isinstance(obj, dict) else None
-        if not isinstance(events, list):
-            continue
-        device_pids = set()
-        for e in events:
-            if not isinstance(e, dict) or e.get("ph") != "M":
-                continue
-            if e.get("name") != "process_name":
-                continue
-            pname = str((e.get("args") or {}).get("name", ""))
-            # device planes are named "/device:TPU:0 ..." (the CPU
-            # backend exposes only "/host:CPU" — no device plane, empty
-            # table, the documented degrade)
-            if pname.startswith("/device:"):
-                device_pids.add(e.get("pid"))
-        if not device_pids:
-            continue
-        for e in events:
-            if not isinstance(e, dict) or e.get("ph") != "X":
-                continue
-            if e.get("pid") not in device_pids:
-                continue
-            name = str(e.get("name", ""))
-            dur = e.get("dur")
-            if not isinstance(dur, (int, float)):
-                continue
-            args = e.get("args") if isinstance(e.get("args"), dict) else None
-            key = (name, _region_of(name, args))
-            ent = ops.get(key)
-            if ent is None:
-                ops[key] = ent = {"op": name, "region": key[1],
-                                  "occurrences": 0, "self_time_us": 0.0}
-            ent["occurrences"] += 1
-            ent["self_time_us"] += float(dur)
-    records = sorted(ops.values(),
-                     key=lambda r: (-r["self_time_us"], r["op"]))
-    for r in records:
-        r["self_time_us"] = round(r["self_time_us"], 3)
+        for events in lines:
+            for name, (own, n) in self_times(events).items():
+                ent = ops.setdefault(instruction_name(name), [0, 0])
+                ent[0] += own
+                ent[1] += n
+    if not ops:
+        return []
+    try:
+        placed, _conflicts = merged_scope_map(scopes)
+    except Exception:  # noqa: BLE001 - a capture without maps still parses
+        placed = {}
+    records = []
+    total = unscoped = 0
+    for op, (own, n) in ops.items():
+        region, direction = placed.get(op, (UNSCOPED, None))
+        total += own
+        unscoped += own if region == UNSCOPED else 0
+        records.append({"op": op, "region": region, "direction": direction,
+                        "occurrences": n,
+                        "self_time_us": round(own / 1e3, 3)})
+    if total:
+        (registry if registry is not None else reliability_metrics
+         ).set_gauge(tnames.TELEMETRY_PROFILE_UNSCOPED_SHARE,
+                     unscoped / total)
+    records.sort(key=lambda r: (-r["self_time_us"], r["op"]))
     return records[:_MAX_OP_RECORDS]
 
 
@@ -258,7 +287,7 @@ def region_totals(records: list) -> dict:
     table (what the ledger ingests after a capture)."""
     out: dict = {}
     for r in records:
-        ent = out.setdefault(r.get("region", "other"),
+        ent = out.setdefault(r.get("region", UNSCOPED),
                              {"self_time_us": 0.0, "occurrences": 0})
         ent["self_time_us"] += float(r.get("self_time_us", 0.0))
         ent["occurrences"] += int(r.get("occurrences", 0))
@@ -284,7 +313,8 @@ class RooflineLedger:
         self._compile_log = compile_log
         self._peaks = peaks
         self._lock = threading.Lock()
-        self._host: dict = {}     # region -> [seconds, occurrences]
+        self._host: dict = {}     # region -> [seconds, occurrences, source]
+        self._rings: dict = {}    # region -> the last RING single durations
         self._device: dict = {}   # region -> {"self_time_us", "occurrences"}
         self._ops: list = []      # last parsed per-op table (bounded)
         self._costs: dict = {}    # region -> {"flops", "bytes_accessed"}
@@ -303,13 +333,39 @@ class RooflineLedger:
             ent[0] += s
             ent[1] += int(occurrences)
             ent[2] = str(source)
+            if occurrences == 1:
+                ring = self._rings.get(region)
+                if ring is None:
+                    ring = self._rings[region] = collections.deque(
+                        maxlen=RING)
+                ring.append(s)
+
+    def durations(self, region: str) -> list:
+        """The last `RING` single durations noted for `region`, oldest
+        first."""
+        with self._lock:
+            return list(self._rings.get(region, ()))
+
+    def region_stats(self, region: str) -> Optional[dict]:
+        """{"count", "seconds", "median", "p95"} of a region's host notes
+        (count and seconds over the ledger's life, the quantiles over the
+        ring: a median and a p95 without a sampled Tracer), or None for a
+        region never noted."""
+        with self._lock:
+            ring = sorted(self._rings.get(region, ()))
+            if not ring:
+                return None
+            seconds, count, _source = self._host[region]
+        return {"count": count, "seconds": seconds,
+                "median": statistics.median(ring),
+                "p95": ring[min(len(ring) - 1, int(0.95 * len(ring)))]}
 
     def ingest_ops(self, records: list) -> None:
         """Adopt a parsed per-op table: device-plane region totals
         REPLACE earlier device totals (a capture is a fresh window, not
         a cumulative series)."""
         totals = region_totals(records)
-        totals.pop("other", None)
+        totals.pop(UNSCOPED, None)
         with self._lock:
             self._ops = list(records)
             if totals:
@@ -330,6 +386,7 @@ class RooflineLedger:
     def clear(self) -> None:
         with self._lock:
             self._host.clear()
+            self._rings.clear()
             self._device.clear()
             self._costs.clear()
             self._ops = []
@@ -439,8 +496,19 @@ def get_roofline() -> RooflineLedger:
 
 def note_region(region: str, seconds: float) -> None:
     """Host-wall region note into the process-default ledger
-    (`utils.tracing.annotate` calls this on every region exit)."""
+    (`utils.tracing.annotate` calls this on every region exit): count,
+    total seconds and the ring of the last `RING` durations. A region
+    that is also a timing label (`names.HOST_REGIONS`) lands in
+    `reliability_metrics` too, where `/metrics` and a benchmark driver's
+    difference over its window find it."""
     _default_ledger.note_region(region, seconds)
+    if region in tnames.HOST_REGIONS:
+        reliability_metrics.observe(region, seconds)
+
+
+def region_stats(region: str) -> Optional[dict]:
+    """`RooflineLedger.region_stats` of the process-default ledger."""
+    return _default_ledger.region_stats(region)
 
 
 @contextlib.contextmanager
@@ -625,7 +693,7 @@ class ProfileSession:
             ctx = span.context if span is not None else tracer.current()
             if ctx is not None:
                 _stamp_context(log_dir, ctx, reg)
-            ops = parse_trace(log_dir)
+            ops = parse_trace(log_dir, registry=reg)
             info["ops"] = ops
             info["regions"] = region_totals(ops)
             ledger = self._ledger if self._ledger is not None \

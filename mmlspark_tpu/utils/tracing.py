@@ -46,34 +46,54 @@ def trace(log_dir: str, create_perfetto_link: bool = False):
         yield log_dir
 
 
-@contextlib.contextmanager
-def annotate(name: str):
+_prof = None     # telemetry.profiler, imported at the first annotate
+
+
+class annotate:
     """Named region inside a trace (jax.profiler.TraceAnnotation on the
-    host timeline) that ALSO feeds the roofline ledger: the region's host
-    wall is noted on exit (`telemetry.profiler.note_region`) and any
-    compile recorded inside tags itself with the region — so
-    `roofline.json` carries per-region rows on every backend, refined to
-    device-plane self time where a parse provided it. jax is only
-    touched when already imported (annotating must never pay a cold jax
-    import on a hot path)."""
-    from ..telemetry import profiler as _prof
-    cm = None
-    if "jax" in sys.modules:
+    host timeline, so the span lies on the device trace's clock in any
+    capture, at the cost of a flag test when none runs; `attrs` ride the
+    annotation) that ALSO feeds the roofline ledger: the region's host
+    wall is noted on exit (`telemetry.profiler.note_region`: count, total
+    and a ring of the last durations) and any compile recorded inside
+    tags itself with the region — so `roofline.json` carries per-region
+    rows on every backend, refined to device-plane self time where a
+    parse provided it. jax is only touched when already imported
+    (annotating must never pay a cold jax import on a hot path). A class
+    and not a generator: three of these sit on every LM step."""
+
+    __slots__ = ("name", "_attrs", "_cm", "_token", "_t0")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self._attrs = attrs
+
+    def __enter__(self):
+        global _prof
+        if _prof is None:
+            from ..telemetry import profiler as _prof
+        self._cm = None
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            try:
+                self._cm = jax.profiler.TraceAnnotation(self.name,
+                                                        **self._attrs)
+                self._cm.__enter__()
+            except Exception:  # noqa: BLE001 - a backend without profiler
+                self._cm = None
+        self._token = _prof._region_var.set(self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        _prof._region_var.reset(self._token)
         try:
-            import jax
-            cm = jax.profiler.TraceAnnotation(name)
-        except Exception:  # noqa: BLE001 - a backend without profiler
-            cm = None
-    t0 = time.perf_counter()
-    try:
-        with _prof.region(name):
-            if cm is not None:
-                with cm:
-                    yield
-            else:
-                yield
-    finally:
-        _prof.note_region(name, time.perf_counter() - t0)
+            if self._cm is not None:
+                self._cm.__exit__(*exc)
+        finally:
+            _prof.note_region(self.name, seconds)
+        return False
 
 
 @contextlib.contextmanager

@@ -18,7 +18,6 @@ oldest-first under a byte bound; benchdiff excludes non-TPU rounds from
 perf gates; and the seeded delay-fault acceptance drives
 straggler-flag -> triggered capture -> bundle with roofline.json, with
 the watch-trip and capture events causally ordered in the span log."""
-import gzip
 import json
 import os
 import time
@@ -110,68 +109,96 @@ def _get_json(url, timeout=15):
     return json.loads(urllib.request.urlopen(url, timeout=timeout).read())
 
 
-def _write_trace(log_dir, events, run="run1", host="vm"):
-    d = os.path.join(log_dir, "plugins", "profile", run)
-    os.makedirs(d, exist_ok=True)
-    path = os.path.join(d, f"{host}.trace.json.gz")
-    with gzip.open(path, "wt") as f:
-        json.dump({"traceEvents": events}, f)
-    return path
+_RECORDED = os.path.join(_REPO, "benchmark", "tests", "data")
 
 
-_DEVICE_META = {"ph": "M", "pid": 2, "name": "process_name",
-                "args": {"name": "/device:TPU:0 (core 0)"}}
-_HOST_META = {"ph": "M", "pid": 1, "name": "process_name",
-              "args": {"name": "/host:CPU"}}
+def _capture_dir(tmp_path, kind):
+    """A capture directory of each kind `parse_trace` has to take."""
+    d = tmp_path / kind
+    run = d / "plugins" / "profile" / "run1"
+    if kind == "missing-dir":
+        return d
+    run.mkdir(parents=True)
+    if kind == "torn-file":
+        (run / "vm.xplane.pb").write_bytes(b"not a protobuf at all")
+    elif kind == "no-device-plane":
+        # what the CPU backend writes: host planes only
+        import jax
+        import jax.numpy as jnp
+        jax.profiler.start_trace(str(d))
+        float(jnp.ones((8, 8)).sum())
+        jax.profiler.stop_trace()
+    else:
+        # two steps of the toy LM on a v5e chip (PR 26,
+        # benchmark/tests/record_lm_scoped.py)
+        import shutil
+        shutil.copy(os.path.join(_RECORDED, "lm_scoped.xplane.pb"),
+                    run / "vm.xplane.pb")
+    return d
 
 
 # ------------------------------------------------------------- trace parse
-def test_parse_trace_missing_or_torn_never_raises(tmp_path):
-    assert tprof.parse_trace(str(tmp_path / "nope")) == []
-    # a torn gz file degrades to an empty table, not a raise
-    d = tmp_path / "torn"
-    p = _write_trace(str(d), [])
-    with open(p, "wb") as f:
-        f.write(b"not gzip at all")
-    assert tprof.parse_trace(str(d)) == []
-
-
-def test_parse_trace_aggregates_device_planes_with_regions(tmp_path):
-    events = [
-        _HOST_META, _DEVICE_META,
-        # host-plane events NEVER count (python frames, not device time)
-        {"ph": "X", "pid": 1, "name": "gbdt.hist", "dur": 9999.0},
-        # named_scope path in the op name
-        {"ph": "X", "pid": 2, "name": "gbdt.hist/fusion.1", "dur": 100.0},
-        {"ph": "X", "pid": 2, "name": "gbdt.hist/fusion.1", "dur": 50.0},
-        # region only in metadata args (long-name style)
-        {"ph": "X", "pid": 2, "name": "fusion.7", "dur": 30.0,
-         "args": {"long_name": "jit(tree)/gbdt.split/reduce.2"}},
-        # unattributed device op
-        {"ph": "X", "pid": 2, "name": "copy.3", "dur": 20.0},
-        # malformed rows degrade field-by-field
-        {"ph": "X", "pid": 2, "name": "bad.dur", "dur": "nan?"},
-        "not-a-dict",
-    ]
-    records = tprof.parse_trace(str(_trace_dir(tmp_path, events)))
-    by_op = {r["op"]: r for r in records}
-    assert by_op["gbdt.hist/fusion.1"]["occurrences"] == 2
-    assert by_op["gbdt.hist/fusion.1"]["self_time_us"] == 150.0
-    assert by_op["gbdt.hist/fusion.1"]["region"] == "gbdt.hist"
-    assert by_op["fusion.7"]["region"] == "gbdt.split"
-    assert by_op["copy.3"]["region"] == "other"
-    assert "bad.dur" not in by_op and "gbdt.hist" not in by_op
-    # largest self time first (deterministic ordering)
-    assert records[0]["op"] == "gbdt.hist/fusion.1"
+@pytest.mark.parametrize("kind", ["missing-dir", "torn-file",
+                                  "no-device-plane", "recorded-v5e"])
+def test_parse_trace_reads_the_xplane_and_never_raises(tmp_path, kind):
+    """The xplane path, one parser: a missing or torn capture and a backend
+    without device planes degrade to an empty table; a capture from the
+    chip is attributed by the step program's scope map, by instruction
+    name, and what no map places is reported, not hidden."""
+    with open(os.path.join(_RECORDED, "lm_scoped_scopes.json")) as f:
+        scopes = json.load(f)
+    reg = MetricsRegistry()
+    records = tprof.parse_trace(str(_capture_dir(tmp_path, kind)),
+                                scopes=scopes, registry=reg)
+    share = reg.peek_gauge(tnames.TELEMETRY_PROFILE_UNSCOPED_SHARE)
+    if kind != "recorded-v5e":
+        assert records == [] and share is None
+        return
+    assert records == sorted(records, key=lambda r: (-r["self_time_us"],
+                                                     r["op"]))
     totals = tprof.region_totals(records)
-    assert totals["gbdt.hist"]["self_time_us"] == 150.0
-    assert totals["gbdt.split"]["occurrences"] == 1
+    assert {tnames.LM_MLP, tnames.LM_ATTN, tnames.LM_ATTN_FLASH,
+            tnames.LM_HEAD, tnames.LM_OPT, tnames.LM_EMBED,
+            tnames.LM_CAST} <= set(totals)
+    by_op = {r["op"]: r for r in records}
+    # the kernels carry their own names into the trace
+    flash = {op.split(".")[0] for op, r in by_op.items()
+             if r["region"] == tnames.LM_ATTN_FLASH}
+    assert {"flash_fwd", "flash_dq", "flash_dkv"} <= flash
+    # 2 layers x 2 steps of each kernel
+    assert sum(r["occurrences"] for op, r in by_op.items()
+               if op.startswith("flash_dq")) == 4
+    # the feed-forward forward runs again inside the backward
+    assert {r["direction"] for r in records
+            if r["region"] == tnames.LM_MLP} == {"fwd", "bwd", "remat"}
+    # self times add up to the device's busy time, unscoped included
+    total = sum(v["self_time_us"] for v in totals.values())
+    unscoped = totals.get(tprof.UNSCOPED, {"self_time_us": 0.0})
+    assert share == pytest.approx(unscoped["self_time_us"] / total,
+                                  rel=1e-3)
+    assert 0.0 <= share < 0.5
+    # the ledger takes the regions and leaves the unscoped rest out
+    led = tprof.RooflineLedger(registry=reg)
+    led.ingest_ops(records)
+    rows = led.rows(peaks={"flops_per_s": None, "hbm_bytes_per_s": None})
+    assert rows[tnames.LM_MLP]["source"] == "device"
+    assert tprof.UNSCOPED not in rows
+    # without a map everything is unscoped, and says so
+    bare = tprof.parse_trace(str(tmp_path / kind), scopes={}, registry=reg)
+    assert {r["region"] for r in bare} == {tprof.UNSCOPED}
+    assert reg.peek_gauge(tnames.TELEMETRY_PROFILE_UNSCOPED_SHARE) == 1.0
 
 
-def _trace_dir(tmp_path, events):
-    d = tmp_path / "cap"
-    _write_trace(str(d), events)
-    return d
+def test_self_times_take_children_out_of_their_parents():
+    events = [("%while.1 = while", 0, 100), ("%fusion.2 = fusion", 10, 30),
+              ("%fusion.3 = fusion", 50, 40), ("%copy.4 = copy", 120, 5),
+              ("%fusion.2 = fusion", 130, 30)]
+    own = tprof.self_times(events)
+    assert own == {"%while.1 = while": [30, 1], "%fusion.2 = fusion": [60, 2],
+                   "%fusion.3 = fusion": [40, 1], "%copy.4 = copy": [5, 1]}
+    assert tprof.instruction_name("%fusion.2 = bf16[8] fusion(%a)") == \
+        "fusion.2"
+    assert tprof.instruction_name("no instruction") == "no instruction"
 
 
 # ---------------------------------------------------------- ProfileSession
@@ -333,17 +360,17 @@ def test_roofline_absent_sides_never_guessed():
 def test_roofline_device_records_override_host_walls():
     led = tprof.RooflineLedger()
     led.note_region("gbdt.hist", 5.0, occurrences=3)
-    led.ingest_ops([{"op": "gbdt.hist/fusion.1", "region": "gbdt.hist",
+    led.ingest_ops([{"op": "fusion.1", "region": "gbdt.hist",
                      "occurrences": 7, "self_time_us": 2_000_000.0},
-                    {"op": "copy", "region": "other",
+                    {"op": "copy.2", "region": tprof.UNSCOPED,
                      "occurrences": 1, "self_time_us": 1.0}])
     row = led.rows(peaks={})["gbdt.hist"]
     assert row["source"] == "device"
     assert row["seconds"] == pytest.approx(2.0)
     assert row["occurrences"] == 7
     export = led.export()
-    assert [o["op"] for o in export["ops"]][0] == "gbdt.hist/fusion.1"
-    assert "gbdt.hist" in export["regions"]
+    assert [o["op"] for o in export["ops"]][0] == "fusion.1"
+    assert set(export["regions"]) == {"gbdt.hist"}
 
 
 def test_resolve_peaks_env_order(monkeypatch):
