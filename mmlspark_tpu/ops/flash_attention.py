@@ -15,22 +15,34 @@ Causal masking compares global q/k positions, so it works for any block
 shape. Training: `flash_attention`'s custom VJP is a FLASH BACKWARD — two
 Pallas kernels (dq over a (h, qb, kb) grid; dk/dv over (h, kb, qb))
 recompute each P block from q/k and the forward's saved log-sum-exp, so
-backward memory stays O(block) like the forward. Measured on v5e at 16k
-causal (BENCH_MODE=flash, 25-rep in-graph timing, round 5): bf16 forward
-d=64 8.2 ms = 5.0x dense XLA (33.5 TFLOP/s — the D=64 head dim caps the
-MXU at half its array, ~98 TFLOP/s shape ceiling); d=128 8.3 ms =
-66.2 TFLOP/s = 33.6% of chip bf16 peak (same wall time, twice the FLOPs —
-the 128-lane contraction fully fed); fwd+bwd 20.9 ms either dim (92
-TFLOP/s combined at d128) where the dense backward needs 17+ GB of score
-gradients and OOMs. Perf notes: per-grid-cell overhead dominates below
-1024-wide blocks (see _auto_blocks); 1024x1024 is also the d128 optimum
-(2048-wide blocks fail VMEM compile at d128; 1024x2048 measured 8.39 ms
-— no win); interior blocks skip all mask work; matmuls run in the input
-dtype. `flash_attention_stats`' VJP is ALSO flash (the same two kernels
-with lse := m and dsum := -dl — see _flash_stats_bwd's shift-invariance
-derivation), so context-parallel ring training is O(block) memory in
-both directions. (The timings above are round-5 history: an older JAX and
-device path. PERF.md carries what has been measured since.)
+backward memory stays O(block) like the forward. `flash_attention_stats`'
+VJP is ALSO flash (the same two kernels with lse := m and dsum := -dl — see
+_flash_stats_bwd's shift-invariance derivation), so context-parallel ring
+training is O(block) memory in both directions.
+
+WHAT A CAUSAL CALL EXECUTES (measured on a v5e, PERF.md section 6, PR 27:
+the sweep's table is there). The grid skips a cell wholly above the
+diagonal, which leaves out nothing when a sequence is ONE block long: at
+S = 1024 every (batch, head) is a single 1024 x 1024 cell, and until PR 27
+it ran 9 matmuls (2 forward, 3 dq, 4 dk/dv) over the whole masked square
+where the algorithm needs 6 over the triangle, 3.0x the operations. With
+d_head = 64 every one of those matmuls has 64 as its contraction or its
+output width and feeds half of the 128-wide MXU, so the shape's ceiling is
+about 98 TFLOP/s and the whole-square kernels already ran at 72% to 91% of
+it: there was nothing to tune, only operations to leave out. A cell ON the
+diagonal of a causal self-attention call now walks its tile in four row
+strips of 256 and computes each strip only up to the diagonal (10 of 16
+sub-tiles, `_attend_boundary`); on a one-cell grid the forward also drops the
+online-softmax carry (`single` in _flash_kernel). Per 128 cells of
+1024 x 1024 x 64 bf16: forward 0.53 -> 0.36 ms, dq 0.76 -> 0.56, dk/dv
+1.00 -> 0.73. What did NOT pay, same table: exact sub-tiles (unmasked
+below the diagonal, masked on it: more and smaller matmuls, the forward
+SLOWER than the whole square), a `fori_loop` over sub-tiles (1.3x to 2.5x
+the whole square), and grid blocks of 512 or 256 (1.2x and 2.1x: a grid
+cell's fixed cost). Long sequences keep 1024 blocks: per-grid-cell
+overhead dominates below that (see _auto_blocks), interior cells run
+unmasked and untouched, and only the diagonal cells take the strips (16k
+causal forward + backward 19.2 -> 18.7 ms). Matmuls run in the input dtype.
 
 PRECISION CONTRACT. Softmax statistics and every accumulation are f32.
 The matmul PRODUCTS follow the input dtype (`_mxu_dot`): bf16 q/k/v take
@@ -59,14 +71,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..reliability.metrics import reliability_metrics
+from ..telemetry import names as tnames
+
 BLOCK_Q = 256
 BLOCK_K = 256
-# Measured on v5e (16k causal, H=8 D=64, 25-rep in-graph timing): the
-# kernel is per-grid-cell-overhead-bound at small blocks — 256x256 runs
-# 24 ms forward, 1024x1024 runs 8.5 ms (and 21 ms fwd+bwd vs 59 ms).
-# 2048+ blocks fail to compile (VMEM); the f32 BACKWARD also fails at
-# 1024 (f32 operand blocks double the VMEM footprint), so the backward
-# caps at 512 for f32. _auto_blocks picks these per call.
+# The kernels are per-grid-cell-overhead-bound at small blocks. Measured on
+# a v5e (PR 27, 128 heads x 1024 x 64 bf16 causal, the three kernels
+# together): one 1024 cell a head 2.30 ms, 512 blocks 2.76 ms (3 visible
+# cells of 4, so 75% of the operations in 1.2x the time), 256 blocks
+# 4.73 ms. 2048+ blocks fail to compile (VMEM); the f32 BACKWARD also
+# fails at 1024 (f32 operand blocks double the VMEM footprint), so the
+# backward caps at 512 for f32. _auto_blocks picks these per call.
 _FWD_BLOCK = 1024
 _BWD_BLOCK_BF16 = 1024
 _BWD_BLOCK_F32 = 512
@@ -79,6 +95,17 @@ KERNEL_FWD = "flash_fwd"
 KERNEL_DQ = "flash_dq"
 KERNEL_DKV = "flash_dkv"
 KERNEL_STATS_FWD = "flash_stats_fwd"
+
+# In-cell triangular schedule: a causal cell ON the diagonal walks its
+# (block, block) tile in row strips of this height and computes, of each
+# strip, only the columns up to the diagonal (_attend_boundary). Measured on a
+# v5e (PR 27, PERF.md section 6; 128 cells of 1024 x 1024 x 64 bf16,
+# forward + dq + dk/dv): whole square 2.30 ms; strips of 512 1.78, of 256
+# 1.65, of 128 1.74. Blocks of 512 take it too (one 512 cell a head,
+# 128 heads: 0.89 -> 0.67 ms; S = 1536 as 3 x 3 cells of 512, 64 heads: a
+# wash, 2.53 -> 2.51); blocks of 256 do not (strips of 128 on a 4 x 4 grid
+# of 256-cells: 4.72 -> 4.84 ms).
+_DIAG_TILE = 256
 
 
 def _pick_block(seq: int) -> int:
@@ -104,6 +131,56 @@ def _auto_blocks(seq_q: int, seq_k: int, dtype) -> tuple:
     return bq, bk, min(bq, bwd_cap), min(bk, bwd_cap)
 
 
+def _diag_tile(causal: bool, block_q: int, block_k: int, seq_q: int,
+               seq_k: int, q_offset=0, k_offset=0) -> Optional[int]:
+    """Strip height for the in-cell triangular schedule, or None where the
+    kernel cannot see a static diagonal: the call must be causal
+    self-attention (`seq_q == seq_k`: the cells that are visible and need a
+    mask are then exactly the ones with qb == kb) with square blocks of at
+    least two strips and STATIC zero offsets. Ring attention's traced
+    `axis_index` offsets, cross-attention and non-causal calls keep the
+    whole-cell masked branch."""
+    static0 = all(isinstance(o, int) and o == 0 for o in (q_offset, k_offset))
+    if (causal and static0 and block_q == block_k and seq_q == seq_k
+            and block_q % _DIAG_TILE == 0 and block_q > _DIAG_TILE):
+        return _DIAG_TILE
+    return None
+
+
+def _attend_boundary(tile_fn, block: int, diag_tile: Optional[int]) -> None:
+    """Run `tile_fn(masked, row0, rows, cols)` over a cell that needs its
+    mask. Without a static diagonal that is the whole masked tile. With one
+    (`diag_tile` = t) the cell lies ON the diagonal and is walked in row
+    strips: strip i holds rows [i*t, (i+1)*t) and columns [0, (i+1)*t),
+    masked as a whole (the causal compare only bites in its last t columns,
+    the `seq_end` one wherever keys were padded). The sub-tiles above the
+    diagonal are in no strip, so they cost no matmul, no exp and no mask
+    pass. One wide masked strip a slice beat every finer split on the chip
+    (module docstring)."""
+    if diag_tile is None:
+        tile_fn(True)
+        return
+    for r0 in range(0, block, diag_tile):
+        tile_fn(True, r0, diag_tile, r0 + diag_tile)
+
+
+def _count_tiles(n_q: int, n_k: int, block: int, tile: Optional[int],
+                 kernels: int = 1) -> None:
+    """Trace-time record of the geometry of `kernels` kernel calls, per
+    (batch, head): sub-tiles the program can execute and sub-tiles the
+    in-cell schedule leaves out. Where the schedule does not engage a grid
+    cell is one sub-tile and nothing is left out."""
+    if tile is None:
+        computed, skipped = n_q * n_k, 0
+    else:
+        n = block // tile
+        below = n_q * (n_q - 1) // 2          # cells wholly below the diagonal
+        computed = below * n * n + n_q * (n * (n + 1) // 2)
+        skipped = n_q * (n * (n - 1) // 2)
+    reliability_metrics.inc(tnames.FLASH_TILES_COMPUTED, kernels * computed)
+    reliability_metrics.inc(tnames.FLASH_TILES_SKIPPED, kernels * skipped)
+
+
 def _mxu_dot(a, b, contract, exact: bool):
     """In-kernel matmul with f32 accumulation, contracting dimension
     `contract[0]` of `a` with `contract[1]` of `b`. `exact` is "the
@@ -123,57 +200,56 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   n_k: int, block_q: int, block_k: int, seq_end,
                   causal: bool, scale: float, q_offset=0,
                   k_offset=0, m_out_ref=None, l_out_ref=None,
-                  normalize: bool = True):
+                  normalize: bool = True, diag_tile: Optional[int] = None):
     # q_offset/k_offset/seq_end may be static ints or traced SMEM scalars
     # (ring attention's per-device offsets come from axis_index)
     qb = pl.program_id(1)
     kb = pl.program_id(2)
+    # a static diagonal on a one-step k grid (S <= block): each head's only
+    # cell is the diagonal one and every strip sees all its keys at once,
+    # so the carry and its scratch go unused (measured per 128 cells: 0.64
+    # ms with the carry merged, 0.45 without, 0.36 written straight out;
+    # PERF.md section 6, PR 27)
+    single = diag_tile is not None and n_k == 1
 
-    @pl.when(kb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # causal: a k-block wholly above the diagonal contributes nothing —
-    # skip its matmuls entirely (halves causal compute; DMA still streams
-    # the block, which is bandwidth-trivial next to the MXU work)
-    visible = (not causal) or (k_offset + kb * block_k
-                               <= q_offset + qb * block_q + block_q - 1)
-    # a block needing NO mask at all: every key is < seq_end and (causal)
-    # every q_pos >= k_pos. Interior blocks take the maskless branch —
-    # the iota/compare/where passes over the (Bq, Bk) tile are pure VPU
-    # overhead that only boundary blocks need (at D=64 the kernel is
-    # VPU-bound, so this is a large fraction of inner-loop time)
-    full = k_offset + (kb + 1) * block_k <= seq_end
-    if causal:
-        full = full & (k_offset + (kb + 1) * block_k - 1
-                       <= q_offset + qb * block_q)
-
-    def _attend(masked: bool):
-        # matmuls run in the INPUT dtype with f32 accumulation
-        # (preferred_element_type): bf16 operands use the MXU's full bf16
-        # rate (~4x the f32 rate on v5e) and softmax/l/m math stays f32.
-        q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)   # (Bq, D)
-        k = k_ref[0]                                     # (Bk, D)
-        v = v_ref[0]                                     # (Bk, D)
+    def _attend(masked: bool, r0: int = 0, nr: int = block_q,
+                nc: int = block_k):
+        # rows [r0, r0 + nr) against the first nc keys of the cell's tile,
+        # the whole tile by default. Matmuls run in the INPUT dtype with
+        # f32 accumulation (preferred_element_type): bf16 operands use the
+        # MXU's full bf16 rate (~4x the f32 rate on v5e) and softmax/l/m
+        # math stays f32.
+        rows, cols = pl.ds(r0, nr), pl.ds(0, nc)
+        q = q_ref[0, rows, :] * jnp.asarray(scale, q_ref.dtype)   # (nr, D)
+        k = k_ref[0, cols, :]                                     # (nc, D)
+        v = v_ref[0, cols, :]                                     # (nc, D)
         exact = q_ref.dtype == jnp.float32
         s = _mxu_dot(q, k, (1, 1), exact)
         if masked:
-            # sublane/lane iotas broadcast in the compare: no (Bq, Bk)
+            # sublane/lane iotas broadcast in the compare: no (nr, nc)
             # iota materialization
-            q_pos = q_offset + qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
+            q_pos = q_offset + qb * block_q + r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (nr, 1), 0)
             k_pos = k_offset + kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
+                jnp.int32, (1, nc), 1)
             valid = k_pos < seq_end                   # padded keys drop out
             if causal:
                 valid = valid & (q_pos >= k_pos)
             s = jnp.where(valid, s, -1e30)
 
-        m_prev = m_ref[...]                           # (Bq, 1)
-        l_prev = l_ref[...]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
+        m_blk = jnp.max(s, axis=-1, keepdims=True)    # (nr, 1)
+        if single:
+            # one exact softmax over the strip's keys, written straight out
+            p = jnp.exp(s - m_blk)
+            l_blk = jnp.sum(p, axis=-1, keepdims=True)
+            pv = _mxu_dot(p.astype(v.dtype), v, (1, 0), exact)
+            o_ref[0, rows, :] = (pv / jnp.maximum(l_blk, 1e-30)
+                                 ).astype(o_ref.dtype)
+            m_out_ref[0, rows, :] = m_blk
+            l_out_ref[0, rows, :] = l_blk
+            return
+        m_prev = m_ref[rows, :]
+        l_prev = l_ref[rows, :]
         m_new = jnp.maximum(m_prev, m_blk)
         # NOTE: p is deliberately NOT masked with `valid` here — an extra
         # where on the (Bq, Bk) tile adds measurable inner-loop VPU work at
@@ -185,13 +261,39 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         # ring consumer's merge weight exp(m - m_new) zeroes them. Direct
         # flash_attention_stats callers must treat m == -1e30 rows as
         # "no visible keys" rather than normalizing acc/l.
-        p = jnp.exp(s - m_new)                        # (Bq, Bk)
+        p = jnp.exp(s - m_new)                        # (nr, nc)
         alpha = jnp.exp(m_prev - m_new)               # rescale old carry
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = (acc_ref[...] * alpha
-                        + _mxu_dot(p.astype(v.dtype), v, (1, 0), exact))
-        m_ref[...] = m_new
-        l_ref[...] = l_new
+        acc_ref[rows, :] = (acc_ref[rows, :] * alpha
+                            + _mxu_dot(p.astype(v.dtype), v, (1, 0), exact))
+        m_ref[rows, :] = m_new
+        l_ref[rows, :] = l_new
+
+    if single:
+        _attend_boundary(_attend, block_q, diag_tile)
+        return
+
+    @pl.when(kb == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # causal: a k-block wholly above the diagonal contributes nothing, so
+    # its cell runs no matmul (DMA still streams the block, which is
+    # bandwidth-trivial next to the MXU work). That skips nothing when the
+    # grid has ONE cell a side (S <= block); what is above the diagonal
+    # INSIDE a cell is left out by _attend_boundary
+    visible = (not causal) or (k_offset + kb * block_k
+                               <= q_offset + qb * block_q + block_q - 1)
+    # a block needing NO mask at all: every key is < seq_end and (causal)
+    # every q_pos >= k_pos. Interior blocks take the maskless branch: the
+    # iota/compare/where passes over the (Bq, Bk) tile are VPU work that
+    # only boundary blocks need
+    full = k_offset + (kb + 1) * block_k <= seq_end
+    if causal:
+        full = full & (k_offset + (kb + 1) * block_k - 1
+                       <= q_offset + qb * block_q)
 
     @pl.when(full)
     def _attend_full():
@@ -199,7 +301,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(visible & jnp.logical_not(full))
     def _attend_masked():
-        _attend(masked=True)
+        # with a static diagonal (diag_tile) these are exactly the cells ON
+        # it: they walk their lower triangle, each strip's carry running on
+        # through m/l/acc as it does from cell to cell
+        _attend_boundary(_attend, block_q, diag_tile)
 
     @pl.when(kb == n_k - 1)
     def _finish():
@@ -390,6 +495,8 @@ def _flash_stats_forward(q, k, v, q_offset, k_offset, causal, scale,
     vh = jnp.moveaxis(v, 1, 0)
     h, _, d = qh.shape
     qh, kh, vh, s, sk, n_q, n_k = _pad_blocks(qh, kh, vh, block_q, block_k)
+    # traced offsets: the diagonal is not static, no in-cell schedule
+    _count_tiles(n_q, n_k, block_q, None)
 
     def kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, m_o, l_o,
                acc_ref, m_ref, l_ref):
@@ -441,12 +548,15 @@ def _flash_forward_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     d = q.shape[-1]
     h = q.shape[0]
     q, k, v, s, sk, n_q, n_k = _pad_blocks(q, k, v, block_q, block_k)
+    tile = _diag_tile(causal, block_q, block_k, s, sk)
+    _count_tiles(n_q, n_k, block_q, tile)
 
     def kernel(q_ref, k_ref, v_ref, o_ref, m_o, l_o, acc_ref, m_ref, l_ref):
         _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                       n_k=n_k, block_q=block_q, block_k=block_k,
                       seq_end=sk, causal=causal, scale=scale,
-                      m_out_ref=m_o, l_out_ref=l_o, normalize=True)
+                      m_out_ref=m_o, l_out_ref=l_o, normalize=True,
+                      diag_tile=tile)
 
     out, m, l = pl.pallas_call(
         kernel,
@@ -479,40 +589,47 @@ def _flash_forward_lse(q, k, v, causal, scale, block_q, block_k, interpret):
 
 def _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, qb, kb, *,
                 block_q: int, block_k: int, causal: bool, scale: float,
-                k_end, q_offset, k_offset, masked: bool):
-    """Recompute the (Bq, Bk) probability block and its dS — shared by both
+                k_end, q_offset, k_offset, masked: bool, r0: int, nr: int,
+                nc: int):
+    """Recompute rows [r0, r0 + nr) against the first nc keys of the cell's
+    probability block (the whole (Bq, Bk) block, or one strip of
+    _attend_boundary on a diagonal cell) and its dS — shared by both
     backward kernels so their masking/scaling can never diverge. Matmuls
     run in the input dtype with f32 accumulation (bf16 operands use the
     MXU's bf16 rate); `masked=False` skips the iota/compare/where passes on
     interior blocks, which only boundary blocks need. q_offset/k_offset/
     k_end may be static ints or traced SMEM scalars (the ring stats
-    backward has per-device global offsets, like the forward)."""
-    q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
+    backward has per-device global offsets, like the forward). Returns
+    (p, ds, do, q, k) with q UNSCALED, the operands the callers contract
+    p and ds with."""
+    rows, cols = pl.ds(r0, nr), pl.ds(0, nc)
+    q = q_ref[0, rows, :]
+    k = k_ref[0, cols, :]
+    v = v_ref[0, cols, :]
+    do = do_ref[0, rows, :]
     exact = q_ref.dtype == jnp.float32
-    s = _mxu_dot(q, k, (1, 1), exact)
+    s = _mxu_dot(q * jnp.asarray(scale, q_ref.dtype), k, (1, 1), exact)
     if masked:
-        q_pos = q_offset + qb * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
+        q_pos = q_offset + qb * block_q + r0 + jax.lax.broadcasted_iota(
+            jnp.int32, (nr, 1), 0)
         k_pos = k_offset + kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
+            jnp.int32, (1, nc), 1)
         valid = k_pos < k_end
         if causal:
             valid = valid & (q_pos >= k_pos)
         s = jnp.where(valid, s, -1e30)
     # padded q rows carry lse=+inf (set by the caller) -> p exactly 0
-    p = jnp.exp(s - lse_ref[0])                       # (Bq, Bk)
+    p = jnp.exp(s - lse_ref[0, rows, :])              # (nr, nc)
     dp = _mxu_dot(do, v, (1, 1), exact)
-    ds = p * (dp - dsum_ref[0])                       # (Bq, Bk)
-    return p, ds, do
+    ds = p * (dp - dsum_ref[0, rows, :])              # (nr, nc)
+    return p, ds, do, q, k
 
 
 def _flash_bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                          lse_ref, dsum_ref, dq_ref, acc_ref, *, n_k: int,
                          block_q: int, block_k: int, causal: bool,
-                         scale: float, k_end: int):
+                         scale: float, k_end: int,
+                         diag_tile: Optional[int] = None):
     qb, kb = pl.program_id(1), pl.program_id(2)
     qoff, koff = qoff_ref[0], koff_ref[0]
 
@@ -520,15 +637,15 @@ def _flash_bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _accum(masked: bool):
-        _, ds, _ = _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                               dsum_ref, qb, kb, block_q=block_q,
-                               block_k=block_k, causal=causal, scale=scale,
-                               k_end=koff + k_end, q_offset=qoff,
-                               k_offset=koff, masked=masked)
-        k = k_ref[0]
-        acc_ref[...] += _mxu_dot(ds.astype(k.dtype), k, (1, 0),
-                                 k.dtype == jnp.float32)
+    def _accum(masked: bool, r0: int = 0, nr: int = block_q,
+               nc: int = block_k):
+        _, ds, _, _, k = _bwd_common(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, qb, kb,
+            block_q=block_q, block_k=block_k, causal=causal, scale=scale,
+            k_end=koff + k_end, q_offset=qoff, k_offset=koff, masked=masked,
+            r0=r0, nr=nr, nc=nc)
+        acc_ref[pl.ds(r0, nr), :] += _mxu_dot(ds.astype(k.dtype), k, (1, 0),
+                                              k.dtype == jnp.float32)
 
     full = _bwd_full_t(qb, kb, block_q, block_k, causal, k_end, qoff, koff)
     visible = _bwd_visible_t(qb, kb, block_q, block_k, causal, qoff, koff)
@@ -539,7 +656,7 @@ def _flash_bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(visible & jnp.logical_not(full))
     def _accum_masked():
-        _accum(masked=True)
+        _attend_boundary(_accum, block_q, diag_tile)
 
     @pl.when(kb == n_k - 1)
     def _finish():
@@ -549,7 +666,8 @@ def _flash_bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, k_ref, v_ref, q_ref, do_ref,
                           lse_ref, dsum_ref, dk_ref, dv_ref, dk_acc,
                           dv_acc, *, n_q: int, block_q: int, block_k: int,
-                          causal: bool, scale: float, k_end: int):
+                          causal: bool, scale: float, k_end: int,
+                          diag_tile: Optional[int] = None):
     kb, qb = pl.program_id(1), pl.program_id(2)
     qoff, koff = qoff_ref[0], koff_ref[0]
 
@@ -558,16 +676,17 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, k_ref, v_ref, q_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _accum(masked: bool):
-        p, ds, do = _bwd_common(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                dsum_ref, qb, kb, block_q=block_q,
-                                block_k=block_k, causal=causal, scale=scale,
-                                k_end=koff + k_end, q_offset=qoff,
-                                k_offset=koff, masked=masked)
-        q = q_ref[0]
+    def _accum(masked: bool, r0: int = 0, nr: int = block_q,
+               nc: int = block_k):
+        p, ds, do, q, _ = _bwd_common(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, qb, kb,
+            block_q=block_q, block_k=block_k, causal=causal, scale=scale,
+            k_end=koff + k_end, q_offset=qoff, k_offset=koff, masked=masked,
+            r0=r0, nr=nr, nc=nc)
         exact = q.dtype == jnp.float32
-        dv_acc[...] += _mxu_dot(p.astype(do.dtype), do, (0, 0), exact)
-        dk_acc[...] += _mxu_dot(ds.astype(q.dtype), q, (0, 0), exact)
+        cols = pl.ds(0, nc)
+        dv_acc[cols, :] += _mxu_dot(p.astype(do.dtype), do, (0, 0), exact)
+        dk_acc[cols, :] += _mxu_dot(ds.astype(q.dtype), q, (0, 0), exact)
 
     full = _bwd_full_t(qb, kb, block_q, block_k, causal, k_end, qoff, koff)
     visible = _bwd_visible_t(qb, kb, block_q, block_k, causal, qoff, koff)
@@ -578,7 +697,7 @@ def _flash_bwd_dkv_kernel(qoff_ref, koff_ref, k_ref, v_ref, q_ref, do_ref,
 
     @pl.when(visible & jnp.logical_not(full))
     def _accum_masked():
-        _accum(masked=True)
+        _attend_boundary(_accum, block_q, diag_tile)
 
     @pl.when(qb == n_q - 1)
     def _finish():
@@ -629,6 +748,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     s_q = q.shape[1]
     sk = k.shape[1]
     q_p, k_p, v_p, _, _, n_q, n_k = _pad_blocks(q, k, v, block_q, block_k)
+    tile = _diag_tile(causal, block_q, block_k, s_q, sk, q_offset, k_offset)
+    _count_tiles(n_q, n_k, block_q, tile, kernels=2)     # dq and dk/dv
     pad_q = q_p.shape[1] - s_q
     g_p = jnp.pad(g, ((0, 0), (0, pad_q), (0, 0))) if pad_q else g
     if dsum is None:
@@ -651,7 +772,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_k=n_k, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale,
-                          k_end=sk),
+                          k_end=sk, diag_tile=tile),
         grid=(h, n_q, n_k),
         in_specs=[smem, smem, row_spec_q, col_spec_k, col_spec_k,
                   row_spec_q, one_spec_q, one_spec_q],
@@ -669,7 +790,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     one_spec_qb = pl.BlockSpec((1, block_q, 1), lambda hh, kb, qb: (hh, qb, 0))
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, n_q=n_q, block_q=block_q, block_k=block_k,
-        causal=causal, scale=scale, k_end=sk)
+        causal=causal, scale=scale, k_end=sk, diag_tile=tile)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(h, n_k, n_q),
@@ -730,10 +851,12 @@ def flash_attention(q, k, v, causal: bool = False,
     """Exact attention without the (S, S) HBM score matrix.
 
     q: (S, H, D); k/v: (Sk, H, D). Returns (S, H, D), same dtype as q.
-    block_q/block_k default to a measured-on-v5e auto choice (1024 for
-    long sequences; the BACKWARD internally caps at 512 for f32 operands,
-    which exceed VMEM at 1024). `interpret` defaults to True off-TPU so
-    tests run anywhere.
+    block_q/block_k default to a measured-on-v5e auto choice (the largest
+    of 1024 / 512 / 256 that pads the sequence by under 20%; the BACKWARD
+    internally caps at 512 for f32 operands, which exceed VMEM at 1024).
+    A causal self-attention call computes each diagonal cell only up to
+    the diagonal (module docstring). `interpret` defaults to True off-TPU
+    so tests run anywhere.
     """
     if scale is None:
         scale = 1.0 / float(np.sqrt(q.shape[-1]))

@@ -79,6 +79,8 @@ TELEMETRY_PROFILE_CAPTURES = "telemetry.profile.captures"
 TELEMETRY_PROFILE_SUPPRESSED = "telemetry.profile.suppressed"
 TELEMETRY_PROFILE_STAMP_ERRORS = "telemetry.profile.stamp_errors"
 LM_STEP_COMPILES = "lm.step.compiles"
+FLASH_TILES_COMPUTED = "flash.tiles.computed"
+FLASH_TILES_SKIPPED = "flash.tiles.skipped"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -202,6 +204,15 @@ COUNTERS = {
     LM_STEP_COMPILES: "compilations of PipelinedLMTrainer's own step "
                       "program, seen during lm.step.dispatch (a new shape, "
                       "or the second step's donated layouts)",
+    FLASH_TILES_COMPUTED: "sub-tiles a traced flash kernel call can execute, "
+                          "per (batch, head), recorded at trace time: the "
+                          "lower triangle of each diagonal cell plus every "
+                          "cell below it where the in-cell causal schedule "
+                          "engages, else one per grid cell",
+    FLASH_TILES_SKIPPED: "sub-tiles above the diagonal that the in-cell "
+                         "causal schedule of a traced flash kernel call "
+                         "leaves out (0 for non-causal, cross-attention and "
+                         "traced-offset ring calls)",
     QUALITY_LABELS_JOINED: "delayed labels joined to their served "
                            "prediction (streaming evaluation pairs)",
     QUALITY_LABELS_LATE: "out-of-order labels that arrived BEFORE their "

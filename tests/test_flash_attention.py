@@ -308,3 +308,156 @@ def test_flash_backward_through_jit_and_composition():
     g = jax.grad(loss)(wq)
     assert np.isfinite(np.asarray(g)).all()
     assert float(jnp.abs(g).max()) > 0
+
+
+# ---------------------------------------------------------------------------
+# In-cell triangular schedule (PR 27): a diagonal cell of a causal
+# self-attention call computes row strips up to the diagonal only.
+
+def _tiles_delta(fn):
+    """(computed, skipped) that `fn` adds to the flash.tiles.* counters,
+    which the kernels' wrappers bump while a call is TRACED."""
+    from mmlspark_tpu.reliability.metrics import reliability_metrics
+    from mmlspark_tpu.telemetry import names as tnames
+    before = (reliability_metrics.get(tnames.FLASH_TILES_COMPUTED),
+              reliability_metrics.get(tnames.FLASH_TILES_SKIPPED))
+    out = fn()
+    return out, (reliability_metrics.get(tnames.FLASH_TILES_COMPUTED)
+                 - before[0],
+                 reliability_metrics.get(tnames.FLASH_TILES_SKIPPED)
+                 - before[1])
+
+
+# (s, sk, block, dtype, causal, sub-tiles (computed, skipped) of forward +
+# dq + dk/dv). Blocks of 512 are two strips of 256 a diagonal cell: 3
+# sub-tiles computed and 1 left out, and 4 for a cell below the diagonal.
+_DIAG_CASES = {
+    # the schedule engages
+    "one-cell": (512, 512, 512, "float32", True, (9, 3)),
+    "one-cell-bf16": (512, 512, 512, "bfloat16", True, (9, 3)),
+    "one-cell-padded": (400, 400, 512, "float32", True, (9, 3)),
+    "two-cells": (1024, 1024, 512, "float32", True, (30, 6)),
+    "two-cells-padded": (700, 700, 512, "float32", True, (30, 6)),
+    "three-cells": (1536, 1536, 512, "float32", True, (63, 9)),
+    "three-cells-bf16": (1536, 1536, 512, "bfloat16", True, (63, 9)),
+    "auto-1024-bf16": (1024, 1024, None, "bfloat16", True, (30, 18)),
+    # f32 at the auto blocks: forward one 1024 cell (10 + 6), backward 2 x 2
+    # cells of 512 in each kernel (2 x 10 + 2 x 2)
+    "auto-1024-f32": (1024, 1024, None, "float32", True, (30, 10)),
+    # and the calls that must keep the whole-cell path
+    "non-causal": (512, 512, 512, "float32", False, (3, 0)),
+    "cross": (300, 512, 512, "float32", False, (3, 0)),
+    "cross-causal": (512, 700, 512, "float32", True, (6, 0)),
+    "blocks-of-256": (512, 512, 256, "float32", True, (12, 0)),
+    "unequal-blocks": (512, 512, (512, 256), "float32", True, (6, 0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DIAG_CASES))
+def test_diagonal_cell_schedule_parity(case):
+    """Forward and q/k/v gradients against the dense f32 reference, with
+    the flash.tiles.* counters saying whether the in-cell schedule engaged:
+    one, two and three cells a side (the carry across cells, diagonal cells
+    next to interior ones), padded lengths (the `seq_end` mask inside a
+    diagonal strip), bf16 and f32, and the calls that keep the old path."""
+    s, sk, block, dtype, causal, tiles = _DIAG_CASES[case]
+    bq, bk = block if isinstance(block, tuple) else (block, block)
+    rng = np.random.default_rng(11)
+    h, d = 2, 32
+    qf = rng.normal(size=(s, h, d)).astype(np.float32)
+    kf = rng.normal(size=(sk, h, d)).astype(np.float32)
+    vf = rng.normal(size=(sk, h, d)).astype(np.float32)
+    w = jnp.asarray(rng.normal(size=(s, h, d)), jnp.float32)
+    q, k, v = (jnp.asarray(a, dtype) for a in (qf, kf, vf))
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    def ref_loss(q, k, v):
+        # dense f32 softmax attention; positions from zero on both sides,
+        # so it also covers causal calls with s != sk
+        sc = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(d)
+        if causal:
+            sc = jnp.where(jnp.arange(s)[:, None] >= jnp.arange(sk)[None],
+                           sc, -1e30)
+        out = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(sc, axis=-1), v)
+        return (out * w).sum(), out
+
+    (g, out), seen = _tiles_delta(
+        lambda: jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v))
+    assert seen == tiles
+    gr, ref = jax.grad(ref_loss, argnums=(0, 1, 2), has_aux=True)(
+        jnp.asarray(qf), jnp.asarray(kf), jnp.asarray(vf))
+    tol_out, tol_grad = (2e-5, 2e-4) if dtype == "float32" else (3e-2, 5e-2)
+    assert out.dtype == q.dtype
+    err = float(jnp.abs(out.astype(jnp.float32) - ref).max()
+                / jnp.abs(ref).max())
+    assert err < tol_out, err
+    for name, a, b in zip("qkv", g, gr):
+        err = float(jnp.abs(a.astype(jnp.float32) - b).max()
+                    / jnp.abs(b).max())
+        assert err < tol_grad, (name, err)
+
+
+def test_stats_with_traced_offsets_keep_whole_cell_path():
+    """Ring attention's offsets come from `axis_index`: traced, so the
+    diagonal is not static and `flash_attention_stats` keeps the whole-cell
+    masked branch in both directions (skipped stays 0) with the numbers of
+    the dense stats reference."""
+    from mmlspark_tpu.ops.flash_attention import (_stats_xla_reference,
+                                                  flash_attention_stats)
+    rng = np.random.default_rng(4)
+    s, h, d = 512, 2, 32
+    q, k, v, w = (jnp.asarray(rng.normal(size=(s, h, d)), jnp.float32)
+                  for _ in range(4))
+
+    def consumer(acc, m, l):
+        wgt = jnp.exp(jnp.minimum(m, 50.0))
+        num = jnp.moveaxis(acc, 0, 1) * wgt[..., None]
+        den = l * wgt + 1e-9
+        return (jnp.moveaxis(num / den[..., None], 0, 1) * w).sum()
+
+    @jax.jit
+    def grads(q, k, v, q_off, k_off):
+        return jax.grad(lambda q, k, v: consumer(*flash_attention_stats(
+            q, k, v, q_off, k_off, causal=True, scale=0.125,
+            block_q=512, block_k=512)), argnums=(0, 1, 2))(q, k, v)
+
+    g, seen = _tiles_delta(lambda: grads(q, k, v, jnp.int32(0), jnp.int32(0)))
+    assert seen == (3, 0)
+    gd = jax.grad(lambda q, k, v: consumer(*_stats_xla_reference(
+        q, k, v, 0, 0, True, 0.125)), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g, gd):
+        rel = float(jnp.abs(a - b).max()) / (float(jnp.abs(b).max()) + 1e-9)
+        assert rel < 2e-4, (name, rel)
+
+
+def test_tiles_counter_reads_the_triangle():
+    """Tracing (nothing runs) the cell's attention call, causal 1024 x 1024
+    bf16 forward + backward, counts 10 of 16 sub-tiles of 256 in each of
+    the three kernels; a 16k forward counts its 120 interior cells whole
+    and its 16 diagonal ones by the triangle; a non-causal call and a
+    stats call leave nothing out."""
+    from mmlspark_tpu.ops.flash_attention import (_DIAG_TILE,
+                                                  flash_attention_stats)
+    assert _DIAG_TILE == 256
+    x = jax.ShapeDtypeStruct((1024, 2, 64), jnp.bfloat16)
+
+    def grad_of(causal):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))
+
+    _, seen = _tiles_delta(lambda: jax.make_jaxpr(grad_of(True))(x, x, x))
+    assert seen == (3 * 10, 3 * 6)
+    _, seen = _tiles_delta(lambda: jax.make_jaxpr(grad_of(False))(x, x, x))
+    assert seen == (3, 0)
+    x16 = jax.ShapeDtypeStruct((16384, 2, 64), jnp.bfloat16)
+    _, seen = _tiles_delta(lambda: jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, causal=True))(x16, x16, x16))
+    assert seen == (120 * 16 + 16 * 10, 16 * 6)
+    _, seen = _tiles_delta(lambda: jax.make_jaxpr(
+        lambda q, k, v: flash_attention_stats(
+            q, k, v, 0, 0, causal=True, scale=0.125))(x, x, x))
+    assert seen == (1, 0)
