@@ -1,0 +1,207 @@
+"""Layers of the hybrid decoder family (zero-centred RMSNorm, gated
+grouped-KV attention with partial rotary embedding, Gated DeltaNet, sparse
+experts with a shared expert), on (B, S, d) activations, as
+`PipelinedLMTrainer` runs them. `lm_spec.py` says which of them a model's
+period is made of; `benchmark/reference/qwen3_next.py` has the same
+equations in plain float32.
+
+Mixed precision as in the dense block: matmul operands in the activations'
+dtype with float32 accumulation; norms, rotary angles, gates' decay, L2
+normalisation, the convolution's sum and every softmax in float32.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ...ops.gated_delta import chunk_gated_delta_rule
+from ...telemetry import names as tnames
+from .moe import moe_layer
+
+# leaves the trainer's per-step cast leaves in float32: vectors and the
+# convolution taps, whose arithmetic is float32 anyway
+F32_LEAVES = frozenset({"norm_in", "norm_post", "final_norm", "q_norm",
+                        "k_norm", "norm", "A_log", "dt_bias", "conv"})
+
+
+def rms_norm(x, w, eps: float):
+    """x * rsqrt(mean(x^2) + eps) * (1 + w), float32 inside."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def rotary(x, theta: float, rot: int):
+    """Rotary embedding of positions 0 .. S-1 on the first `rot` of the
+    last dimension of x (B, S, H, D); half-split ("rotate_half")."""
+    seq = x.shape[1]
+    inv = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    xr = x[..., :rot].astype(jnp.float32)
+    half = jnp.concatenate([-xr[..., rot // 2:], xr[..., :rot // 2]], -1)
+    out = xr * jnp.cos(ang) + half * jnp.sin(ang)
+    return jnp.concatenate([out.astype(x.dtype), x[..., rot:]], axis=-1)
+
+
+def _matmul(x, w):
+    return jnp.einsum("...d,df->...f", x, w,
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def attention_mixer(x, p, a, eps: float, attention: str):
+    """Gated grouped-KV attention on normed x (B, S, d). `a`: the spec's
+    GatedAttention. Grouped KV reaches the kernels by repeating K and V."""
+    from ...ops.flash_attention import flash_attention
+    from ...parallel.ring_attention import reference_attention
+    b, s, _ = x.shape
+    h, kv, d = a.n_heads, a.n_kv_heads, a.head_dim
+    qg = _matmul(x, p["q_proj"]).reshape(b, s, h, 2 * d)
+    q, gate = qg[..., :d], qg[..., d:].reshape(b, s, h * d)
+    k = _matmul(x, p["k_proj"]).reshape(b, s, kv, d)
+    v = _matmul(x, p["v_proj"]).reshape(b, s, kv, d)
+    q = rotary(rms_norm(q, p["q_norm"], eps), a.rope_theta, a.rotary_dim)
+    k = rotary(rms_norm(k, p["k_norm"], eps), a.rope_theta, a.rotary_dim)
+    k = jnp.repeat(k, h // kv, axis=2)
+    v = jnp.repeat(v, h // kv, axis=2)
+    with jax.named_scope(tnames.LM_ATTN_FLASH):
+        # the batch rides on the kernels' head axis: (S, B x H, D). Under a
+        # `vmap` the kernels' instructions would be named `vmap_flash_fwd_`
+        # and the readers that match them by name would find nothing
+        def heads(t):
+            return jnp.moveaxis(t, 0, 1).reshape(s, b * h, d)
+        attend = flash_attention if attention == "flash" \
+            else reference_attention
+        out = attend(heads(q), heads(k), heads(v), causal=True)
+        out = jnp.moveaxis(out.reshape(s, b, h, d), 0, 1)
+    gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(x.dtype)
+    return _matmul(out.reshape(b, s, h * d) * gate, p["o_proj"])
+
+
+def gdn_mixer(x, p, g, eps: float):
+    """Gated DeltaNet on normed x (B, S, d). `g`: the spec's
+    GatedDeltaNet."""
+    b, s, _ = x.shape
+    hk, hv, dk, dv = g.n_key_heads, g.n_value_heads, g.key_dim, g.value_dim
+    f32 = jnp.float32
+    qkvz = _matmul(x, p["in_proj_qkvz"])
+    n_qkv = 2 * hk * dk + hv * dv
+    z = qkvz[..., n_qkv:].reshape(b, s, hv, dv)
+    ba = jnp.einsum("bsd,df->bsf", x, p["in_proj_ba"],
+                    preferred_element_type=f32)
+    padded = jnp.pad(qkvz[..., :n_qkv],
+                     ((0, 0), (g.conv_width - 1, 0), (0, 0)))
+    taps = p["conv"].astype(f32)
+    qkv = jax.nn.silu(sum(padded[:, j:j + s].astype(f32) * taps[j]
+                          for j in range(g.conv_width)))
+
+    def l2(t):
+        return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+
+    q = l2(qkv[..., :hk * dk].reshape(b, s, hk, dk)) * dk ** -0.5
+    k = l2(qkv[..., hk * dk:2 * hk * dk].reshape(b, s, hk, dk))
+    q = jnp.repeat(q.astype(x.dtype), hv // hk, axis=2)
+    k = jnp.repeat(k.astype(x.dtype), hv // hk, axis=2)
+    v = qkv[..., 2 * hk * dk:].reshape(b, s, hv, dv).astype(x.dtype)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    decay = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+        ba[..., hv:] + p["dt_bias"].astype(f32))
+    with jax.named_scope(tnames.LM_GDN_SCAN):
+        o = jax.vmap(chunk_gated_delta_rule)(q, k, v, decay, beta)
+    o32 = o.astype(f32)
+    o32 = o32 * jax.lax.rsqrt((o32 * o32).mean(-1, keepdims=True) + eps)
+    o = (o32 * p["norm"].astype(f32)).astype(x.dtype) \
+        * jax.nn.silu(z.astype(f32)).astype(x.dtype)
+    return _matmul(o.reshape(b, s, hv * dv), p["out_proj"])
+
+
+def hybrid_layer(h, lp, kind: str, spec, attention: str, remat: bool):
+    """One layer on h (B, S, d): h + mixer(norm_in(h)), then
+    h + experts(norm_post(h)). Returns (h, the expert layer's stats)."""
+    eps = spec.norm_eps
+
+    def mix(h, lp):
+        if kind == "attention":
+            with jax.named_scope(tnames.LM_ATTN):
+                return h + attention_mixer(
+                    rms_norm(h, lp["norm_in"], eps), lp["mixer"],
+                    spec.attention, eps, attention)
+        with jax.named_scope(tnames.LM_GDN):
+            return h + gdn_mixer(rms_norm(h, lp["norm_in"], eps),
+                                 lp["mixer"], spec.delta_net, eps)
+
+    def experts(h, lp):
+        e = spec.experts
+        with jax.named_scope(tnames.LM_MOE_ROUTER):
+            y = rms_norm(h, lp["norm_post"], eps)
+        out, stats = moe_layer(y.reshape(-1, y.shape[-1]), lp["moe"],
+                               e.top_k, e.held, e.renormalize)
+        with jax.named_scope(tnames.LM_MOE_SHARED):
+            return h + out.reshape(h.shape), stats
+
+    if remat:
+        mix, experts = jax.checkpoint(mix), jax.checkpoint(experts)
+    return experts(mix(h, lp), lp)
+
+
+def init_hybrid(spec, seed: int) -> dict:
+    """Seeded host weights of a hybrid model: normal(0, init_std) matrices,
+    zero-centred norms at 0, the gated output norm at 1, `A_log` the log of
+    uniform(1, 16) and `dt_bias` the inverse softplus of a step
+    log-uniform in [1e-3, 1e-1] (the family's habit; the configuration
+    file lists them as assumed)."""
+    rng = np.random.default_rng(seed)
+    d, std = spec.d_model, spec.init_std
+
+    def dense(*shape):
+        return rng.standard_normal(shape, dtype=np.float32) * np.float32(std)
+
+    def zeros(*shape):
+        return np.zeros(shape, np.float32)
+
+    def moe():
+        e = spec.experts
+        n = e.held[1] - e.held[0]
+        return {"router": dense(d, e.n_experts),
+                "w_gate": dense(n, d, e.width), "w_up": dense(n, d, e.width),
+                "w_down": dense(n, e.width, d),
+                "shared_gate": dense(d, e.shared_width),
+                "shared_up": dense(d, e.shared_width),
+                "shared_down": dense(e.shared_width, d),
+                "shared_expert_gate": dense(d, 1)}
+
+    def mixer(kind):
+        if kind == "attention":
+            a = spec.attention
+            return {"q_proj": dense(d, a.n_heads * 2 * a.head_dim),
+                    "k_proj": dense(d, a.n_kv_heads * a.head_dim),
+                    "v_proj": dense(d, a.n_kv_heads * a.head_dim),
+                    "q_norm": zeros(a.head_dim), "k_norm": zeros(a.head_dim),
+                    "o_proj": dense(a.n_heads * a.head_dim, d)}
+        g = spec.delta_net
+        n_qkv = 2 * g.n_key_heads * g.key_dim + g.n_value_heads * g.value_dim
+        step = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                  g.n_value_heads))
+        return {"in_proj_qkvz": dense(
+                    d, n_qkv + g.n_value_heads * g.value_dim),
+                "in_proj_ba": dense(d, 2 * g.n_value_heads),
+                "conv": dense(g.conv_width, n_qkv) * np.float32(
+                    1.0 / (std * np.sqrt(g.conv_width))),
+                "A_log": np.log(rng.uniform(1.0, 16.0, g.n_value_heads)
+                                ).astype(np.float32),
+                "dt_bias": (step + np.log(-np.expm1(-step))
+                            ).astype(np.float32),
+                "norm": np.ones(g.value_dim, np.float32),
+                "out_proj": dense(g.n_value_heads * g.value_dim, d)}
+
+    def stacked(kind):
+        layers = [{"norm_in": zeros(d), "norm_post": zeros(d),
+                   "mixer": mixer(kind), "moe": moe()}
+                  for _ in range(spec.n_periods)]
+        return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *layers)
+
+    return {"embed": dense(spec.vocab_size, d),
+            "head": dense(spec.vocab_size, d),
+            "final_norm": zeros(d),
+            "layers": [stacked(kind) for kind in spec.period]}

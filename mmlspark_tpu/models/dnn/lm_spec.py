@@ -1,0 +1,155 @@
+"""The description of a decoder that `PipelinedLMTrainer` trains.
+
+A model is `n_periods` repetitions of a PERIOD: a fixed sequence of layer
+kinds in their published order. The trainer stacks parameters by position
+in the period (every leaf gains a leading `n_periods` axis, which is the
+axis the pipeline shards) and scans periods. A dense GPT-2 block is a period
+of one layer; a hybrid decoder's period is, for example, three Gated
+DeltaNet layers and one full-attention layer.
+
+Layer kinds:
+  "dense"      LayerNorm, full multi-head attention, GELU MLP, learned
+               positions, head tied to the embedding (`pp_training._block`)
+  "gdn"        zero-centred RMSNorm, Gated DeltaNet, sparse experts
+  "attention"  zero-centred RMSNorm, gated grouped-KV attention with partial
+               rotary embedding, sparse experts   (`hybrid_layers`)
+
+What a description cannot say yet is in ROADMAP.md (queue R and D2).
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedAttention:
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    rope_theta: float
+    rotary_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaNet:
+    n_key_heads: int
+    n_value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """`held` is the contiguous range [lo, hi) of the `n_experts` that this
+    chip holds; the router is `n_experts` wide whatever is held."""
+    n_experts: int
+    top_k: int
+    width: int
+    shared_width: int
+    held: tuple
+    renormalize: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
+class LMSpec:
+    vocab_size: int
+    d_model: int
+    period: tuple                 # layer kinds, in order
+    n_periods: int
+    # the dense block's sizes
+    n_heads: int = 0
+    d_ff: int = 0
+    max_len: int = 0              # learned positions; 0 = none
+    # the hybrid layers' sizes
+    norm_eps: float = 1e-6
+    attention: Optional[GatedAttention] = None
+    delta_net: Optional[GatedDeltaNet] = None
+    experts: Optional[Experts] = None
+    init_std: float = 0.02
+
+    def __post_init__(self):
+        kinds = set(self.period)
+        if not self.period or not kinds <= {"dense", "gdn", "attention"}:
+            raise ValueError(f"period {self.period!r}: layer kinds are "
+                             f"dense | gdn | attention")
+        if "dense" in kinds and kinds != {"dense"}:
+            raise ValueError("a dense block does not mix with hybrid layers "
+                             "in one period")
+        if self.n_periods < 1:
+            raise ValueError(f"n_periods must be >= 1, got {self.n_periods}")
+        if self.hybrid:
+            missing = [name for name, part in (
+                ("attention", self.attention), ("gdn", self.delta_net))
+                if name in kinds and part is None]
+            if missing or self.experts is None:
+                raise ValueError(f"a hybrid period needs its "
+                                 f"{missing + ['experts']} sizes")
+            lo, hi = self.experts.held
+            if not 0 <= lo < hi <= self.experts.n_experts:
+                raise ValueError(f"experts held {self.experts.held} is no "
+                                 f"range of {self.experts.n_experts}")
+
+    @property
+    def hybrid(self) -> bool:
+        return "dense" not in self.period
+
+    @property
+    def meta(self) -> dict:
+        """What a checkpoint must agree on to be resumed."""
+        if not self.hybrid:
+            return {"n_heads": self.n_heads, "d_model": self.d_model}
+        return {"d_model": self.d_model, "period": "/".join(self.period),
+                "n_periods": self.n_periods,
+                "experts_held": list(self.experts.held)}
+
+
+def gpt2_spec(vocab_size: int, d_model: int, n_heads: int, n_layers: int,
+              d_ff: int, max_len: int) -> LMSpec:
+    """The dense block `PipelinedLMTrainer` has always trained, as a
+    description: a period of one layer, `n_layers` periods."""
+    return LMSpec(vocab_size=vocab_size, d_model=d_model, period=("dense",),
+                  n_periods=n_layers, n_heads=n_heads, d_ff=d_ff,
+                  max_len=max_len)
+
+
+def qwen3_next_spec(cfg: dict, experts_held: tuple,
+                    n_experts: Optional[int] = None,
+                    n_periods: Optional[int] = None) -> LMSpec:
+    """A `qwen3_next` config.json (Hugging Face keys) as a description.
+    `cfg["vocab_size"]` is the vocabulary held here (a slice is a smaller
+    vocabulary); `n_experts` is the router's width (the published count;
+    default `cfg["num_experts"]`) and `experts_held` this chip's range of
+    them; `n_periods` defaults to `num_layers` (the layers held here, else
+    `num_hidden_layers`) over `full_attention_interval`."""
+    interval = cfg["full_attention_interval"]
+    layers = cfg.get("num_layers", cfg["num_hidden_layers"])
+    if n_periods is None:
+        if layers % interval:
+            raise ValueError(f"{layers} layers are no whole number of "
+                             f"periods of {interval}")
+        n_periods = layers // interval
+    return LMSpec(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
+        period=tuple("attention" if (i + 1) % interval == 0 else "gdn"
+                     for i in range(interval)),
+        n_periods=n_periods, norm_eps=cfg["rms_norm_eps"],
+        attention=GatedAttention(
+            n_heads=cfg["num_attention_heads"],
+            n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+            rope_theta=float(cfg["rope_theta"]),
+            rotary_dim=int(cfg["head_dim"] * cfg["partial_rotary_factor"])),
+        delta_net=GatedDeltaNet(
+            n_key_heads=cfg["linear_num_key_heads"],
+            n_value_heads=cfg["linear_num_value_heads"],
+            key_dim=cfg["linear_key_head_dim"],
+            value_dim=cfg["linear_value_head_dim"],
+            conv_width=cfg["linear_conv_kernel_dim"]),
+        experts=Experts(
+            n_experts=cfg["num_experts"] if n_experts is None else n_experts,
+            top_k=cfg["num_experts_per_tok"],
+            width=cfg["moe_intermediate_size"],
+            shared_width=cfg["shared_expert_intermediate_size"],
+            held=tuple(experts_held),
+            renormalize=bool(cfg["norm_topk_prob"])))

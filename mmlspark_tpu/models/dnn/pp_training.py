@@ -31,6 +31,7 @@ from ...reliability.metrics import reliability_metrics
 from ...telemetry import names as tnames
 from ...telemetry.perf import register_program
 from ...utils.tracing import annotate
+from .lm_spec import LMSpec, gpt2_spec
 from .transformer import init_transformer
 
 
@@ -41,6 +42,10 @@ def _stack_layers(layers: list) -> dict:
 
 
 import functools as _functools
+
+# positions a chunk of the untied head's loss holds: float32 logits of a
+# chunk are (microbatch, 2048, vocabulary)
+_HEAD_CHUNK = 2048
 
 
 @_functools.lru_cache(maxsize=None)
@@ -187,14 +192,25 @@ class PipelinedLMTrainer:
     streams rotating K/V blocks through the Pallas kernel + its flash
     backward). loss = t.step(tokens): (B, S) int32,
     B % (dp * n_microbatches) == 0, S % cp == 0.
+
+    What is trained is a description (`model`, an `lm_spec.LMSpec`): a
+    period of layer kinds and how often it repeats. The six integers
+    (`vocab_size` .. `max_len`) are the dense GPT-2 block's description,
+    built here when no `model` is given. Parameters stack by position in
+    the period and the stage scans periods; `n_periods` must divide by the
+    pipe axis. A hybrid description (Gated DeltaNet, gated grouped-KV
+    attention, sparse experts: `hybrid_layers`) trains on the data and
+    pipe axes; its layers have no Megatron or ring form yet.
     """
 
-    def __init__(self, vocab_size: int, mesh=None, n_microbatches: int = 4,
+    def __init__(self, vocab_size: int = None, mesh=None,
+                 n_microbatches: int = 4,
                  d_model: int = 128, n_heads: int = 8, n_layers: int = 4,
                  d_ff: int = 256, max_len: int = 512, lr: float = 1e-3,
                  seed: int = 0, attention: str = "dense",
                  optimizer: str = "adam",
-                 compute_dtype: str = "float32", remat: bool = False):
+                 compute_dtype: str = "float32", remat: bool = False,
+                 model: LMSpec = None):
         """compute_dtype="bfloat16" trains mixed-precision: master weights
         and the Adam state stay f32; weights and activations are cast to
         bf16 for every matmul (MXU bf16 rate, ~4x f32 on v5e) while layer
@@ -224,6 +240,13 @@ class PipelinedLMTrainer:
             raise ValueError("remat must be bool|'full'|'save_attn'")
         if compute_dtype not in ("float32", "bfloat16"):
             raise ValueError("compute_dtype must be float32|bfloat16")
+        if model is None:
+            if vocab_size is None:
+                raise ValueError("give a model description or vocab_size")
+            model = gpt2_spec(vocab_size, d_model, n_heads, n_layers, d_ff,
+                              max_len)
+        self.model = model
+        n_periods = model.n_periods
         import jax
         import jax.numpy as jnp
         import optax
@@ -233,26 +256,33 @@ class PipelinedLMTrainer:
 
         if mesh is None:
             n = jax.device_count()
-            pp = max(d for d in range(1, n_layers + 1)
-                     if n_layers % d == 0 and n % d == 0)
+            pp = max(d for d in range(1, n_periods + 1)
+                     if n_periods % d == 0 and n % d == 0)
             mesh = grid_mesh((n // pp, pp), (DATA_AXIS, PIPE_AXIS))
         n_stages = mesh.shape[PIPE_AXIS]
-        if n_layers % n_stages:
+        if n_periods % n_stages:
             raise ValueError(
-                f"n_layers ({n_layers}) must divide by the pipe axis "
-                f"({n_stages}) so every stage holds the same layer count")
+                f"n_periods ({n_periods}; for a dense block, n_layers) must "
+                f"divide by the pipe axis ({n_stages}) so every stage holds "
+                f"the same layer count")
         # optional third axis: Megatron tensor parallelism inside each stage
         tp = mesh.shape[MODEL_AXIS] if MODEL_AXIS in mesh.axis_names else 1
-        if n_heads % tp:
+        if model.n_heads % tp:
             raise ValueError(
-                f"n_heads ({n_heads}) must divide by the model axis ({tp})")
-        if d_ff % tp:
+                f"n_heads ({model.n_heads}) must divide by the model axis "
+                f"({tp})")
+        if model.d_ff % tp:
             raise ValueError(
-                f"d_ff ({d_ff}) must divide by the model axis ({tp})")
+                f"d_ff ({model.d_ff}) must divide by the model axis ({tp})")
         # optional fourth axis: context parallelism — the SEQUENCE shards
         # over it and attention runs as a ring inside each stage
         from ...parallel import SEQ_AXIS
         cp = mesh.shape[SEQ_AXIS] if SEQ_AXIS in mesh.axis_names else 1
+        if model.hybrid and (MODEL_AXIS in mesh.axis_names
+                             or SEQ_AXIS in mesh.axis_names):
+            raise ValueError(
+                "a hybrid model trains on the data and pipe axes only: its "
+                "layers have no Megatron slicing and no ring form yet")
         self.mesh = mesh
         self.n_stages = n_stages
         self.tp = tp
@@ -271,14 +301,21 @@ class PipelinedLMTrainer:
                 f"is bubble, and each stage holds more activation memory "
                 f"than n_microbatches >= {n_stages} would", stacklevel=2)
 
-        raw = init_transformer(vocab_size, d_model, n_heads, n_layers,
-                               d_ff, max_len, seed)
-        self.meta = raw.pop("meta")
-        params = {
-            "layers": _stack_layers(raw["layers"]),   # leaves (L, ...)
-            "embed": raw["embed"], "pos": raw["pos"],
-            "final_ln": raw["final_ln"],
-        }
+        if model.hybrid:
+            from .hybrid_layers import F32_LEAVES, hybrid_layer, init_hybrid
+            # "layers": one dict per position of the period, leaves (P, ...)
+            params = init_hybrid(model, seed)
+            self.meta = model.meta
+        else:
+            raw = init_transformer(model.vocab_size, model.d_model,
+                                   model.n_heads, n_periods, model.d_ff,
+                                   model.max_len, seed)
+            self.meta = raw.pop("meta")
+            params = {
+                "layers": _stack_layers(raw["layers"]),   # leaves (L, ...)
+                "embed": raw["embed"], "pos": raw["pos"],
+                "final_ln": raw["final_ln"],
+            }
 
         if tp == 1:
             layer_specs = jax.tree_util.tree_map(
@@ -298,11 +335,11 @@ class PipelinedLMTrainer:
                 "w2": P(PIPE_AXIS, MODEL_AXIS, None),
                 "b2": P(PIPE_AXIS, None),
             }
+        replicated = [k for k in params if k != "layers"]
         self._param_specs = {
             "layers": layer_specs,
-            "embed": P(), "pos": P(), "final_ln":
-                jax.tree_util.tree_map(lambda _: P(), params["final_ln"]),
-        }
+            **{k: jax.tree_util.tree_map(lambda _: P(), params[k])
+               for k in replicated}}
         shardings = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), self._param_specs,
             is_leaf=lambda x: isinstance(x, P))
@@ -318,9 +355,9 @@ class PipelinedLMTrainer:
                       else P(DATA_AXIS, None))
         self._batch_sharding = NamedSharding(mesh, batch_spec)
 
-        h_loc = self.meta["n_heads"] // tp   # local heads per model shard
-        d = self.meta["d_model"]
-        dh = d // self.meta["n_heads"]
+        h_loc = model.n_heads // tp   # local heads per model shard (dense)
+        d = model.d_model
+        dh = d // model.n_heads if model.n_heads else 0
         M = n_microbatches
         S_P = n_stages
         # axis PRESENCE (not size) selects the sharded code paths: a mesh
@@ -332,12 +369,24 @@ class PipelinedLMTrainer:
         cp_axis = SEQ_AXIS if SEQ_AXIS in mesh.axis_names else None
         opt = self._opt
         cdt = jnp.dtype(compute_dtype)
+        hybrid = model.hybrid
+        # a hybrid model's step returns, with its loss, what its expert
+        # layers counted: (pairs routed, pairs held, sum over expert-layer
+        # calls of the held experts' max load over their mean, calls)
+        n_stats = 4
 
         def device_loss(p, tokens):
-            """Per-device GPipe forward; returns the replicated global loss.
+            """Per-device GPipe forward; returns the replicated global loss
+            (a hybrid model: and its expert layers' counts).
             p["layers"] leaves are this stage's (L/P, ...) slice; with cp,
             `tokens` is also a SEQUENCE shard and positions are global."""
-            if cdt != jnp.float32:
+            if cdt != jnp.float32 and hybrid:
+                # as below, but vectors and the convolution's taps stay f32
+                with jax.named_scope(tnames.LM_CAST):
+                    p = jax.tree_util.tree_map_with_path(
+                        lambda path, a: a if path[-1].key in F32_LEAVES
+                        else a.astype(cdt), p)
+            elif cdt != jnp.float32:
                 # one differentiable downcast per step: grads flow back to
                 # the f32 masters through the cast's transpose. Layer-norm
                 # scale/bias ride along in bf16 — _layer_norm upcasts its
@@ -370,6 +419,21 @@ class PipelinedLMTrainer:
             pos_mask = jnp.where(
                 (jnp.arange(S_loc) == S_loc - 1) & is_last_shard, 0.0, 1.0)
 
+            def apply_hybrid_stage(x):
+                """(mb, S, d) through this stage's periods, each the
+                description's sequence of layer kinds; every sublayer is
+                recomputed in the backward pass when `remat` is set."""
+                def one_period(h_x, lps):
+                    stats = jnp.zeros((n_stats,), jnp.float32)
+                    for kind, lp in zip(model.period, lps):
+                        h_x, (routed, held, load) = hybrid_layer(
+                            h_x, lp, kind, model, attention, bool(remat))
+                        stats = stats + jnp.stack(
+                            [routed, held, load, jnp.float32(1.0)])
+                    return h_x, stats
+                x, stats = jax.lax.scan(one_period, x, p["layers"])
+                return x, stats.sum(0)
+
             def apply_stage(x):      # (mb, S, d) through this stage's layers
                 if remat == "save_attn":
                     # attention residuals stored (the flash forward is
@@ -399,12 +463,51 @@ class PipelinedLMTrainer:
 
             def embed_mb(tok):       # (mb, S) -> (mb, S, d)
                 with jax.named_scope(tnames.LM_EMBED):
+                    if hybrid:       # positions are rotary, inside attention
+                        return p["embed"][tok]
                     pos = jax.lax.dynamic_slice_in_dim(
                         p["pos"], seq_off, S_loc, axis=0)
                     return p["embed"][tok] + pos
 
+            def untied_loss(y, tgt):
+                """Final RMSNorm and the untied head, `_HEAD_CHUNK`
+                positions at a time, each chunk's logits recomputed in the
+                backward pass: float32 logits and their gradient exist for
+                one chunk, not for the microbatch."""
+                from .hybrid_layers import rms_norm
+                with jax.named_scope(tnames.LM_HEAD):
+                    n_chunks = -(-S_loc // _HEAD_CHUNK)
+                    pad = n_chunks * _HEAD_CHUNK - S_loc
+
+                    def chunked(a):      # (mb, S, ...) -> (chunks, mb, C, ...)
+                        a = jnp.pad(a, ((0, 0), (0, pad))
+                                    + ((0, 0),) * (a.ndim - 2))
+                        a = a.reshape((a.shape[0], n_chunks, _HEAD_CHUNK)
+                                      + a.shape[2:])
+                        return jnp.moveaxis(a, 1, 0)
+
+                    @jax.checkpoint
+                    def one_chunk(acc, xs):
+                        y_c, tgt_c, mask_c = xs
+                        z = rms_norm(y_c, p["final_norm"], model.norm_eps)
+                        logits = jnp.einsum(
+                            "msd,vd->msv", z, p["head"],
+                            preferred_element_type=jnp.float32)
+                        logp = jax.nn.log_softmax(logits, axis=-1)
+                        nll = -jnp.take_along_axis(
+                            logp, tgt_c[..., None], axis=-1)[..., 0]
+                        return acc + (nll * mask_c).sum(), None
+
+                    mask = jnp.broadcast_to(pos_mask, tgt.shape)
+                    total, _ = jax.lax.scan(
+                        one_chunk, jnp.float32(0.0),
+                        (chunked(y), chunked(tgt), chunked(mask)))
+                    return total
+
             def mb_loss(y, tgt):     # final-stage head: local masked SUM
                 from .transformer import _layer_norm
+                if hybrid:
+                    return untied_loss(y, tgt)
                 with jax.named_scope(tnames.LM_HEAD):
                     z = _layer_norm(y, p["final_ln"])
                     # tied softmax head: bf16 operands at the MXU's bf16
@@ -419,7 +522,7 @@ class PipelinedLMTrainer:
                     return (nll * pos_mask).sum()
 
             def tick(carry, t):
-                act, acc = carry
+                act, acc = carry[:2]
                 # lax.cond, not where: where would run the embedding lookup
                 # on every stage and the full vocab-width LM head on every
                 # tick — cond pays each only where its result is consumed
@@ -427,7 +530,13 @@ class PipelinedLMTrainer:
                     s_idx == 0,
                     lambda: embed_mb(mbs[jnp.clip(t, 0, M - 1)]),
                     lambda: act)
-                y = apply_stage(x_in)
+                if hybrid:
+                    y, stats = apply_hybrid_stage(x_in)
+                    # a stage counts the ticks in which it held a microbatch
+                    live = (t >= s_idx) & (t - s_idx < M)
+                    counts = carry[2] + jnp.where(live, stats, 0.0)
+                else:
+                    y = apply_stage(x_in)
                 out_idx = t - (S_P - 1)
                 valid = ((out_idx >= 0) & (out_idx < M)
                          & (s_idx == S_P - 1))
@@ -437,11 +546,14 @@ class PipelinedLMTrainer:
                 act = jax.lax.ppermute(
                     y, PIPE_AXIS,
                     [(i, (i + 1) % S_P) for i in range(S_P)])
-                return (act, acc), None
+                return ((act, acc, counts) if hybrid else (act, acc)), None
 
             act0 = jnp.zeros((mb, S_loc, d), cdt)
-            (_, acc), _ = jax.lax.scan(tick, (act0, jnp.float32(0.0)),
-                                       jnp.arange(M + S_P - 1))
+            carry0 = (act0, jnp.float32(0.0))
+            if hybrid:
+                carry0 += (jnp.zeros((n_stats,), jnp.float32),)
+            (_, acc, *counts), _ = jax.lax.scan(tick, carry0,
+                                                jnp.arange(M + S_P - 1))
             # loss lives on the last stage; g-operator (psum forward,
             # IDENTITY backward) over BOTH pipe and seq shards — a bare
             # psum's transpose under check_vma=False is another psum, which
@@ -452,10 +564,22 @@ class PipelinedLMTrainer:
             if cp_axis:
                 loss = _tp_g(cp_axis)(loss)
             denom = M * mb * (S_loc * cp - 1)
-            return jax.lax.pmean(loss / denom, DATA_AXIS)
+            loss = jax.lax.pmean(loss / denom, DATA_AXIS)
+            if not hybrid:
+                return loss
+            return loss, jax.lax.psum(jax.lax.stop_gradient(counts[0]),
+                                      (PIPE_AXIS, DATA_AXIS))
 
         def fwd_bwd(p, tokens):
-            loss, grads = jax.value_and_grad(device_loss)(p, tokens)
+            loss, grads = jax.value_and_grad(device_loss, has_aux=hybrid)(
+                p, tokens)
+            if hybrid:
+                # one vector leaves the program, so the host reads the
+                # counts with the loss: (loss, pairs routed, pairs held,
+                # mean over expert-layer calls of max load over mean load)
+                loss, counts = loss
+                loss = jnp.concatenate(
+                    [loss[None], counts[:2], counts[2:3] / counts[3]])
             # dp gradient all-reduce; stage-sharded layer grads stay local
             # to their pipe coordinate; replicated leaves (embed/pos/
             # final_ln) are psum'd over pipe below — each stage holds a
@@ -470,7 +594,7 @@ class PipelinedLMTrainer:
                     lambda g: jax.lax.psum(g, cp_axis), grads)
             rep = {k: jax.tree_util.tree_map(
                 lambda g: jax.lax.psum(g, PIPE_AXIS), grads[k])
-                for k in ("embed", "pos", "final_ln")}
+                for k in replicated}
             grads = {**grads, **rep}
             return loss, grads
 
@@ -499,8 +623,15 @@ class PipelinedLMTrainer:
                 params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
-        # raw step kept for run()'s fori_loop body; jitted once here
-        self._step_fn = train_step
+        self._fwd_bwd = jax.jit(mapped)
+
+        def scalar_step(params, opt_state, tokens):
+            params, opt_state, loss = train_step(params, opt_state, tokens)
+            return params, opt_state, loss[0]
+
+        # raw step kept for run()'s fori_loop body (whose carry is the
+        # scalar loss); jitted once here
+        self._step_fn = scalar_step if hybrid else train_step
         self._step = jax.jit(train_step, donate_argnums=self._donate)
         self._multi = None   # lazily-built multi-step executable (run())
         self._step_shape = None   # token shape of the last step() call
@@ -567,7 +698,24 @@ class PipelinedLMTrainer:
         if compiled:
             reliability_metrics.inc(tnames.LM_STEP_COMPILES, compiled)
         with annotate(tnames.LM_STEP_WAIT):
-            return float(loss)
+            out = np.asarray(loss)
+        if out.ndim:
+            # a hybrid model's step: the expert layers' counts came with it
+            reliability_metrics.inc(tnames.MOE_PAIRS_ROUTED, int(out[1]))
+            reliability_metrics.inc(tnames.MOE_PAIRS_HELD, int(out[2]))
+            reliability_metrics.set_gauge(tnames.MOE_LOAD_MAX_OVER_MEAN,
+                                          float(out[3]))
+            return float(out[0])
+        return float(out)
+
+    def loss_and_grads(self, tokens: np.ndarray) -> tuple:
+        """(loss, gradient tree) of one batch by the step's own forward and
+        backward pass, with no update: what parity tests compare with a
+        reference's `jax.grad`."""
+        self._check_batch(tokens)
+        loss, grads = self._fwd_bwd(self.params, self._to_device(tokens))
+        loss = np.asarray(loss)
+        return float(loss[0] if loss.ndim else loss), grads
 
     def _register_step_program(self) -> None:
         """Name the step program of the last `step()` call's shapes for

@@ -86,6 +86,7 @@ BLOCK_K = 256
 _FWD_BLOCK = 1024
 _BWD_BLOCK_BF16 = 1024
 _BWD_BLOCK_F32 = 512
+_BWD_WIDE_HEAD = 128      # heads wider than this take the f32 cap (_bwd_cap)
 
 # The kernels' names, which reach the compiled program: a Pallas call's HLO
 # instruction is named after the innermost element of JAX's name stack, so
@@ -120,14 +121,24 @@ def _pick_block(seq: int) -> int:
     return BLOCK_Q
 
 
-def _auto_blocks(seq_q: int, seq_k: int, dtype) -> tuple:
+def _bwd_cap(dtype, head_dim: int) -> int:
+    """Largest backward block the kernels' VMEM allows: 1024 for bf16
+    operands of head size up to 128, 512 for f32 operands and for heads
+    wider than 128 (bf16 at 256: the dk/dv kernel wants 16.8 MB of the
+    16 MB scoped limit at 1024, compiled for a described v5e, PR 28; blocks
+    of 512 compile, as do 1024 x 512, which would lose the in-cell causal
+    schedule that wants square blocks)."""
+    return (_BWD_BLOCK_BF16 if jnp.dtype(dtype) == jnp.bfloat16
+            and head_dim <= _BWD_WIDE_HEAD else _BWD_BLOCK_F32)
+
+
+def _auto_blocks(seq_q: int, seq_k: int, dtype, head_dim: int = 0) -> tuple:
     """(block_q, block_k, bwd_block_q, bwd_block_k) for this shape/dtype.
     Per-dim waste-bounded block choice; the backward uses smaller blocks
-    for f32 (VMEM)."""
+    for f32 and for wide heads (VMEM)."""
     bq = _pick_block(seq_q)
     bk = _pick_block(seq_k)
-    bwd_cap = (_BWD_BLOCK_BF16 if jnp.dtype(dtype) == jnp.bfloat16
-               else _BWD_BLOCK_F32)
+    bwd_cap = _bwd_cap(dtype, head_dim)
     return bq, bk, min(bq, bwd_cap), min(bk, bwd_cap)
 
 
@@ -864,15 +875,14 @@ def flash_attention(q, k, v, causal: bool = False,
         interpret = jax.devices()[0].platform != "tpu"
     q = jnp.asarray(q)
     a_bq, a_bk, a_bwd_bq, a_bwd_bk = _auto_blocks(
-        q.shape[0], k.shape[0], q.dtype)
+        q.shape[0], k.shape[0], q.dtype, q.shape[-1])
     bq = int(block_q) if block_q is not None else a_bq
     bk = int(block_k) if block_k is not None else a_bk
     # explicit blocks pin the backward too (sweep scripts rely on that) —
     # but capped by the dtype VMEM ceiling: an f32 caller passing
     # block_q=1024 would otherwise hit the documented f32-backward VMEM
     # compile failure only at grad time (round-4 advisor)
-    bwd_cap = (_BWD_BLOCK_BF16 if jnp.dtype(q.dtype) == jnp.bfloat16
-               else _BWD_BLOCK_F32)
+    bwd_cap = _bwd_cap(q.dtype, q.shape[-1])
     bwd_bq = min(int(block_q), bwd_cap) if block_q is not None else a_bwd_bq
     bwd_bk = min(int(block_k), bwd_cap) if block_k is not None else a_bwd_bk
     qh = jnp.moveaxis(q, 1, 0)                # (H, S, D)

@@ -81,6 +81,8 @@ TELEMETRY_PROFILE_STAMP_ERRORS = "telemetry.profile.stamp_errors"
 LM_STEP_COMPILES = "lm.step.compiles"
 FLASH_TILES_COMPUTED = "flash.tiles.computed"
 FLASH_TILES_SKIPPED = "flash.tiles.skipped"
+MOE_PAIRS_ROUTED = "moe.pairs.routed"
+MOE_PAIRS_HELD = "moe.pairs.held"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -213,6 +215,12 @@ COUNTERS = {
                          "causal schedule of a traced flash kernel call "
                          "leaves out (0 for non-causal, cross-attention and "
                          "traced-offset ring calls)",
+    MOE_PAIRS_ROUTED: "(token, expert) pairs the routers of the expert "
+                      "layers made, summed over the layers of the steps "
+                      "PipelinedLMTrainer.step ran (read from the numbers "
+                      "the step program returns with its loss)",
+    MOE_PAIRS_HELD: "of moe.pairs.routed, the pairs routed to an expert "
+                    "this chip holds, which are the ones it computed",
     QUALITY_LABELS_JOINED: "delayed labels joined to their served "
                            "prediction (streaming evaluation pairs)",
     QUALITY_LABELS_LATE: "out-of-order labels that arrived BEFORE their "
@@ -322,8 +330,12 @@ CLUSTER_HOSTS_DEAD = "cluster.hosts.dead"
 WORKLOADS_IFOREST_THRESHOLD = "workloads.iforest.threshold"
 WORKLOADS_SAR_CATALOG_ITEMS = "workloads.sar.catalog.items"
 TELEMETRY_PROFILE_UNSCOPED_SHARE = "telemetry.profile.unscoped_share"
+MOE_LOAD_MAX_OVER_MEAN = "moe.load.max_over_mean"
 
 GAUGES = {
+    MOE_LOAD_MAX_OVER_MEAN: "last step's expert load imbalance: the fullest "
+                            "held expert's pairs over the mean of the held "
+                            "experts', averaged over the expert layers",
     TELEMETRY_PROFILE_UNSCOPED_SHARE: "share of the last parsed capture's "
                                       "device self time that no registered "
                                       "program's scope map puts in a region",
@@ -464,6 +476,12 @@ LM_MLP = "lm.mlp"
 LM_HEAD = "lm.head"
 LM_OPT = "lm.opt"
 LM_CAST = "lm.cast"
+LM_GDN = "lm.gdn"
+LM_GDN_SCAN = "lm.gdn.scan"
+LM_MOE_ROUTER = "lm.moe.router"
+LM_MOE_DISPATCH = "lm.moe.dispatch"
+LM_MOE_EXPERTS = "lm.moe.experts"
+LM_MOE_SHARED = "lm.moe.shared"
 GBDT_HIST = "gbdt.hist"
 GBDT_SPLIT = "gbdt.split"
 GBDT_ROUTE = "gbdt.route"
@@ -480,6 +498,18 @@ DEVICE_REGIONS = {
     LM_HEAD: "final layer norm, tied logits, log-softmax, NLL",
     LM_OPT: "optimizer update + apply_updates over the f32 masters",
     LM_CAST: "per-step f32 -> compute-dtype cast of the parameters",
+    LM_GDN: "Gated-DeltaNet mixer outside its recurrence: input norm, "
+            "qkvz/ba projections, causal convolution, gates, L2 norms, "
+            "gated output norm, out projection, residual",
+    LM_GDN_SCAN: "the chunked gated delta rule (ops/gated_delta.py), "
+                 "forward and backward",
+    LM_MOE_ROUTER: "expert layer: post norm, router logits, softmax, top-k",
+    LM_MOE_DISPATCH: "expert layer: sort of the pairs by held expert, the "
+                     "tile loop's gathers and scatter-adds",
+    LM_MOE_EXPERTS: "expert layer: the held experts' gated MLPs over the "
+                    "tiles of the pairs routed to them",
+    LM_MOE_SHARED: "expert layer: the shared expert, its sigmoid gate, the "
+                   "sum with the routed part, residual",
     GBDT_HIST: "node x feature x bin histogram build (and its psum)",
     GBDT_SPLIT: "best-split search of one level",
     GBDT_ROUTE: "advance rows to their child nodes",
