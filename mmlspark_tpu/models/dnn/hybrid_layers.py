@@ -101,14 +101,18 @@ def gdn_mixer(x, p, g, eps: float):
 
     q = l2(qkv[..., :hk * dk].reshape(b, s, hk, dk)) * dk ** -0.5
     k = l2(qkv[..., hk * dk:2 * hk * dk].reshape(b, s, hk, dk))
-    q = jnp.repeat(q.astype(x.dtype), hv // hk, axis=2)
-    k = jnp.repeat(k.astype(x.dtype), hv // hk, axis=2)
+    # the recurrence reads the hk key heads as they are: value head h
+    # takes key head h // (hv / hk), so nothing is repeated
+    q, k = q.astype(x.dtype), k.astype(x.dtype)
     v = qkv[..., 2 * hk * dk:].reshape(b, s, hv, dv).astype(x.dtype)
     beta = jax.nn.sigmoid(ba[..., :hv])
     decay = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
         ba[..., hv:] + p["dt_bias"].astype(f32))
     with jax.named_scope(tnames.LM_GDN_SCAN):
-        o = jax.vmap(chunk_gated_delta_rule)(q, k, v, decay, beta)
+        # the batch goes in whole: it is a grid axis of the kernels, and
+        # under a `vmap` here they would be named `vmap_gdn_fwd_` (the trap
+        # `attention_mixer` describes)
+        o = chunk_gated_delta_rule(q, k, v, decay, beta)
     o32 = o.astype(f32)
     o32 = o32 * jax.lax.rsqrt((o32 * o32).mean(-1, keepdims=True) + eps)
     o = (o32 * p["norm"].astype(f32)).astype(x.dtype) \

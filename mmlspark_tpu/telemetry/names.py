@@ -83,6 +83,8 @@ FLASH_TILES_COMPUTED = "flash.tiles.computed"
 FLASH_TILES_SKIPPED = "flash.tiles.skipped"
 MOE_PAIRS_ROUTED = "moe.pairs.routed"
 MOE_PAIRS_HELD = "moe.pairs.held"
+GDN_SCAN_ROUTE_PALLAS = "gdn.scan.route.pallas"
+GDN_SCAN_ROUTE_XLA = "gdn.scan.route.xla"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -221,6 +223,15 @@ COUNTERS = {
                       "the step program returns with its loss)",
     MOE_PAIRS_HELD: "of moe.pairs.routed, the pairs routed to an expert "
                     "this chip holds, which are the ones it computed",
+    GDN_SCAN_ROUTE_PALLAS: "calls of the gated delta rule traced down the "
+                           "Pallas kernels (gdn_fwd, gdn_bwd): a TPU and "
+                           "shapes that fit them (ops/gated_delta.py), or "
+                           "a test's own interpret-mode call; counted at "
+                           "trace time, once a call site",
+    GDN_SCAN_ROUTE_XLA: "calls of the gated delta rule traced down its XLA "
+                        "form: off the TPU, or head sizes that are no "
+                        "multiple of 128, or another chunk than 64 (never "
+                        "silent)",
     QUALITY_LABELS_JOINED: "delayed labels joined to their served "
                            "prediction (streaming evaluation pairs)",
     QUALITY_LABELS_LATE: "out-of-order labels that arrived BEFORE their "
@@ -501,7 +512,10 @@ DEVICE_REGIONS = {
     LM_GDN: "Gated-DeltaNet mixer outside its recurrence: input norm, "
             "qkvz/ba projections, causal convolution, gates, L2 norms, "
             "gated output norm, out projection, residual",
-    LM_GDN_SCAN: "the chunked gated delta rule (ops/gated_delta.py), "
+    LM_GDN_SCAN: "the chunked gated delta rule (ops/gated_delta.py): on a "
+                 "TPU at 128-wide heads the kernels gdn_fwd (forward, and "
+                 "again for remat) and gdn_bwd with the running sum of the "
+                 "log decay and their layout glue, else its XLA form; "
                  "forward and backward",
     LM_MOE_ROUTER: "expert layer: post norm, router logits, softmax, top-k",
     LM_MOE_DISPATCH: "expert layer: sort of the pairs by held expert, the "

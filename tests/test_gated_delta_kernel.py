@@ -1,0 +1,241 @@
+"""The gated delta rule's Pallas kernels (`ops/gated_delta.py`: `gdn_fwd`,
+`gdn_bwd`) through the interpreter on the CPU: output and all five gradients
+against the benchmark's positional reference and against the XLA form, the
+shape rule that chooses between kernel and XLA form, the counters that say
+which was taken, and the kernels' place in the compiled step's regions."""
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mmlspark_tpu.ops import gated_delta as gd
+from mmlspark_tpu.reliability.metrics import reliability_metrics
+from mmlspark_tpu.telemetry import names as tnames
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def ref():
+    spec = importlib.util.spec_from_file_location(
+        "reference_qwen3_next",
+        os.path.join(REPO, "benchmark", "reference", "qwen3_next.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def inputs(seq, heads, key_heads, dtype, batch=2, dk=128, dv=128):
+    ks = jax.random.split(jax.random.PRNGKey(seq + heads), 6)
+
+    def l2(t):
+        return t / jnp.sqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+
+    args = ((l2(jax.random.normal(ks[0], (batch, seq, key_heads, dk)))
+             * dk ** -0.5).astype(dtype),
+            l2(jax.random.normal(ks[1], (batch, seq, key_heads, dk))
+               ).astype(dtype),
+            jax.random.normal(ks[2], (batch, seq, heads, dv)).astype(dtype),
+            -jax.nn.softplus(jax.random.normal(ks[3], (batch, seq, heads)))
+            * 0.3,
+            jax.nn.sigmoid(jax.random.normal(ks[4], (batch, seq, heads))))
+    return args, jax.random.normal(ks[5], (batch, seq, heads, dv))
+
+
+def out_and_grads(fn, args, cot):
+    def loss(*a):
+        o = fn(*a)
+        return (o.astype(jnp.float32) * cot).sum(), o
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    return (o,) + grads
+
+
+def worst(got, want):
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+
+# (value heads, key heads, heads a step): one head block and more than one;
+# key heads repeated to the value heads inside the kernel or not at all
+LAYOUTS = [(2, 2, 2), (4, 2, 2), (4, 4, 4)]
+
+
+@pytest.mark.parametrize("seq", [64, 150, 257])
+@pytest.mark.parametrize("heads,key_heads,step", LAYOUTS)
+def test_float32_kernels_match_reference_and_xla_form(ref, seq, heads,
+                                                      key_heads, step):
+    args, cot = inputs(seq, heads, key_heads, jnp.float32)
+    rep = heads // key_heads
+
+    def positional(q, k, v, g, beta):
+        q, k = jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2)
+        return jax.vmap(ref.delta_rule)(q, k, v, g, beta)
+
+    with jax.default_matmul_precision("highest"):
+        got = out_and_grads(lambda *a: gd.gated_delta_pallas(
+            *a, interpret=True, heads_per_step=step), args, cot)
+        xla = out_and_grads(gd.chunk_gated_delta_rule, args, cot)
+        want = out_and_grads(positional, args, cot)
+    assert float(jnp.abs(got[0] - want[0]).max()) < 2e-6
+    assert float(jnp.abs(got[0] - xla[0]).max()) < 2e-6
+    for a, b, c in zip(got[1:], want[1:], xla[1:]):
+        assert worst(a, b) < 2e-5
+        assert worst(a, c) < 2e-5
+
+
+@pytest.mark.parametrize("seq", [64, 150, 257])
+@pytest.mark.parametrize("heads,key_heads,step", LAYOUTS[:2])
+def test_bfloat16_kernels_match_the_xla_form(seq, heads, key_heads, step):
+    """Against the XLA form in bfloat16, each within what bfloat16 operands
+    leave of the float32 XLA form: the kernels round no more than it."""
+    args, cot = inputs(seq, heads, key_heads, jnp.bfloat16)
+    exact = tuple(a.astype(jnp.float32) for a in args)
+    got = out_and_grads(lambda *a: gd.gated_delta_pallas(
+        *a, interpret=True, heads_per_step=step), args, cot)
+    xla = out_and_grads(gd.chunk_gated_delta_rule, args, cot)
+    with jax.default_matmul_precision("highest"):
+        want = out_and_grads(gd.chunk_gated_delta_rule, exact, cot)
+    assert got[0].dtype == jnp.bfloat16
+    for a, b, c in zip(got, xla, want):
+        assert a.dtype == b.dtype
+        assert worst(a, b) < 0.03
+        assert worst(a, c) < max(1.5 * worst(b, c), 0.01)
+
+
+def test_unbatched_call_is_the_batched_one():
+    args, _ = inputs(100, 2, 2, jnp.float32, batch=1)
+    one = gd.gated_delta_pallas(*(a[0] for a in args), interpret=True)
+    assert float(jnp.abs(one - gd.gated_delta_pallas(
+        *args, interpret=True)[0]).max()) == 0.0
+
+
+def routes(fn):
+    before = [reliability_metrics.get(n) for n in (
+        tnames.GDN_SCAN_ROUTE_PALLAS, tnames.GDN_SCAN_ROUTE_XLA)]
+    fn()
+    return tuple(reliability_metrics.get(n) - b for n, b in zip(
+        (tnames.GDN_SCAN_ROUTE_PALLAS, tnames.GDN_SCAN_ROUTE_XLA), before))
+
+
+@pytest.mark.parametrize("dk,dv,chunk,dtype", [
+    (16, 24, 64, jnp.float32),        # the toy widths of the trainer's tests
+    (128, 128, 16, jnp.float32),      # another chunk than the kernels'
+    (128, 128, 64, jnp.float16),      # a dtype they were not written for
+    (128, 128, 64, jnp.float32),      # fits, but this is not a TPU
+])
+def test_what_does_not_fit_takes_the_xla_form_and_is_counted(dk, dv, chunk,
+                                                             dtype):
+    args, _ = inputs(40, 2, 2, dtype, batch=1, dk=dk, dv=dv)
+    fits = dk == dv == 128 and chunk == 64 and dtype == jnp.float32
+    assert gd.pallas_fits(args[0], args[2], chunk) == fits
+    assert routes(lambda: gd.chunk_gated_delta_rule(
+        *args, chunk=chunk)) == (0, 1)
+
+
+def test_interpret_call_takes_the_kernels_and_is_counted():
+    args, _ = inputs(40, 2, 2, jnp.float32, batch=1)
+    assert routes(lambda: gd.gated_delta_pallas(
+        *args, interpret=True)) == (1, 0)
+    bad, _ = inputs(40, 2, 2, jnp.float32, batch=1, dk=16)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        gd.gated_delta_pallas(*bad, interpret=True)
+
+
+# ------------------------------------------- compiled for a described v5e
+# Only one process at a time may load the TPU's library, so the topology is
+# described inside a fixture of this one file (never at import), and every
+# test below compiles in this process.
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler for the chip here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip cannot be read back from the
+    # persistent cache without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo, SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_kernels_compile_for_v5e_at_the_published_widths(v5e):
+    """32 value heads over 16 key heads of 128, bfloat16, as the cell runs
+    them (a shorter sequence: the grid's length is no part of a kernel):
+    what Mosaic refuses (an unaligned slice, too much VMEM) it refuses
+    here."""
+    _, chip = v5e
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=chip)
+
+    args = (shape(2, 512, 16, 128), shape(2, 512, 16, 128),
+            shape(2, 512, 32, 128), shape(2, 512, 32, dtype=jnp.float32),
+            shape(2, 512, 32, dtype=jnp.float32))
+    text = jax.jit(jax.grad(
+        lambda *a: gd.gated_delta_pallas(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4))).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert gd.KERNEL_FWD in text and gd.KERNEL_BWD in text
+    plain = jax.jit(gd.gated_delta_pallas).lower(*args).compile().as_text()
+    assert f"%{gd.KERNEL_FWD}." in plain and gd.KERNEL_BWD not in plain
+
+
+def test_compiled_layer_holds_the_kernels_in_the_scan_region(v5e,
+                                                             monkeypatch):
+    """A DeltaNet layer as the trainer runs it (`jax.checkpoint` round the
+    mixer, bfloat16), at head sizes that fit the kernels, compiled for the
+    chip: every kernel call lies in region `lm.gdn.scan`, `gdn_fwd` as
+    `fwd` and as `remat`, `gdn_bwd` as `bwd`, so `gdn_scan_ms_per_step`
+    reads all of the recurrence; and the choice was counted."""
+    from mmlspark_tpu.models.dnn import hybrid_layers
+    from mmlspark_tpu.models.dnn.lm_spec import qwen3_next_spec
+    from mmlspark_tpu.telemetry import perf
+    topo, chip = v5e
+    cfg = dict(hidden_size=64, num_hidden_layers=4, full_attention_interval=4,
+               head_dim=32, num_attention_heads=4, num_key_value_heads=2,
+               partial_rotary_factor=0.25, rope_theta=1e7, rms_norm_eps=1e-6,
+               linear_num_key_heads=2, linear_num_value_heads=4,
+               linear_key_head_dim=128, linear_value_head_dim=128,
+               linear_conv_kernel_dim=4, num_experts=8, num_experts_per_tok=2,
+               moe_intermediate_size=32, shared_expert_intermediate_size=32,
+               norm_topk_prob=True, vocab_size=97)
+    spec = qwen3_next_spec(cfg, (0, 8))
+    layer = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape[1:], jnp.float32 if a.ndim == 2 else jnp.bfloat16,
+            sharding=chip),
+        hybrid_layers.init_hybrid(spec, 0)["layers"][0])
+    h = jax.ShapeDtypeStruct((2, 128, 64), jnp.bfloat16, sharding=chip)
+
+    def loss(h, lp):
+        out, _ = hybrid_layers.hybrid_layer(h, lp, "gdn", spec, "dense",
+                                            remat=True)
+        return out.astype(jnp.float32).sum()
+
+    # the choice of path asks the platform of jax.devices()[0]
+    monkeypatch.setattr(jax, "devices", lambda *a: topo.devices)
+    scopes = {}
+    taken = routes(lambda: scopes.update(perf.scope_map(jax.jit(jax.grad(
+        loss, argnums=(0, 1))).lower(h, layer).compile().as_text())))
+    assert taken[0] > 0 and taken[1] == 0
+    kernels = {name: where for name, where in scopes.items()
+               if name.startswith(("gdn_", "vmap_gdn_"))}
+    assert {where for where in kernels.values()} == {
+        (tnames.LM_GDN_SCAN, "fwd"), (tnames.LM_GDN_SCAN, "remat"),
+        (tnames.LM_GDN_SCAN, "bwd")}
+    assert {name.split(".")[0]: way for name, (_, way) in kernels.items()
+            if way == "bwd"} == {gd.KERNEL_BWD: "bwd"}
+    assert all(name.startswith(gd.KERNEL_FWD)
+               for name, (_, way) in kernels.items() if way != "bwd")
