@@ -354,7 +354,7 @@ def gbdt_phase(n_rows: int, n_features: int, n_iters: int, max_depth: int,
 
 # --------------------------------------------------------------------- lm
 def lm_mesh(n_devices: int):
-    """1 device: the (data 1, pipe 1) mesh of bench.py's lm mode; 4k
+    """1 device: the (data 1, pipe 1) mesh of the benchmark's LM cells; 4k
     devices: data k x pipe 2 x model 2."""
     from mmlspark_tpu.parallel import (DATA_AXIS, MODEL_AXIS, PIPE_AXIS,
                                        grid_mesh)

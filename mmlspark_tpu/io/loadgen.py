@@ -1,10 +1,9 @@
 """Serving load generator: N concurrent keep-alive HTTP clients against a
 ServingServer, with latency bookkeeping.
 
-Shared by the serving benches (bench.py BENCH_MODE=serving/fleet) and the
-throughput-floor tests (tests/test_io_http.py) so the harness — error
-capture, wall-clock accounting, percentile math — has exactly one
-implementation (role: the reference's serving load suites drive
+Used by the serving load tests (tests/test_io_http.py, test_control.py) so
+the harness — error capture, wall-clock accounting, percentile math — has
+exactly one implementation (role: the reference's serving load suites drive
 WorkerServer the same way, HTTPv2Suite throughput tests).
 
 A client NEVER aborts on a failed request: the pre-control-loop version

@@ -1138,8 +1138,8 @@ def serve_pipeline(model, input_cols, output_col: str = "prediction",
     (io/plan.py): per-(fingerprint, shape-bucket) cached plans, prebuilt
     GBDT host scoring, one columnar decode per batch, per-row 400s for
     malformed JSON, preserialized reply framing. `fast_path=False` keeps
-    the uncached Table-per-batch path — the pre-overhaul baseline
-    BENCH_MODE=serving measures against. `batch_linger_ms` is the
+    the uncached Table-per-batch path, the pre-overhaul baseline.
+    `batch_linger_ms` is the
     microbatch coalescing budget (docs/serving.md "Latency tuning").
     `faults` arms the transform's `serving.swap` chaos site (a
     mid-`install_model` fault rolls back to the incumbent); hot-swap a

@@ -1,9 +1,10 @@
-"""Layers of the hybrid decoder family (zero-centred RMSNorm, gated
-grouped-KV attention with partial rotary embedding, Gated DeltaNet, sparse
-experts with a shared expert), on (B, S, d) activations, as
-`PipelinedLMTrainer` runs them. `lm_spec.py` says which of them a model's
-period is made of; `benchmark/reference/qwen3_next.py` has the same
-equations in plain float32.
+"""The hybrid decoder family (kinds `gdn`, `attention`: zero-centred
+RMSNorm, gated grouped-KV attention with partial rotary embedding, Gated
+DeltaNet, sparse experts with a shared expert, an untied head), on
+(B, S, d) activations, as `PipelinedLMTrainer` runs it. `lm_spec.py` says
+which of the kinds a model's period is made of;
+`benchmark/reference/qwen3_next.py` has the same equations in plain
+float32. What a family supplies: docs/dnn.md "Model families".
 
 Mixed precision as in the dense block: matmul operands in the activations'
 dtype with float32 accumulation; norms, rotary angles, gates' decay, L2
@@ -16,13 +17,61 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...ops.gated_delta import chunk_gated_delta_rule
+from ...parallel import DATA_AXIS, PIPE_AXIS
+from ...reliability.metrics import reliability_metrics
 from ...telemetry import names as tnames
 from .moe import moe_layer
 
-# leaves the trainer's per-step cast leaves in float32: vectors and the
-# convolution taps, whose arithmetic is float32 anyway
+# the mesh axes this family has a form for: its layers have no Megatron
+# slicing and no ring form yet
+AXES = (DATA_AXIS, PIPE_AXIS)
+# what a stage counts for the host, summed over its expert-layer calls:
+# (pairs routed, pairs held, held experts' max load over mean, calls)
+STATS = jax.ShapeDtypeStruct((4,), jnp.float32)
+# leaves the per-step cast leaves in float32: vectors and the convolution
+# taps, whose arithmetic is float32 anyway
 F32_LEAVES = frozenset({"norm_in", "norm_post", "final_norm", "q_norm",
                         "k_norm", "norm", "A_log", "dt_bias", "conv"})
+# positions a chunk of the head's loss holds: float32 logits of a chunk are
+# (microbatch, 2048, vocabulary)
+_HEAD_CHUNK = 2048
+
+
+def check(spec) -> None:
+    """The sizes a period of these kinds needs."""
+    missing = [name for name, part in (
+        ("attention", spec.attention), ("gdn", spec.delta_net))
+        if name in spec.period and part is None]
+    if missing or spec.experts is None:
+        raise ValueError(f"a hybrid period needs its "
+                         f"{missing + ['experts']} sizes")
+    lo, hi = spec.experts.held
+    if not 0 <= lo < hi <= spec.experts.n_experts:
+        raise ValueError(f"experts held {spec.experts.held} is no "
+                         f"range of {spec.experts.n_experts}")
+
+
+def meta(spec) -> dict:
+    """What a checkpoint must agree on to be resumed."""
+    return {"d_model": spec.d_model, "period": "/".join(spec.period),
+            "n_periods": spec.n_periods,
+            "experts_held": list(spec.experts.held)}
+
+
+def init(spec, seed: int) -> dict:
+    # late-bound: compile_hybrid_v5e.py stands shapes in for `init_hybrid`
+    return init_hybrid(spec, seed)
+
+
+def cast(p, dtype):
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: a if path[-1].key in F32_LEAVES else a.astype(dtype),
+        p)
+
+
+def embed(p, tokens, seq_off):
+    """(mb, S) -> (mb, S, d); positions are rotary, inside attention."""
+    return p["embed"][tokens]
 
 
 def rms_norm(x, w, eps: float):
@@ -147,6 +196,70 @@ def hybrid_layer(h, lp, kind: str, spec, attention: str, remat: bool):
     if remat:
         mix, experts = jax.checkpoint(mix), jax.checkpoint(experts)
     return experts(mix(h, lp), lp)
+
+
+def stage(x, layers, spec, attention: str, remat, tp_axis=None,
+          cp_axis=None):
+    """(mb, S, d) through this stage's periods, each the description's
+    sequence of layer kinds -> (x, `STATS`). Every sublayer is recomputed
+    in the backward pass when `remat` is set, whichever value it has
+    (ROADMAP D13)."""
+    def one_period(h_x, lps):
+        stats = jnp.zeros(STATS.shape, STATS.dtype)
+        for kind, lp in zip(spec.period, lps):
+            h_x, (routed, held, load) = hybrid_layer(
+                h_x, lp, kind, spec, attention, bool(remat))
+            stats = stats + jnp.stack(
+                [routed, held, load, jnp.float32(1.0)])
+        return h_x, stats
+    x, stats = jax.lax.scan(one_period, x, layers)
+    return x, stats.sum(0)
+
+
+def head_loss(p, y, targets, mask, spec):
+    """Final RMSNorm and the untied head on the last stage's (mb, S, d):
+    the masked SUM of the next-token losses, `_HEAD_CHUNK` positions at a
+    time, each chunk's logits recomputed in the backward pass: float32
+    logits and their gradient exist for one chunk, not for the
+    microbatch."""
+    seq = y.shape[1]
+    n_chunks = -(-seq // _HEAD_CHUNK)
+    pad = n_chunks * _HEAD_CHUNK - seq
+
+    def chunked(a):      # (mb, S, ...) -> (chunks, mb, C, ...)
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape((a.shape[0], n_chunks, _HEAD_CHUNK) + a.shape[2:])
+        return jnp.moveaxis(a, 1, 0)
+
+    @jax.checkpoint
+    def one_chunk(acc, xs):
+        y_c, tgt_c, mask_c = xs
+        z = rms_norm(y_c, p["final_norm"], spec.norm_eps)
+        logits = jnp.einsum("msd,vd->msv", z, p["head"],
+                            preferred_element_type=jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, tgt_c[..., None], axis=-1)[..., 0]
+        return acc + (nll * mask_c).sum(), None
+
+    mask = jnp.broadcast_to(mask, targets.shape)
+    total, _ = jax.lax.scan(one_chunk, jnp.float32(0.0),
+                            (chunked(y), chunked(targets), chunked(mask)))
+    return total
+
+
+def summary(stats) -> list:
+    """What of a step's summed `STATS` leaves the program with the loss:
+    (pairs routed, pairs held) and the mean over expert-layer calls of max
+    load over mean load."""
+    return [stats[:2], stats[2:3] / stats[3]]
+
+
+def report(values) -> None:
+    """A step's `summary`, on the host, into the expert layers' counters."""
+    reliability_metrics.inc(tnames.MOE_PAIRS_ROUTED, int(values[0]))
+    reliability_metrics.inc(tnames.MOE_PAIRS_HELD, int(values[1]))
+    reliability_metrics.set_gauge(tnames.MOE_LOAD_MAX_OVER_MEAN,
+                                  float(values[2]))
 
 
 def init_hybrid(spec, seed: int) -> dict:

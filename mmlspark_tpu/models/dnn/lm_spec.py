@@ -7,12 +7,13 @@ axis the pipeline shards) and scans periods. A dense GPT-2 block is a period
 of one layer; a hybrid decoder's period is, for example, three Gated
 DeltaNet layers and one full-attention layer.
 
-Layer kinds:
+Layer kinds, each with the FAMILY (a module) that holds its layers and
+their embedding, head, layout and counters (docs/dnn.md "Model families"):
   "dense"      LayerNorm, full multi-head attention, GELU MLP, learned
-               positions, head tied to the embedding (`pp_training._block`)
+               positions, head tied to the embedding      (`dense_layers`)
   "gdn"        zero-centred RMSNorm, Gated DeltaNet, sparse experts
   "attention"  zero-centred RMSNorm, gated grouped-KV attention with partial
-               rotary embedding, sparse experts   (`hybrid_layers`)
+               rotary embedding, sparse experts      (`hybrid_layers`)
 
 What a description cannot say yet is in ROADMAP.md (queue R and D2).
 """
@@ -20,6 +21,12 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Optional
+
+from . import dense_layers, hybrid_layers
+
+# layer kind -> the family that holds it; a period's kinds belong to one
+FAMILIES = {"dense": dense_layers, "gdn": hybrid_layers,
+            "attention": hybrid_layers}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,39 +77,29 @@ class LMSpec:
     init_std: float = 0.02
 
     def __post_init__(self):
-        kinds = set(self.period)
-        if not self.period or not kinds <= {"dense", "gdn", "attention"}:
+        if not self.period or not set(self.period) <= set(FAMILIES):
             raise ValueError(f"period {self.period!r}: layer kinds are "
-                             f"dense | gdn | attention")
-        if "dense" in kinds and kinds != {"dense"}:
-            raise ValueError("a dense block does not mix with hybrid layers "
-                             "in one period")
+                             f"{' | '.join(FAMILIES)}")
+        other = next((k for k in self.period
+                      if FAMILIES[k] is not self.family), None)
+        if other is not None:
+            raise ValueError(
+                f"a period's kinds belong to one family: "
+                f"{self.period[0]!r} ({self.family.__name__}) does not "
+                f"mix with {other!r} ({FAMILIES[other].__name__})")
         if self.n_periods < 1:
             raise ValueError(f"n_periods must be >= 1, got {self.n_periods}")
-        if self.hybrid:
-            missing = [name for name, part in (
-                ("attention", self.attention), ("gdn", self.delta_net))
-                if name in kinds and part is None]
-            if missing or self.experts is None:
-                raise ValueError(f"a hybrid period needs its "
-                                 f"{missing + ['experts']} sizes")
-            lo, hi = self.experts.held
-            if not 0 <= lo < hi <= self.experts.n_experts:
-                raise ValueError(f"experts held {self.experts.held} is no "
-                                 f"range of {self.experts.n_experts}")
+        self.family.check(self)
 
     @property
-    def hybrid(self) -> bool:
-        return "dense" not in self.period
+    def family(self):
+        """The module that holds this period's layer kinds."""
+        return FAMILIES[self.period[0]]
 
     @property
     def meta(self) -> dict:
         """What a checkpoint must agree on to be resumed."""
-        if not self.hybrid:
-            return {"n_heads": self.n_heads, "d_model": self.d_model}
-        return {"d_model": self.d_model, "period": "/".join(self.period),
-                "n_periods": self.n_periods,
-                "experts_held": list(self.experts.held)}
+        return self.family.meta(self)
 
 
 def gpt2_spec(vocab_size: int, d_model: int, n_heads: int, n_layers: int,

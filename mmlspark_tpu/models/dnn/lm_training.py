@@ -157,8 +157,8 @@ class ShardedLMTrainer:
 
         # donate params + opt state ON TPU: non-donated steps leave a
         # fresh ~3x-model-size output tree per call and measured 4.6x
-        # slower on the dev chip (see pp_training.train_step for numbers
-        # and for why CPU must NOT donate — multi-device CPU aliasing
+        # slower on the dev chip (see pp_training.train_step for why CPU
+        # must NOT donate — multi-device CPU aliasing
         # SIGABRTs under shard_map/collective programs)
         self._donate = ((0, 1) if mesh.devices.flat[0].platform == "tpu"
                         else ())

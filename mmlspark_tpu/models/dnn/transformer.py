@@ -85,10 +85,8 @@ def transformer_apply(params: dict, tokens, causal: bool = False,
     key_mask: (seq,) bool excluding padding keys from attention (dense only;
     the sequence-parallel paths take exact-length documents).
     attention_dtype: cast q/k/v to this dtype for the attention op (e.g.
-    jnp.bfloat16 — measured on v5e at 16k causal, BENCH_MODE=flash: bf16
-    operands run the flash forward ~1.1x and fwd+bwd ~1.5x faster than
-    f32, the backward gap coming from the larger VMEM blocks bf16
-    affords). Scores and softmax accumulation stay f32 on every path
+    jnp.bfloat16: the flash kernels' bf16 operands afford larger VMEM
+    blocks than f32 ones). Scores and softmax accumulation stay f32 on every path
     (dense, flash, ring, ulysses); the output is cast back to the
     residual dtype.
     """
@@ -162,9 +160,7 @@ class TransformerSentenceEncoder(Model, HasInputCol, HasOutputCol):
     attention_dtype = Param(
         "attention_dtype",
         "cast q/k/v to this dtype inside encode_long's attention "
-        "(bfloat16 runs the flash forward ~1.1x faster than f32 on v5e, "
-        "measured at 16k causal via BENCH_MODE=flash; softmax "
-        "accumulation stays f32 on every path)", None,
+        "(softmax accumulation stays f32 on every path)", None,
         validator=one_of(None, "bfloat16", "float32"))
 
     def __init__(self, **kw):

@@ -29,7 +29,7 @@ _DEVICE_SHAP_MAX_DEPTH = 8
 # executor-LOCAL model scoring (HTTPSourceV2 pipelines run on the
 # executor, docs/mmlspark-serving.md:142-146). Host scoring of a
 # 256-row batch through 20 trees is ~100 us. Large batches still take
-# the jitted device scan (bulk inference throughput, BENCH_MODE=predict),
+# the jitted device scan (bulk inference throughput),
 # and so do big ENSEMBLES on mid-size batches: the host loop is
 # O(rows x trees x depth) python-dispatched numpy, so the auto route
 # also caps total element-ops (a 2000-tree model on 4000 rows would be

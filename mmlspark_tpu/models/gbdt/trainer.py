@@ -284,8 +284,7 @@ def route_rows_level(bins_t, node_of_row, node_local, feat, thr, apply,
     loop issued up to 63 separate dynamic slices of `bins_t` per tree,
     each its own fusion; the gather plus the select chain below is a
     single fused elementwise pass per level. No n x F or n x m f32
-    materialization at all. Shared with bench.py's per-phase breakdown so
-    the measured routing cost is the shipped routing code."""
+    materialization at all."""
     w16 = 0 if words is None else words.shape[-1]
     bins_sel = jnp.take(bins_t, feat, axis=0, mode="clip").astype(
         jnp.int32)                                           # (m, n) stripes

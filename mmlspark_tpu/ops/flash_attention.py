@@ -826,17 +826,6 @@ def _flash_shd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
                           interpret)
 
 
-def _xla_reference_shd(q, k, v, causal, scale):
-    s = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
-    if causal:
-        qp = jnp.arange(q.shape[1])[:, None]
-        kp = jnp.arange(k.shape[1])[None, :]
-        s = jnp.where((qp >= kp)[None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("hqk,hkd->hqd", p, v.astype(jnp.float32)).astype(q.dtype)
-
-
 def _flash_fwd_vjp(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
                    bwd_block_k, interpret):
     out, lse = _flash_forward_lse(q, k, v, causal, scale, block_q, block_k,

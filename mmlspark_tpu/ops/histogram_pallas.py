@@ -57,8 +57,7 @@ LO = sqrt(2.5*mB) — hardware-friendly LO comes from the table below.
     narrow-lane LO=16/32 joint layouts at B=64 and the direct kernel at
     m=64, B=255 under the default scoped-VMEM limit. No row has been
     TIMED since the kernel was rewritten: which LO wins where is still the
-    old measurement (B >= 128) or the analytic model (B < 128), and
-    BENCH_MODE=hist is the grid that re-measures it.
+    old measurement (B >= 128) or the analytic model (B < 128).
 
 LEVEL-INVARIANT ONE-HOT REUSE (round 6). The lo digit of the joint key is
 bin % LO whenever LO divides B — independent of the node assignment, i.e.
@@ -74,8 +73,7 @@ LO=64 plane block that B >= 128 would need — (32, 64, 4096) int8, double-
 buffered — exhausts v5e's scoped VMEM at compile time (PR 21 chip run), so
 those shapes take the computed joint route with or without a plan. The
 LO=16 planes kernel compiles and matches the scatter on the chip. Opt-in
-via MMLSPARK_TPU_HIST=planes until a chip A/B (bench.py emits it) proves a
-win: the analytic model puts planes within ~10-20% of the computed joint
+via MMLSPARK_TPU_HIST=planes until a chip A/B proves a win: the analytic model puts planes within ~10-20% of the computed joint
 at 8M x 32 x 64 because the VPU saving is partially repaid as plane
 streaming (4 GB/level at LO=16).
 
@@ -332,9 +330,8 @@ def pallas_hist(bins, grad, hess, node_local, active, n_nodes: int,
 
     `lo_planes`/`plane_lo`: per-fit precomputed lo one-hot planes from
     build_hist_plan — enables the 'planes' route for shallow levels.
-    `route`: explicit ('direct'|'joint'|'planes', LO) override, the
-    bench/test hook behind BENCH_MODE=hist's per-route grid; None = the
-    kernel_route table."""
+    `route`: explicit ('direct'|'joint'|'planes', LO) override, the hook
+    of tests that run one route; None = the kernel_route table."""
     n, F = bins.shape
     # uint8 end to end: the transpose stays 1 byte/element in HBM (an i32
     # operand would materialize 4x the traffic and a convert pass per level;

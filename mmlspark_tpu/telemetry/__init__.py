@@ -63,7 +63,7 @@ Pillars:
 
 Sampling defaults OFF (env `MMLSPARK_TPU_TRACE_SAMPLE`, or
 `telemetry.configure(sample=...)`): at 0% the hot-path cost is a single
-compare per site (`BENCH_MODE=telemetry` pins the off/1%/full A/B).
+compare per site.
 """
 from .spans import (CAPACITY_ENV, REQUEST_ID_HEADER, SAMPLE_ENV, Span,
                     SpanContext, TAIL_ENV, TRACE_HEADER, Tracer, configure,

@@ -774,8 +774,8 @@ FAULT_SITES = {
 
 # ------------------------------------------- benchdiff record names
 # Not registry metrics (nothing inc()s or gauges them): these are the
-# canonical names of JSON records bench.py emits and benchdiff gates.
-# They live here so the bench writer and the gate assertions share one
+# canonical names of the JSON records benchdiff gates (ROADMAP D4b).
+# They live here so a writer and the gate assertions share one
 # spelling (docs/observability.md "MULTICHIP rounds gate like bench
 # rounds" describes the record shape benchdiff gates).
 COMM_GBDT_VOTE_OPS = "comm.gbdt.vote.ops"

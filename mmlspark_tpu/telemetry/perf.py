@@ -50,9 +50,8 @@ those gaps (docs/observability.md "Performance observability"):
   bundle dir is configured (env ``MMLSPARK_TPU_BUNDLE_DIR`` or
   `configure_flight_recorder(bundle_dir=...)`).
 
-`hbm_utilization` also lives here: the bench honesty metric (achieved
-bytes/s over measured copy bandwidth) extracted from bench.py so every
-future harness computes it the same way.
+`hbm_utilization` also lives here: achieved bytes/s over measured copy
+bandwidth, in one place so every harness computes it the same way.
 """
 from __future__ import annotations
 

@@ -39,9 +39,8 @@ the ROADMAP item-4 autotuner's measured rows.
   self-time when a parse provided it, host-noted wall otherwise) with
   `CompileLog` cost analysis into achieved FLOP/s and HBM bytes/s
   against peak (env/chip table, `resolve_peaks`). Exported as
-  `op.<region>.{hbm_util,flops_util}` gauges, the `roofline.json`
-  section of every flight bundle, and the `roofline` block of bench.py's
-  headline record. A side that is unknown (no peak declared, no cost
+  `op.<region>.{hbm_util,flops_util}` gauges and the `roofline.json`
+  section of every flight bundle. A side that is unknown (no peak declared, no cost
   analysis for the region) leaves its gauge ABSENT — never guessed,
   same contract as MFU.
 """

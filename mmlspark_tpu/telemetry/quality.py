@@ -17,8 +17,7 @@ online (docs/observability.md "Model-quality observability"):
   ingest/fit time (frozen into the served model's plan payload), and
   the LIVE profile folded on the serving hot path — head-sampled by
   request id (deterministic, the span sampler's own crc32 rule) so the
-  batch-of-1 continuous path stays sub-ms (`BENCH_MODE=quality` pins
-  the stated overhead budget).
+  batch-of-1 continuous path stays sub-ms.
 - **Drift scores** (`psi` / `js_divergence` / `drift_scores`):
   Population Stability Index and Jensen-Shannon divergence over the
   SHARED bucket grids. Counts sum across chunks and workers — never

@@ -1,6 +1,6 @@
 """Where this checkout keeps JAX's persistent compilation cache.
 
-One helper, `enable_compile_cache()`, used by tests/conftest.py, bench.py,
+One helper, `enable_compile_cache()`, used by tests/conftest.py,
 chip_smoke.py and the child script of tests/test_supervisor.py:
 
 - `JAX_COMPILATION_CACHE_DIR` set: JAX reads the variable itself and this

@@ -233,7 +233,7 @@ class SARServingModel(SARModel):
         """Users-only tables answer with the seed host recommend path
         (affinity re-upload + per-batch top_k) shaped like the compiled
         plan's (n, 2, k) output — the uncompiled fast_path=False serving
-        baseline BENCH_MODE=workloads A/Bs against. Tables carrying the
+        baseline. Tables carrying the
         item column keep the seed (user, item) -> rating scoring."""
         if self.item_col in t:
             return super()._transform(t)

@@ -4,8 +4,7 @@ tests/resources/benchmarks/, `add_benchmark(name, value, precision)` compares
 each run against the stored golden (creating it on first run).
 
 Also home of `measure_quiet` — the tier-1 deflake helper for wall-clock
-capability floors (the PR-9 quiet-host-retry pattern, see bench.py's
-serving A/B): a throughput/latency FLOOR proves a capability, so one
+capability floors (the PR-9 quiet-host-retry pattern): a throughput/latency FLOOR proves a capability, so one
 quiet pass suffices; host contention can only push the measurement the
 failing way. Retry with a settle pause before letting a single noisy run
 fail the suite.

@@ -86,7 +86,7 @@ def regression_table():
 @pytest.fixture
 def bench_rounds(tmp_path):
     """Five driver-format headline rounds (`{"n", "parsed", "tail"}`
-    wrappers around bench.py's GBDT record, all measured on a TPU) plus a
+    wrappers around a GBDT headline record, all measured on a TPU) plus a
     builder-format extras file from a CPU run. The shape telemetry.benchdiff
     reads; the values are fixtures, with an hbm_utilization dip from round 4
     to round 5 so that a 10% gate fires."""

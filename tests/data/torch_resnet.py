@@ -106,8 +106,8 @@ def export_resnet18_onnx(path: str, seed: int = 0, spatial: int = 224,
     patch is scoped to the export and RESTORED after, since the target is a
     process-global torch private. When the private path has moved in this
     torch build: a clear pytest skip inside a test run, a plain
-    RuntimeError from CLI callers (bench.py's ONNX mode must not grow a
-    pytest dependency)."""
+    RuntimeError from CLI callers (which must not grow a pytest
+    dependency)."""
     import os
     mod = _find_onnx_proto_utils()
     if mod is None:

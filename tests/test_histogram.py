@@ -155,7 +155,7 @@ def test_kernel_route_table_pinned():
     """THE routing table (histogram_pallas docstring) as executable pins:
     a route change must show up as a diff here, not silently in perf."""
     expect = {
-        # B = 64 (round-6 analytic rows; BENCH_MODE=hist refreshes)
+        # B = 64 (round-6 analytic rows, not timed since: ROADMAP D3)
         (1, 64): ("joint", 16), (2, 64): ("joint", 16),
         (4, 64): ("joint", 32), (8, 64): ("direct", 64),
         (16, 64): ("direct", 64), (32, 64): ("direct", 64),

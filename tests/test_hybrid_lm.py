@@ -326,11 +326,8 @@ def test_experts_held_must_be_a_range_of_the_router():
                                                       (8, 17))})
 
 
-def test_hybrid_refuses_model_and_seq_axes():
-    from mmlspark_tpu.parallel import MODEL_AXIS
-    with pytest.raises(ValueError, match="data and pipe axes"):
-        hybrid_trainer(mesh=grid_mesh((1, 1, 1), (DATA_AXIS, PIPE_AXIS,
-                                                  MODEL_AXIS)))
+def test_hybrid_periods_divide_by_the_pipe_axis():
+    # the model and seq axes' refusal: tests/test_lm_families.py
     with pytest.raises(ValueError, match="n_periods"):
         hybrid_trainer(mesh=grid_mesh((1, 4), (DATA_AXIS, PIPE_AXIS)))
 

@@ -441,8 +441,7 @@ def test_voting_reduces_allreduce_bytes_4x():
 def test_oocore_larger_than_budget_smoke(tmp_path):
     """The mmap smoke at real scale (excluded from tier-1 by the `slow`
     mark): a 25 MB .npy staged under a 2 MB residency budget, fit
-    bit-identical to in-core. BENCH_OOCORE_ROWS scales the same path
-    arbitrarily from bench.py (BENCH_MODE=oocore)."""
+    bit-identical to in-core."""
     rng = np.random.default_rng(0)
     n, f = 200_000, 32
     x = rng.normal(size=(n, f)).astype(np.float32)

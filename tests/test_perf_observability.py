@@ -644,7 +644,7 @@ def test_benchdiff_gbdt_gates(tmp_path, capsys):
 
 
 def test_benchdiff_fleet_gates(tmp_path, capsys):
-    """Round-16 fleet gates: the BENCH_MODE=fleet headline synthesizes
+    """Round-16 fleet gates: the fleet headline record synthesizes
     fleet.rollback_window_p99_ms and fleet.requests_dropped as born
     lower-is-better — a round that stretched the chaos-window tail or
     dropped even one request during rollback fails the diff even though

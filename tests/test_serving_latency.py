@@ -3,8 +3,7 @@ cache, and latency-percentile observability.
 
 Per the round-5 advisor flake finding (GPipe M-sweep): tier-1 asserts
 ORDERING / MONOTONIC invariants and metric PRESENCE only — never absolute
-wall-clock thresholds. Absolute latency/throughput numbers live in
-`BENCH_MODE=serving python bench.py` output.
+wall-clock thresholds.
 """
 import json
 import threading
