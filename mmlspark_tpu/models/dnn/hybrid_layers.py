@@ -12,11 +12,14 @@ normalisation, the convolution's sum and every softmax in float32.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...ops.gated_delta import chunk_gated_delta_rule
+from ...ops.gated_delta import gated_delta_slab
+from ...ops.gdn_mixer import gdn_finish, gdn_prepare
 from ...parallel import DATA_AXIS, PIPE_AXIS
 from ...reliability.metrics import reliability_metrics
 from ...telemetry import names as tnames
@@ -128,32 +131,54 @@ def attention_mixer(x, p, a, eps: float, attention: str):
     return _matmul(out.reshape(b, s, h * d) * gate, p["o_proj"])
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def gdn_in_proj(x, w_qkvz, w_ba, n_qkv: int):
+    """The DeltaNet mixer's two input projections of x (B, S, d): qkv
+    (B, S, n_qkv) and z (the rest of `w_qkvz`'s columns) in x's dtype, ba
+    float32. The split is made at the weights, so qkv and z are slabs of
+    their own and no slice of a (B, S, .) array is ever copied; the
+    backward sums x's three cotangents in float32 and rounds once."""
+    f32 = jnp.float32
+    return (_matmul(x, w_qkvz[:, :n_qkv]), _matmul(x, w_qkvz[:, n_qkv:]),
+            jnp.einsum("bsd,df->bsf", x, w_ba, preferred_element_type=f32))
+
+
+def _gdn_in_proj_fwd(x, w_qkvz, w_ba, n_qkv):
+    return gdn_in_proj(x, w_qkvz, w_ba, n_qkv), (x, w_qkvz, w_ba)
+
+
+def _gdn_in_proj_bwd(n_qkv, res, cts):
+    x, w_qkvz, w_ba = res
+    f32 = jnp.float32
+
+    def dx(ct, w):
+        return jnp.einsum("bsf,df->bsd", ct, w, preferred_element_type=f32)
+
+    def dw(ct, w):
+        return jnp.einsum("bsd,bsf->df", x, ct,
+                          preferred_element_type=f32).astype(w.dtype)
+
+    dqkv, dz, dba = cts
+    return ((dx(dqkv, w_qkvz[:, :n_qkv]) + dx(dz, w_qkvz[:, n_qkv:])
+             + dx(dba, w_ba)).astype(x.dtype),
+            jnp.concatenate([dw(dqkv, w_qkvz), dw(dz, w_qkvz)], axis=1),
+            dw(dba, w_ba))
+
+
+gdn_in_proj.defvjp(_gdn_in_proj_fwd, _gdn_in_proj_bwd)
+
+
 def gdn_mixer(x, p, g, eps: float):
     """Gated DeltaNet on normed x (B, S, d). `g`: the spec's
-    GatedDeltaNet."""
-    b, s, _ = x.shape
+    GatedDeltaNet. Between the projections every array is a (B, S, H d)
+    slab (docs/dnn.md "The DeltaNet mixer's layout")."""
     hk, hv, dk, dv = g.n_key_heads, g.n_value_heads, g.key_dim, g.value_dim
     f32 = jnp.float32
-    qkvz = _matmul(x, p["in_proj_qkvz"])
-    n_qkv = 2 * hk * dk + hv * dv
-    z = qkvz[..., n_qkv:].reshape(b, s, hv, dv)
-    ba = jnp.einsum("bsd,df->bsf", x, p["in_proj_ba"],
-                    preferred_element_type=f32)
-    padded = jnp.pad(qkvz[..., :n_qkv],
-                     ((0, 0), (g.conv_width - 1, 0), (0, 0)))
-    taps = p["conv"].astype(f32)
-    qkv = jax.nn.silu(sum(padded[:, j:j + s].astype(f32) * taps[j]
-                          for j in range(g.conv_width)))
-
-    def l2(t):
-        return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
-
-    q = l2(qkv[..., :hk * dk].reshape(b, s, hk, dk)) * dk ** -0.5
-    k = l2(qkv[..., hk * dk:2 * hk * dk].reshape(b, s, hk, dk))
+    qkv, z, ba = gdn_in_proj(x, p["in_proj_qkvz"], p["in_proj_ba"],
+                             2 * hk * dk + hv * dv)
     # the recurrence reads the hk key heads as they are: value head h
     # takes key head h // (hv / hk), so nothing is repeated
-    q, k = q.astype(x.dtype), k.astype(x.dtype)
-    v = qkv[..., 2 * hk * dk:].reshape(b, s, hv, dv).astype(x.dtype)
+    q, k, v = gdn_prepare(qkv, p["conv"], (hk, dk, hv, dv))
     beta = jax.nn.sigmoid(ba[..., :hv])
     decay = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
         ba[..., hv:] + p["dt_bias"].astype(f32))
@@ -161,12 +186,8 @@ def gdn_mixer(x, p, g, eps: float):
         # the batch goes in whole: it is a grid axis of the kernels, and
         # under a `vmap` here they would be named `vmap_gdn_fwd_` (the trap
         # `attention_mixer` describes)
-        o = chunk_gated_delta_rule(q, k, v, decay, beta)
-    o32 = o.astype(f32)
-    o32 = o32 * jax.lax.rsqrt((o32 * o32).mean(-1, keepdims=True) + eps)
-    o = (o32 * p["norm"].astype(f32)).astype(x.dtype) \
-        * jax.nn.silu(z.astype(f32)).astype(x.dtype)
-    return _matmul(o.reshape(b, s, hv * dv), p["out_proj"])
+        o = gated_delta_slab(q, k, v, decay, beta, dk, dv)
+    return _matmul(gdn_finish(o, z, p["norm"], dv, eps), p["out_proj"])
 
 
 def hybrid_layer(h, lp, kind: str, spec, attention: str, remat: bool):

@@ -34,7 +34,10 @@ inputs are bfloat16 or float32.
   (H, ., .) arrays and batched products. The blocks read q, k, v as
   (B, S, H x d) slabs, the layout the mixer's projections already have (a
   head is a 128-lane slice of a row, so no transpose exists anywhere), and
-  write `o` the same way. Grouped key heads are read as they are: value
+  write `o` the same way; `gated_delta_slab` takes and returns those
+  slabs, which is how the mixer calls (`ops/gdn_mixer.py` prepares and
+  finishes them in the same layout), and the (B, S, H, d) signature is a
+  reshape round it. Grouped key heads are read as they are: value
   head h takes lanes of key head h // (H / Hk), and the backward sums dq and
   dk over the group in float32 before it rounds them.
 - The recurrent state, (heads, dk, dv) float32, lives in a VMEM scratch
@@ -132,13 +135,33 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK):
     return (jax.vmap(form) if q.ndim == 4 else form)(q, k, v, g, beta)
 
 
+def gated_delta_slab(q, k, v, g, beta, dk: int, dv: int):
+    """`chunk_gated_delta_rule` on the layout the mixer keeps end to end:
+    q, k (B, S, Hk dk), v (B, S, H dv), a head a group of lanes of a row,
+    g and beta (B, S, H) -> o (B, S, H dv). The kernels read and write
+    these slabs as they are; the XLA form sees them as (B, S, H, d)."""
+    if _fits(dk, dv, q.shape[-1] // dk, v.shape[-1] // dv, q.dtype,
+             v.dtype) and jax.devices()[0].platform == "tpu":
+        return _pallas_slab(q, k, v, g, beta, dk, dv)
+
+    def heads(t, d):
+        return t.reshape(t.shape[:2] + (-1, d))
+
+    return chunk_gated_delta_rule(
+        heads(q, dk), heads(k, dk), heads(v, dv), g, beta).reshape(v.shape)
+
+
 def pallas_fits(q, v, chunk: int = CHUNK) -> bool:
     """The kernels' shape rule: whole 128-lane heads, the chunk they were
     written for, bfloat16 or float32."""
-    return (chunk == CHUNK and q.shape[-1] % _LANES == 0
-            and v.shape[-1] % _LANES == 0 and q.dtype == v.dtype
-            and v.shape[-2] % q.shape[-2] == 0
-            and q.dtype in (jnp.bfloat16, jnp.float32))
+    return chunk == CHUNK and _fits(q.shape[-1], v.shape[-1], q.shape[-2],
+                                    v.shape[-2], q.dtype, v.dtype)
+
+
+def _fits(dk, dv, key_heads, heads, q_dtype, v_dtype) -> bool:
+    return (dk % _LANES == 0 and dv % _LANES == 0 and q_dtype == v_dtype
+            and heads % key_heads == 0
+            and q_dtype in (jnp.bfloat16, jnp.float32))
 
 
 def _chunk_gated_delta_rule_xla(q, k, v, g, beta, chunk: int = CHUNK):
@@ -542,14 +565,27 @@ def gated_delta_pallas(q, k, v, g, beta, *, interpret: bool = False,
             f"gated_delta_pallas wants dk and dv in multiples of {_LANES} "
             f"and bfloat16 or float32, got q {q.shape} {q.dtype}, "
             f"v {v.shape} {v.dtype}")
-    reliability_metrics.inc(tnames.GDN_SCAN_ROUTE_PALLAS)
     if q.ndim == 3:
         return gated_delta_pallas(
             q[None], k[None], v[None], g[None], beta[None],
             interpret=interpret, heads_per_step=heads_per_step)[0]
-    batch, seq, key_heads, dk = q.shape
-    heads, dv = v.shape[2:]
-    rep = heads // key_heads
+    batch, seq, _, dk = q.shape
+
+    def slab(t):                      # (B, S, H, d) -> (B, S, H d)
+        return t.reshape(batch, seq, -1)
+
+    return _pallas_slab(slab(q), slab(k), slab(v), g, beta, dk, v.shape[-1],
+                        interpret, heads_per_step).reshape(v.shape)
+
+
+def _pallas_slab(q, k, v, g, beta, dk: int, dv: int, interpret: bool = False,
+                 heads_per_step: int = HEADS_PER_STEP):
+    """The kernels on slabs q, k (B, S, Hk dk), v (B, S, H dv) with g, beta
+    (B, S, H) -> o (B, S, H dv)."""
+    reliability_metrics.inc(tnames.GDN_SCAN_ROUTE_PALLAS)
+    batch, seq, _ = v.shape
+    heads = v.shape[-1] // dv
+    rep = heads // (q.shape[-1] // dk)
     f32 = jnp.float32
     # heads a step: whole groups of the `rep` heads that share a key head
     hb = max([d for d in range(rep, max(heads_per_step, rep) + 1, rep)
@@ -558,16 +594,15 @@ def gated_delta_pallas(q, k, v, g, beta, *, interpret: bool = False,
     pad = (-seq) % CHUNK
     n = (seq + pad) // CHUNK
 
-    def slab(t):                      # (B, S, H, d) -> (B, S', H d)
-        t = jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return t.reshape(batch, n * CHUNK, -1)
+    def whole_chunks(t):              # (B, S, ...) -> (B, S', ...)
+        return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
 
     def along_lanes(t):               # (B, S, H) -> (B, H/hb, n, hb, C)
-        t = jnp.pad(t.astype(f32), ((0, 0), (0, pad), (0, 0)))
+        t = whole_chunks(t.astype(f32))
         return jnp.transpose(t.reshape(batch, n, CHUNK, nh, hb),
                              (0, 3, 1, 4, 2))
 
-    o = _gdn(slab(q), slab(k), slab(v),
+    o = _gdn(whole_chunks(q), whole_chunks(k), whole_chunks(v),
              jnp.cumsum(along_lanes(g), axis=-1), along_lanes(beta),
              (hb, rep, dk, dv, bool(interpret)))
-    return o.reshape(batch, n * CHUNK, heads, dv)[:, :seq]
+    return o[:, :seq]

@@ -85,6 +85,8 @@ MOE_PAIRS_ROUTED = "moe.pairs.routed"
 MOE_PAIRS_HELD = "moe.pairs.held"
 GDN_SCAN_ROUTE_PALLAS = "gdn.scan.route.pallas"
 GDN_SCAN_ROUTE_XLA = "gdn.scan.route.xla"
+GDN_MIXER_ROUTE_PALLAS = "gdn.mixer.route.pallas"
+GDN_MIXER_ROUTE_XLA = "gdn.mixer.route.xla"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -232,6 +234,17 @@ COUNTERS = {
                         "form: off the TPU, or head sizes that are no "
                         "multiple of 128, or another chunk than 64 (never "
                         "silent)",
+    GDN_MIXER_ROUTE_PALLAS: "calls of the Gated-DeltaNet mixer's two fused "
+                            "passes (ops/gdn_mixer.py: gdn_prepare, "
+                            "gdn_finish) traced down the Pallas kernels "
+                            "gdn_prep_fwd / gdn_prep_bwd and gdn_post_fwd "
+                            "/ gdn_post_bwd: a TPU, head sizes in "
+                            "multiples of 128, bfloat16 or float32, or a "
+                            "test's own interpret-mode call; counted at "
+                            "trace time, once a pass and call site",
+    GDN_MIXER_ROUTE_XLA: "calls of the same two passes traced down their "
+                         "plain jnp form on the slab: off the TPU, or head "
+                         "sizes that are no multiple of 128 (never silent)",
     QUALITY_LABELS_JOINED: "delayed labels joined to their served "
                            "prediction (streaming evaluation pairs)",
     QUALITY_LABELS_LATE: "out-of-order labels that arrived BEFORE their "
@@ -510,8 +523,12 @@ DEVICE_REGIONS = {
     LM_OPT: "optimizer update + apply_updates over the f32 masters",
     LM_CAST: "per-step f32 -> compute-dtype cast of the parameters",
     LM_GDN: "Gated-DeltaNet mixer outside its recurrence: input norm, "
-            "qkvz/ba projections, causal convolution, gates, L2 norms, "
-            "gated output norm, out projection, residual",
+            "qkv/z/ba projections, gates, out projection, residual, and "
+            "on (B, S, H d) slabs (ops/gdn_mixer.py) the causal "
+            "convolution + SiLU + L2 norms as one pass (kernels "
+            "gdn_prep_fwd, gdn_prep_bwd) and the gated output norm as "
+            "another (gdn_post_fwd, gdn_post_bwd); off the TPU or at "
+            "other head sizes the same two passes in plain jnp",
     LM_GDN_SCAN: "the chunked gated delta rule (ops/gated_delta.py): on a "
                  "TPU at 128-wide heads the kernels gdn_fwd (forward, and "
                  "again for remat) and gdn_bwd with the running sum of the "

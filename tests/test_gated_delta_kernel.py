@@ -2,15 +2,20 @@
 `gdn_bwd`) through the interpreter on the CPU: output and all five gradients
 against the benchmark's positional reference and against the XLA form, the
 shape rule that chooses between kernel and XLA form, the counters that say
-which was taken, and the kernels' place in the compiled step's regions."""
+which was taken, and the kernels' place in the compiled step's regions; and,
+because one file a process may describe the chip in, the same compiled
+checks for the mixer's own kernels (`ops/gdn_mixer.py`, whose parities are
+in test_gdn_mixer_kernels.py)."""
 import importlib.util
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from mmlspark_tpu.ops import gated_delta as gd
+from mmlspark_tpu.ops import gdn_mixer as gm
 from mmlspark_tpu.reliability.metrics import reliability_metrics
 from mmlspark_tpu.telemetry import names as tnames
 
@@ -112,12 +117,15 @@ def test_unbatched_call_is_the_batched_one():
         *args, interpret=True)[0]).max()) == 0.0
 
 
-def routes(fn):
-    before = [reliability_metrics.get(n) for n in (
-        tnames.GDN_SCAN_ROUTE_PALLAS, tnames.GDN_SCAN_ROUTE_XLA)]
+SCAN_ROUTES = (tnames.GDN_SCAN_ROUTE_PALLAS, tnames.GDN_SCAN_ROUTE_XLA)
+MIXER_ROUTES = (tnames.GDN_MIXER_ROUTE_PALLAS, tnames.GDN_MIXER_ROUTE_XLA)
+
+
+def routes(fn, names=SCAN_ROUTES):
+    before = [reliability_metrics.get(n) for n in names]
     fn()
-    return tuple(reliability_metrics.get(n) - b for n, b in zip(
-        (tnames.GDN_SCAN_ROUTE_PALLAS, tnames.GDN_SCAN_ROUTE_XLA), before))
+    return tuple(reliability_metrics.get(n) - b
+                 for n, b in zip(names, before))
 
 
 @pytest.mark.parametrize("dk,dv,chunk,dtype", [
@@ -192,13 +200,15 @@ def test_kernels_compile_for_v5e_at_the_published_widths(v5e):
     assert f"%{gd.KERNEL_FWD}." in plain and gd.KERNEL_BWD not in plain
 
 
-def test_compiled_layer_holds_the_kernels_in_the_scan_region(v5e,
-                                                             monkeypatch):
+LAYER_TOKENS = (2, 512)
+
+
+@pytest.fixture(scope="module")
+def gdn_layer(v5e):
     """A DeltaNet layer as the trainer runs it (`jax.checkpoint` round the
-    mixer, bfloat16), at head sizes that fit the kernels, compiled for the
-    chip: every kernel call lies in region `lm.gdn.scan`, `gdn_fwd` as
-    `fwd` and as `remat`, `gdn_bwd` as `bwd`, so `gdn_scan_ms_per_step`
-    reads all of the recurrence; and the choice was counted."""
+    mixer, bfloat16), at head sizes that fit the kernels, its gradient
+    compiled for the chip: (the optimized text, its scope map, what the
+    recurrence's and the mixer's route counters counted meanwhile)."""
     from mmlspark_tpu.models.dnn import hybrid_layers
     from mmlspark_tpu.models.dnn.lm_spec import qwen3_next_spec
     from mmlspark_tpu.telemetry import perf
@@ -212,12 +222,13 @@ def test_compiled_layer_holds_the_kernels_in_the_scan_region(v5e,
                moe_intermediate_size=32, shared_expert_intermediate_size=32,
                norm_topk_prob=True, vocab_size=97)
     spec = qwen3_next_spec(cfg, (0, 8))
-    layer = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(
-            a.shape[1:], jnp.float32 if a.ndim == 2 else jnp.bfloat16,
-            sharding=chip),
+    layer = jax.tree_util.tree_map_with_path(
+        lambda path, a: jax.ShapeDtypeStruct(
+            a.shape[1:], jnp.float32 if path[-1].key in
+            hybrid_layers.F32_LEAVES else jnp.bfloat16, sharding=chip),
         hybrid_layers.init_hybrid(spec, 0)["layers"][0])
-    h = jax.ShapeDtypeStruct((2, 128, 64), jnp.bfloat16, sharding=chip)
+    h = jax.ShapeDtypeStruct(LAYER_TOKENS + (64,), jnp.bfloat16,
+                             sharding=chip)
 
     def loss(h, lp):
         out, _ = hybrid_layers.hybrid_layer(h, lp, "gdn", spec, "dense",
@@ -225,17 +236,92 @@ def test_compiled_layer_holds_the_kernels_in_the_scan_region(v5e,
         return out.astype(jnp.float32).sum()
 
     # the choice of path asks the platform of jax.devices()[0]
-    monkeypatch.setattr(jax, "devices", lambda *a: topo.devices)
-    scopes = {}
-    taken = routes(lambda: scopes.update(perf.scope_map(jax.jit(jax.grad(
-        loss, argnums=(0, 1))).lower(h, layer).compile().as_text())))
-    assert taken[0] > 0 and taken[1] == 0
-    kernels = {name: where for name, where in scopes.items()
-               if name.startswith(("gdn_", "vmap_gdn_"))}
-    assert {where for where in kernels.values()} == {
-        (tnames.LM_GDN_SCAN, "fwd"), (tnames.LM_GDN_SCAN, "remat"),
-        (tnames.LM_GDN_SCAN, "bwd")}
-    assert {name.split(".")[0]: way for name, (_, way) in kernels.items()
-            if way == "bwd"} == {gd.KERNEL_BWD: "bwd"}
-    assert all(name.startswith(gd.KERNEL_FWD)
-               for name, (_, way) in kernels.items() if way != "bwd")
+    real_devices = jax.devices
+    jax.devices = lambda *a: topo.devices
+    text, counted = [], {}
+    try:
+        counted["scan"] = routes(lambda: counted.update(mixer=routes(
+            lambda: text.append(jax.jit(jax.grad(loss, argnums=(
+                0, 1))).lower(h, layer).compile().as_text()),
+            MIXER_ROUTES)))
+    finally:
+        jax.devices = real_devices
+    return text[0], perf.scope_map(text[0]), counted
+
+
+
+def kernel_ways(scopes, *kernels):
+    """{(region, way)} of the instructions named after `kernels`."""
+    return {where for name, where in scopes.items()
+            if name.split(".")[0] in kernels}
+
+
+def test_compiled_layer_holds_the_kernels_in_the_scan_region(gdn_layer):
+    """Every call of the recurrence's kernels lies in region `lm.gdn.scan`,
+    `gdn_fwd` as `fwd` and as `remat`, `gdn_bwd` as `bwd`, so
+    `gdn_scan_ms_per_step` reads all of the recurrence; and the choice was
+    counted."""
+    _, scopes, counted = gdn_layer
+    assert counted["scan"][0] > 0 and counted["scan"][1] == 0
+    assert not [name for name in scopes if name.startswith("vmap_gdn_")]
+    assert kernel_ways(scopes, gd.KERNEL_FWD) == {
+        (tnames.LM_GDN_SCAN, "fwd"), (tnames.LM_GDN_SCAN, "remat")}
+    assert kernel_ways(scopes, gd.KERNEL_BWD) == {(tnames.LM_GDN_SCAN, "bwd")}
+
+
+# ---- the mixer's fused passes round the recurrence (ops/gdn_mixer.py)
+
+def test_mixer_kernels_compile_for_v5e_at_the_published_widths(v5e):
+    """16 key heads under 32 value heads of 128, bfloat16, the blocks the
+    cell runs (a shorter sequence: the grid's length is no part of a
+    kernel): what Mosaic refuses it refuses here."""
+    _, chip = v5e
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=chip)
+
+    heads = (16, 128, 32, 128)
+
+    def prep_loss(qkv, taps):
+        return sum(t.astype(jnp.float32).sum()
+                   for t in gm.prepare_pallas(qkv, taps, heads))
+
+    def post_loss(o, z, w):
+        return gm.finish_pallas(o, z, w, 128, 1e-6).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(prep_loss, argnums=(0, 1))).lower(
+        shape(2, 512, 8192), shape(4, 8192, dtype=jnp.float32)
+    ).compile().as_text()
+    # one call each for q, k and v, forward and backward
+    assert text.count(f"%{gm.KERNEL_PREP_FWD}.") >= 3
+    assert text.count(f"%{gm.KERNEL_PREP_BWD}.") >= 3
+    text = jax.jit(jax.value_and_grad(post_loss, argnums=(0, 1, 2))).lower(
+        shape(2, 512, 4096), shape(2, 512, 4096),
+        shape(128, dtype=jnp.float32)).compile().as_text()
+    assert f"%{gm.KERNEL_POST_FWD}." in text
+    assert f"%{gm.KERNEL_POST_BWD}." in text
+
+
+def test_compiled_layer_keeps_the_slab_and_the_regions(gdn_layer):
+    """The mixer's four kernels lie in region `lm.gdn` outside
+    `lm.gdn.scan`, the forward ones as `fwd` and as `remat`, the backward
+    ones as `bwd`; and between the projections nothing of a slab's size is
+    copied, reshaped or transposed."""
+    import re
+    text, scopes, counted = gdn_layer
+    assert counted["mixer"][0] > 0 and counted["mixer"][1] == 0
+    assert kernel_ways(scopes, gm.KERNEL_PREP_FWD, gm.KERNEL_POST_FWD) == {
+        (tnames.LM_GDN, "fwd"), (tnames.LM_GDN, "remat")}
+    assert kernel_ways(scopes, gm.KERNEL_PREP_BWD, gm.KERNEL_POST_BWD) == {
+        (tnames.LM_GDN, "bwd")}
+    # the narrowest slab here is q's, (2, 512, 2 x 128)
+    slab = int(np.prod(LAYER_TOKENS)) * 2 * 128
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%((?:copy|reshape|transpose)[\w.-]*) = "
+                     r"\w+\[([\d,]*)\]", line)
+        if m and scopes.get(m.group(1), ("",))[0] in (
+                tnames.LM_GDN, tnames.LM_GDN_SCAN) and np.prod(
+                [int(n) for n in m.group(2).split(",") if n]) >= slab:
+            moved.append(m.group(1))
+    assert moved == []
