@@ -26,7 +26,11 @@ STATS = ()
 
 
 def check(spec) -> None:
-    """Nothing beyond what `LMSpec` checks of every description."""
+    """A dense model is its period: one kind of block all the way."""
+    if spec.leading or spec.period_ffn:
+        raise ValueError("a dense model has no leading layers and one "
+                         "feed-forward kind: leading, leading_ffn and "
+                         "period_ffn stay empty")
 
 
 def meta(spec) -> dict:

@@ -48,10 +48,21 @@ def check(spec) -> None:
     if missing or spec.experts is None:
         raise ValueError(f"a hybrid period needs its "
                          f"{missing + ['experts']} sizes")
-    lo, hi = spec.experts.held
-    if not 0 <= lo < hi <= spec.experts.n_experts:
-        raise ValueError(f"experts held {spec.experts.held} is no "
-                         f"range of {spec.experts.n_experts}")
+    check_experts(spec.experts)
+    if spec.leading or spec.period_ffn:
+        raise ValueError("a hybrid model has no leading layers and one "
+                         "feed-forward kind: leading, leading_ffn and "
+                         "period_ffn stay empty")
+    if spec.experts.scoring != "softmax" or spec.experts.scale != 1.0:
+        raise ValueError("a hybrid model's router is the softmax one, "
+                         "unscaled")
+
+
+def check_experts(experts) -> None:
+    lo, hi = experts.held
+    if not 0 <= lo < hi <= experts.n_experts:
+        raise ValueError(f"experts held {experts.held} is no "
+                         f"range of {experts.n_experts}")
 
 
 def meta(spec) -> dict:
@@ -97,26 +108,20 @@ def rotary(x, theta: float, rot: int):
     return jnp.concatenate([out.astype(x.dtype), x[..., rot:]], axis=-1)
 
 
-def _matmul(x, w):
+def matmul(x, w):
     return jnp.einsum("...d,df->...f", x, w,
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def attention_mixer(x, p, a, eps: float, attention: str):
-    """Gated grouped-KV attention on normed x (B, S, d). `a`: the spec's
-    GatedAttention. Grouped KV reaches the kernels by repeating K and V."""
+def grouped_attention(q, k, v, attention: str):
+    """Causal attention of q (B, S, H, D) over k, v (B, S, KV, D), query
+    head j reading KV head j // (H / KV) -> (B, S, H, D). Grouped KV
+    reaches the kernels by repeating K and V."""
     from ...ops.flash_attention import flash_attention
     from ...parallel.ring_attention import reference_attention
-    b, s, _ = x.shape
-    h, kv, d = a.n_heads, a.n_kv_heads, a.head_dim
-    qg = _matmul(x, p["q_proj"]).reshape(b, s, h, 2 * d)
-    q, gate = qg[..., :d], qg[..., d:].reshape(b, s, h * d)
-    k = _matmul(x, p["k_proj"]).reshape(b, s, kv, d)
-    v = _matmul(x, p["v_proj"]).reshape(b, s, kv, d)
-    q = rotary(rms_norm(q, p["q_norm"], eps), a.rope_theta, a.rotary_dim)
-    k = rotary(rms_norm(k, p["k_norm"], eps), a.rope_theta, a.rotary_dim)
-    k = jnp.repeat(k, h // kv, axis=2)
-    v = jnp.repeat(v, h // kv, axis=2)
+    b, s, h, d = q.shape
+    k = jnp.repeat(k, h // k.shape[2], axis=2)
+    v = jnp.repeat(v, h // v.shape[2], axis=2)
     with jax.named_scope(tnames.LM_ATTN_FLASH):
         # the batch rides on the kernels' head axis: (S, B x H, D). Under a
         # `vmap` the kernels' instructions would be named `vmap_flash_fwd_`
@@ -126,9 +131,23 @@ def attention_mixer(x, p, a, eps: float, attention: str):
         attend = flash_attention if attention == "flash" \
             else reference_attention
         out = attend(heads(q), heads(k), heads(v), causal=True)
-        out = jnp.moveaxis(out.reshape(s, b, h, d), 0, 1)
+        return jnp.moveaxis(out.reshape(s, b, h, d), 0, 1)
+
+
+def attention_mixer(x, p, a, eps: float, attention: str):
+    """Gated grouped-KV attention on normed x (B, S, d). `a`: the spec's
+    GatedAttention."""
+    b, s, _ = x.shape
+    h, kv, d = a.n_heads, a.n_kv_heads, a.head_dim
+    qg = matmul(x, p["q_proj"]).reshape(b, s, h, 2 * d)
+    q, gate = qg[..., :d], qg[..., d:].reshape(b, s, h * d)
+    k = matmul(x, p["k_proj"]).reshape(b, s, kv, d)
+    v = matmul(x, p["v_proj"]).reshape(b, s, kv, d)
+    q = rotary(rms_norm(q, p["q_norm"], eps), a.rope_theta, a.rotary_dim)
+    k = rotary(rms_norm(k, p["k_norm"], eps), a.rope_theta, a.rotary_dim)
+    out = grouped_attention(q, k, v, attention)
     gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(x.dtype)
-    return _matmul(out.reshape(b, s, h * d) * gate, p["o_proj"])
+    return matmul(out.reshape(b, s, h * d) * gate, p["o_proj"])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -139,7 +158,7 @@ def gdn_in_proj(x, w_qkvz, w_ba, n_qkv: int):
     their own and no slice of a (B, S, .) array is ever copied; the
     backward sums x's three cotangents in float32 and rounds once."""
     f32 = jnp.float32
-    return (_matmul(x, w_qkvz[:, :n_qkv]), _matmul(x, w_qkvz[:, n_qkv:]),
+    return (matmul(x, w_qkvz[:, :n_qkv]), matmul(x, w_qkvz[:, n_qkv:]),
             jnp.einsum("bsd,df->bsf", x, w_ba, preferred_element_type=f32))
 
 
@@ -187,7 +206,7 @@ def gdn_mixer(x, p, g, eps: float):
         # under a `vmap` here they would be named `vmap_gdn_fwd_` (the trap
         # `attention_mixer` describes)
         o = gated_delta_slab(q, k, v, decay, beta, dk, dv)
-    return _matmul(gdn_finish(o, z, p["norm"], dv, eps), p["out_proj"])
+    return matmul(gdn_finish(o, z, p["norm"], dv, eps), p["out_proj"])
 
 
 def hybrid_layer(h, lp, kind: str, spec, attention: str, remat: bool):
@@ -237,12 +256,12 @@ def stage(x, layers, spec, attention: str, remat, tp_axis=None,
     return x, stats.sum(0)
 
 
-def head_loss(p, y, targets, mask, spec):
-    """Final RMSNorm and the untied head on the last stage's (mb, S, d):
-    the masked SUM of the next-token losses, `_HEAD_CHUNK` positions at a
-    time, each chunk's logits recomputed in the backward pass: float32
-    logits and their gradient exist for one chunk, not for the
-    microbatch."""
+def chunked_loss(y, targets, mask, log_probs_of):
+    """The masked SUM of the next-token losses of (mb, S, d) activations,
+    `_HEAD_CHUNK` positions at a time, each chunk's log-probabilities
+    (`log_probs_of(y_c)`: (mb, C, d) -> float32 (mb, C, V)) recomputed in
+    the backward pass: float32 logits and their gradient exist for one
+    chunk, not for the microbatch."""
     seq = y.shape[1]
     n_chunks = -(-seq // _HEAD_CHUNK)
     pad = n_chunks * _HEAD_CHUNK - seq
@@ -255,10 +274,7 @@ def head_loss(p, y, targets, mask, spec):
     @jax.checkpoint
     def one_chunk(acc, xs):
         y_c, tgt_c, mask_c = xs
-        z = rms_norm(y_c, p["final_norm"], spec.norm_eps)
-        logits = jnp.einsum("msd,vd->msv", z, p["head"],
-                            preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
+        logp = log_probs_of(y_c)
         nll = -jnp.take_along_axis(logp, tgt_c[..., None], axis=-1)[..., 0]
         return acc + (nll * mask_c).sum(), None
 
@@ -266,6 +282,17 @@ def head_loss(p, y, targets, mask, spec):
     total, _ = jax.lax.scan(one_chunk, jnp.float32(0.0),
                             (chunked(y), chunked(targets), chunked(mask)))
     return total
+
+
+def head_loss(p, y, targets, mask, spec):
+    """Final RMSNorm and the untied head on the last stage's (mb, S, d),
+    through `chunked_loss`."""
+    def log_probs_of(y_c):
+        z = rms_norm(y_c, p["final_norm"], spec.norm_eps)
+        logits = jnp.einsum("msd,vd->msv", z, p["head"],
+                            preferred_element_type=jnp.float32)
+        return jax.nn.log_softmax(logits, axis=-1)
+    return chunked_loss(y, targets, mask, log_probs_of)
 
 
 def summary(stats) -> list:

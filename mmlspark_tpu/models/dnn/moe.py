@@ -34,15 +34,35 @@ TILE = 256
 _SCATTER = {"mode": "drop"}
 
 
-def route(x, w_router, top_k: int, renormalize: bool = True):
-    """x (N, d) -> (indices (N, k) int32, weights (N, k) float32): softmax
-    over all experts in float32, the k largest, renormalised to sum 1."""
+# added to the chosen sigmoid scores' sum before it divides them
+_SIGMOID_NORM_EPS = 1e-6
+
+
+def route(x, w_router, top_k: int, renormalize: bool = True,
+          scoring: str = "softmax", bias=None, scale: float = 1.0):
+    """x (N, d) -> (indices (N, k) int32, weights (N, k) float32), scored
+    over all experts in float32 by the description's rule. "softmax": the
+    k largest probabilities, renormalised to sum 1. "sigmoid": sigmoid
+    scores; the k largest of score + `bias` (E,) are CHOSEN (the selection
+    bias of an auxiliary-loss-free balancer: it moves no weight and gets no
+    gradient), the weights are the chosen experts' UNBIASED scores,
+    renormalised over the chosen. `scale` multiplies the weights."""
     with jax.named_scope(tnames.LM_MOE_ROUTER):
         logits = jnp.einsum("nd,de->ne", x, w_router,
                             preferred_element_type=jnp.float32)
-        top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-        if renormalize:
-            top = top / top.sum(-1, keepdims=True)
+        if scoring == "softmax":
+            top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            if renormalize:
+                top = top / top.sum(-1, keepdims=True)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(
+                scores if bias is None else scores + bias, top_k)
+            top = jnp.take_along_axis(scores, idx, axis=-1)
+            if renormalize:
+                top = top / (top.sum(-1, keepdims=True) + _SIGMOID_NORM_EPS)
+        if scale != 1.0:
+            top = top * scale
         return idx.astype(jnp.int32), top
 
 
@@ -176,25 +196,32 @@ def gated_mlp(x, w_gate, w_up, w_down):
 
 
 def moe_layer(x, p, top_k: int, experts_held: tuple,
-              renormalize: bool = True):
+              renormalize: bool = True, scoring: str = "softmax",
+              scale: float = 1.0):
     """x (N, d) -> (y (N, d), stats (3,) float32). p: `router` (d, E_all),
-    `w_gate`, `w_up` (E, d, f), `w_down` (E, f, d) of the experts held,
-    `shared_gate`, `shared_up` (d, fs), `shared_down` (fs, d),
-    `shared_expert_gate` (d, 1). stats = (pairs routed, pairs held, the
-    fullest held expert's pairs over the mean of the held experts')."""
+    `w_gate`, `w_up` (E, d, f), `w_down` (E, f, d) of the experts held;
+    where the description has a shared expert, `shared_gate`, `shared_up`
+    (d, fs), `shared_down` (fs, d), `shared_expert_gate` (d, 1); where its
+    router chooses by a biased score, `expert_bias` (E_all,) (`route`).
+    stats = (pairs routed, pairs held, the fullest held expert's pairs over
+    the mean of the held experts')."""
     lo, hi = experts_held
-    idx, top_p = route(x, p["router"], top_k, renormalize)
+    idx, top_p = route(x, p["router"], top_k, renormalize, scoring,
+                       p.get("expert_bias"), scale)
     plan = dispatch_plan(idx, lo, hi)
     with jax.named_scope(tnames.LM_MOE_EXPERTS):
         routed = _experts(x, top_p.astype(jnp.float32), p["w_gate"],
                           p["w_up"], p["w_down"], plan)
-    with jax.named_scope(tnames.LM_MOE_SHARED):
-        shared = gated_mlp(x, p["shared_gate"], p["shared_up"],
-                           p["shared_down"])
-        gate = jax.nn.sigmoid(jnp.dot(x, p["shared_expert_gate"],
-                                      preferred_element_type=jnp.float32))
-        shared = (shared * gate).astype(x.dtype)
+    shared = None
+    if "shared_gate" in p:
+        with jax.named_scope(tnames.LM_MOE_SHARED):
+            shared = gated_mlp(x, p["shared_gate"], p["shared_up"],
+                               p["shared_down"])
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, p["shared_expert_gate"],
+                preferred_element_type=jnp.float32))
+            shared = (shared * gate).astype(x.dtype)
     counts = jax.lax.stop_gradient(plan["counts"]).astype(jnp.float32)
     stats = jnp.stack([jnp.float32(idx.size), counts.sum(),
                        counts.max() / jnp.maximum(counts.mean(), 1.0)])
-    return routed + shared, stats
+    return (routed if shared is None else routed + shared), stats

@@ -506,6 +506,8 @@ LM_MOE_ROUTER = "lm.moe.router"
 LM_MOE_DISPATCH = "lm.moe.dispatch"
 LM_MOE_EXPERTS = "lm.moe.experts"
 LM_MOE_SHARED = "lm.moe.shared"
+LM_CONV = "lm.conv"
+LM_CONV_GATE = "lm.conv.gate"
 GBDT_HIST = "gbdt.hist"
 GBDT_SPLIT = "gbdt.split"
 GBDT_ROUTE = "gbdt.route"
@@ -534,13 +536,19 @@ DEVICE_REGIONS = {
                  "again for remat) and gdn_bwd with the running sum of the "
                  "log decay and their layout glue, else its XLA form; "
                  "forward and backward",
-    LM_MOE_ROUTER: "expert layer: post norm, router logits, softmax, top-k",
+    LM_MOE_ROUTER: "expert layer: post norm, router logits, softmax or "
+                   "sigmoid scores (+ selection bias), top-k",
     LM_MOE_DISPATCH: "expert layer: sort of the pairs by held expert, the "
                      "tile loop's gathers and scatter-adds",
     LM_MOE_EXPERTS: "expert layer: the held experts' gated MLPs over the "
                     "tiles of the pairs routed to them",
     LM_MOE_SHARED: "expert layer: the shared expert, its sigmoid gate, the "
                    "sum with the routed part, residual",
+    LM_CONV: "gated short-convolution mixer outside its gate pass: input "
+             "norm, in projection (B, C, u), out projection, residual",
+    LM_CONV_GATE: "the short convolution's gate pass on (B, S, d) slabs: "
+                  "B * u, the causal depthwise taps, C *, float32 inside; "
+                  "forward and backward",
     GBDT_HIST: "node x feature x bin histogram build (and its psum)",
     GBDT_SPLIT: "best-split search of one level",
     GBDT_ROUTE: "advance rows to their child nodes",
