@@ -209,7 +209,33 @@ def gdn_mixer(x, p, g, eps: float):
     return matmul(gdn_finish(o, z, p["norm"], dv, eps), p["out_proj"])
 
 
-def hybrid_layer(h, lp, kind: str, spec, attention: str, remat: bool):
+def checkpoint_sublayers(mix, feed, remat, flash: bool, routing: bool):
+    """A layer's two sublayers (h, lp) -> ..., checkpointed as the
+    trainer's `remat` says. True / "full": the backward pass recomputes
+    each from its input, but for the residuals named in
+    `tnames.REMAT_RESIDUALS` (the flash forward's output and row sums; an
+    expert layer's scores, chosen ids and tile plan), which are kept:
+    megabytes that cost milliseconds to make again. "save_attn": the mixer
+    keeps what reverse-mode keeps, the feed-forward as under True. False:
+    neither is checkpointed. `flash` / `routing`: `mix` makes a flash call, `feed` is
+    an expert layer; they count, at trace time, the checkpoints whose
+    policy finds something to keep (docs/dnn.md "What remat keeps")."""
+    if not remat:
+        return mix, feed
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *tnames.REMAT_RESIDUALS)
+
+    def checkpointed(fn, counter):
+        if counter:
+            reliability_metrics.inc(counter)
+        return jax.checkpoint(fn, policy=keep)
+
+    if remat != "save_attn":
+        mix = checkpointed(mix, flash and tnames.LM_REMAT_KEEP_FLASH)
+    return mix, checkpointed(feed, routing and tnames.LM_REMAT_KEEP_ROUTING)
+
+
+def hybrid_layer(h, lp, kind: str, spec, attention: str, remat):
     """One layer on h (B, S, d): h + mixer(norm_in(h)), then
     h + experts(norm_post(h)). Returns (h, the expert layer's stats)."""
     eps = spec.norm_eps
@@ -233,22 +259,22 @@ def hybrid_layer(h, lp, kind: str, spec, attention: str, remat: bool):
         with jax.named_scope(tnames.LM_MOE_SHARED):
             return h + out.reshape(h.shape), stats
 
-    if remat:
-        mix, experts = jax.checkpoint(mix), jax.checkpoint(experts)
+    mix, experts = checkpoint_sublayers(
+        mix, experts, remat,
+        flash=kind == "attention" and attention == "flash", routing=True)
     return experts(mix(h, lp), lp)
 
 
 def stage(x, layers, spec, attention: str, remat, tp_axis=None,
           cp_axis=None):
     """(mb, S, d) through this stage's periods, each the description's
-    sequence of layer kinds -> (x, `STATS`). Every sublayer is recomputed
-    in the backward pass when `remat` is set, whichever value it has
-    (ROADMAP D13)."""
+    sequence of layer kinds -> (x, `STATS`). `remat`: what the backward
+    pass recomputes of each sublayer (`checkpoint_sublayers`)."""
     def one_period(h_x, lps):
         stats = jnp.zeros(STATS.shape, STATS.dtype)
         for kind, lp in zip(spec.period, lps):
             h_x, (routed, held, load) = hybrid_layer(
-                h_x, lp, kind, spec, attention, bool(remat))
+                h_x, lp, kind, spec, attention, remat)
             stats = stats + jnp.stack(
                 [routed, held, load, jnp.float32(1.0)])
         return h_x, stats

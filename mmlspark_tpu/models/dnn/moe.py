@@ -22,8 +22,11 @@ recomputing a tile's hidden activations and accumulating the gradients.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ...telemetry import names as tnames
 
@@ -38,6 +41,56 @@ _SCATTER = {"mode": "drop"}
 _SIGMOID_NORM_EPS = 1e-6
 
 
+def _chosen(idx, n_experts: int):
+    """idx (N, k) -> bool (N, k, E): where expert e is a row's j-th choice.
+    A row chooses an expert once, so a sum over either axis of values
+    masked by it moves ONE value exactly; the v5e runs it as one fused pass
+    where a gather or a scatter of N k scalars costs a millisecond."""
+    return idx[..., None] == jnp.arange(n_experts, dtype=idx.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _choose(logits, bias, top_k: int, scoring: str):
+    """Router logits (N, E) float32 -> (the chosen experts' ids (N, k)
+    int32, their scores (N, k)) by `route`'s rule. Its own backward, so
+    that what the backward reads is NAMED (`tnames.REMAT_RESIDUALS`): the
+    scores and the ids. Reverse-mode through `lax.top_k` and the softmax
+    reads their own untagged outputs, and a checkpoint that keeps names
+    would run both again to have them."""
+    return _choose_fwd(logits, bias, top_k, scoring)[0]
+
+
+def _choose_fwd(logits, bias, top_k, scoring):
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        top, idx = jax.lax.top_k(scores, top_k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            scores if bias is None else scores + bias, top_k)
+        top = jnp.where(_chosen(idx, scores.shape[-1]), scores[:, None, :],
+                        0.0).sum(-1)
+    scores, idx, top = checkpoint_name(
+        (scores, idx.astype(jnp.int32), top), tnames.KEEP_ROUTING)
+    return (idx, top), (scores, idx)
+
+
+def _choose_bwd(top_k, scoring, res, cts):
+    scores, idx = res
+    # the chosen scores' cotangent back at their places, zero elsewhere
+    d_scores = jnp.where(_chosen(idx, scores.shape[-1]), cts[1][..., None],
+                         0.0).sum(-2)
+    if scoring == "softmax":
+        weighted = scores * d_scores
+        d_logits = weighted - scores * weighted.sum(-1, keepdims=True)
+    else:
+        d_logits = d_scores * scores * (1.0 - scores)
+    return d_logits, None       # the selection bias moves no weight
+
+
+_choose.defvjp(_choose_fwd, _choose_bwd)
+
+
 def route(x, w_router, top_k: int, renormalize: bool = True,
           scoring: str = "softmax", bias=None, scale: float = 1.0):
     """x (N, d) -> (indices (N, k) int32, weights (N, k) float32), scored
@@ -50,20 +103,14 @@ def route(x, w_router, top_k: int, renormalize: bool = True,
     with jax.named_scope(tnames.LM_MOE_ROUTER):
         logits = jnp.einsum("nd,de->ne", x, w_router,
                             preferred_element_type=jnp.float32)
-        if scoring == "softmax":
-            top, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-            if renormalize:
-                top = top / top.sum(-1, keepdims=True)
-        else:
-            scores = jax.nn.sigmoid(logits)
-            _, idx = jax.lax.top_k(
-                scores if bias is None else scores + bias, top_k)
-            top = jnp.take_along_axis(scores, idx, axis=-1)
-            if renormalize:
-                top = top / (top.sum(-1, keepdims=True) + _SIGMOID_NORM_EPS)
+        idx, top = _choose(logits, bias, top_k, scoring)
+        if renormalize:
+            norm = top.sum(-1, keepdims=True)
+            top = top / (norm if scoring == "softmax"
+                         else norm + _SIGMOID_NORM_EPS)
         if scale != 1.0:
             top = top * scale
-        return idx.astype(jnp.int32), top
+        return idx, top
 
 
 def dispatch_plan(idx, lo: int, hi: int):
@@ -78,12 +125,15 @@ def dispatch_plan(idx, lo: int, hi: int):
         held = (flat >= lo) & (flat < hi)
         key = jnp.where(held, flat - lo, n_held)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        counts = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+        counts = (key[:, None] == jnp.arange(n_held, dtype=key.dtype)).sum(
+            0, dtype=jnp.int32)
         starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                   jnp.cumsum(counts)]).astype(jnp.int32)
         tile_ends = jnp.cumsum((counts + TILE - 1) // TILE).astype(jnp.int32)
-        return {"order": order, "starts": starts, "tile_ends": tile_ends,
-                "counts": counts}
+        # named: a checkpointed expert layer keeps the plan and sorts once
+        return checkpoint_name(
+            {"order": order, "starts": starts, "tile_ends": tile_ends,
+             "counts": counts}, tnames.KEEP_ROUTING)
 
 
 def _tile(t, plan, top_k: int, n_tokens: int):

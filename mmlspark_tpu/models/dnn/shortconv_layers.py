@@ -41,9 +41,9 @@ import numpy as np
 
 from ...parallel import DATA_AXIS, PIPE_AXIS
 from ...telemetry import names as tnames
-from .hybrid_layers import (STATS, check_experts, chunked_loss,
-                            grouped_attention, matmul, report, rotary,
-                            summary)
+from .hybrid_layers import (STATS, check_experts, checkpoint_sublayers,
+                            chunked_loss, grouped_attention, matmul, report,
+                            rotary, summary)
 from .moe import gated_mlp, moe_layer
 
 __all__ = ["AXES", "STATS", "bound", "cast", "check", "embed", "head_loss",
@@ -169,7 +169,7 @@ def attention_mixer(x, p, a, eps: float, attention: str):
     return matmul(out.reshape(b, s, h * d), p["o_proj"])
 
 
-def layer(h, lp, kind: str, ffn: str, spec, attention: str, remat: bool):
+def layer(h, lp, kind: str, ffn: str, spec, attention: str, remat):
     """One layer on h (B, S, d) -> (h, the expert layer's stats or
     None)."""
     eps = spec.norm_eps
@@ -201,8 +201,10 @@ def layer(h, lp, kind: str, ffn: str, spec, attention: str, remat: bool):
             return h + out.reshape(h.shape), stats
 
     feed = dense if ffn == "dense" else experts
-    if remat:
-        mix, feed = jax.checkpoint(mix), jax.checkpoint(feed)
+    mix, feed = checkpoint_sublayers(
+        mix, feed, remat,
+        flash=kind == "full_attention" and attention == "flash",
+        routing=ffn == "experts")
     return feed(mix(h, lp), lp)
 
 
@@ -217,14 +219,14 @@ def embed(p, tokens, seq_off, spec):
 
 def stage(x, layers, spec, attention: str, remat, tp_axis=None,
           cp_axis=None):
-    """(mb, S, d) through this stage's periods -> (x, `STATS`). Every
-    sublayer is recomputed in the backward pass when `remat` is set,
-    whichever value it has (ROADMAP D13)."""
+    """(mb, S, d) through this stage's periods -> (x, `STATS`). `remat`:
+    what the backward pass recomputes of each sublayer
+    (`hybrid_layers.checkpoint_sublayers`)."""
     def one_period(h_x, lps):
         stats = jnp.zeros(STATS.shape, STATS.dtype)
         for kind, ffn, lp in zip(spec.period, spec.period_ffn, lps):
             h_x, counted = layer(h_x, lp, kind, ffn, spec, attention,
-                                 bool(remat))
+                                 remat)
             if counted is not None:
                 stats = stats + jnp.concatenate([counted,
                                                  jnp.ones((1,), stats.dtype)])

@@ -68,6 +68,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -830,6 +831,10 @@ def _flash_fwd_vjp(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
                    bwd_block_k, interpret):
     out, lse = _flash_forward_lse(q, k, v, causal, scale, block_q, block_k,
                                   interpret)
+    # named HERE, inside the fwd rule: a checkpoint policy sees these
+    # equations, and a name on the call's output would keep the output and
+    # still run the kernel again for the residuals
+    out, lse = checkpoint_name((out, lse), tnames.KEEP_FLASH)
     return out, (q, k, v, out, lse)   # lse: (H, S, 1)
 
 

@@ -87,6 +87,8 @@ GDN_SCAN_ROUTE_PALLAS = "gdn.scan.route.pallas"
 GDN_SCAN_ROUTE_XLA = "gdn.scan.route.xla"
 GDN_MIXER_ROUTE_PALLAS = "gdn.mixer.route.pallas"
 GDN_MIXER_ROUTE_XLA = "gdn.mixer.route.xla"
+LM_REMAT_KEEP_FLASH = "lm.remat.keep.flash"
+LM_REMAT_KEEP_ROUTING = "lm.remat.keep.routing"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -245,6 +247,23 @@ COUNTERS = {
     GDN_MIXER_ROUTE_XLA: "calls of the same two passes traced down their "
                          "plain jnp form on the slab: off the TPU, or head "
                          "sizes that are no multiple of 128 (never silent)",
+    LM_REMAT_KEEP_FLASH: "checkpointed mixer sublayers of the hybrid and "
+                         "short-convolution families whose policy keeps a "
+                         "flash call's output and row sums (flash.forward "
+                         "of REMAT_RESIDUALS below), so the backward pass does not run "
+                         "flash_fwd again; counted at trace time, once a "
+                         "traced sublayer (a period's layer is traced once "
+                         "however many periods the scan runs). 0 under "
+                         "remat=False, remat=\"save_attn\" (the mixer is not "
+                         "checkpointed), attention=\"dense\" and in the "
+                         "dense family",
+    LM_REMAT_KEEP_ROUTING: "checkpointed expert sublayers of the same two "
+                           "families whose policy keeps the routing "
+                           "(moe.routing of REMAT_RESIDUALS below: scores, "
+                           "chosen ids and scores, the tile plan), so "
+                           "the backward pass runs no top-k and no sort "
+                           "again; counted like lm.remat.keep.flash. 0 "
+                           "under remat=False and in the dense family",
     QUALITY_LABELS_JOINED: "delayed labels joined to their served "
                            "prediction (streaming evaluation pairs)",
     QUALITY_LABELS_LATE: "out-of-order labels that arrived BEFORE their "
@@ -555,6 +574,32 @@ DEVICE_REGIONS = {
     GBDT_OBJECTIVE: "gradient/hessian, row weights and the margin update "
                     "of one boosting iteration",
     GBDT_BIN: "device bin assignment (apply_bins_device)",
+}
+
+# ------------------------------------------------------ remat residuals
+# What `jax.checkpoint` keeps of a sublayer it otherwise recomputes: arrays
+# tagged with `jax.ad_checkpoint.checkpoint_name` where they are made, kept
+# by `save_only_these_names(*REMAT_RESIDUALS)` (models/dnn/hybrid_layers.py
+# `checkpoint_sublayers`). A tag lowers to nothing, and a program that
+# checkpoints none of them is unchanged by it. A tag keeps an array for the
+# equations AFTER it: what a `custom_vjp` hands its backward is tagged
+# inside its fwd rule, not at its call.
+KEEP_FLASH = "flash.forward"
+KEEP_ROUTING = "moe.routing"
+
+REMAT_RESIDUALS = {
+    KEEP_FLASH: "the flash forward's output (heads, S, D), in the "
+                "activations' dtype, and its row log-sum-exp (heads, S, 1), "
+                "float32 (ops/flash_attention.py _flash_fwd_vjp): all the "
+                "backward kernels need of a second flash_fwd",
+    KEEP_ROUTING: "an expert layer's routing (models/dnn/moe.py): the "
+                  "scores over all experts (N, E) float32, which their "
+                  "backward reads in place of the logits, the chosen "
+                  "experts' ids (N, k) int32 and their scores before they "
+                  "are renormalised and scaled (_choose: no second top-k); "
+                  "the tile plan's pair ids sorted by held expert (N k,) "
+                  "with its starts, tile ends and counts (dispatch_plan: no "
+                  "second sort)",
 }
 
 LM_STEP_H2D = "lm.step.h2d"

@@ -313,6 +313,38 @@ def test_softmax_route_is_the_parents_bit_for_bit(renormalize, dtype):
     assert np.array_equal(got[1], want[1])
 
 
+def _plain_route(x, w_router, top_k, scoring, bias):
+    """`moe.route`'s weights by `lax.top_k` and `take_along_axis`, for
+    reverse-mode as JAX writes it."""
+    logits = jnp.einsum("nd,de->ne", x, w_router,
+                        preferred_element_type=jnp.float32)
+    if scoring == "softmax":
+        top, _ = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        return top / top.sum(-1, keepdims=True)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + bias, top_k)
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    return top / (top.sum(-1, keepdims=True) + 1e-6)
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid_bias"])
+def test_the_routers_own_backward_is_reverse_modes(scoring):
+    """`moe._choose` writes its backward by hand so that it reads named
+    residuals; the gradients are those of the plain expression."""
+    rng = np.random.default_rng(9)
+    x = jnp.asarray(rng.standard_normal((300, 64)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((64, 16)) * 0.3, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal(16) * 0.05, jnp.float32)
+    g = jnp.asarray(rng.standard_normal((300, 4)), jnp.float32)
+    got = jax.grad(lambda x, w, b: (moe.route(
+        x, w, 4, True, scoring, b if scoring != "softmax" else None)[1]
+        * g).sum(), argnums=(0, 1, 2))(x, w, bias)
+    want = jax.grad(lambda x, w: (_plain_route(x, w, 4, scoring, bias)
+                                  * g).sum(), argnums=(0, 1))(x, w)
+    assert rel(got[0], want[0]) < 1e-5 and rel(got[1], want[1]) < 1e-5
+    assert not np.asarray(got[2]).any()      # the selection bias: none
+
+
 def test_the_tied_heads_gradient_is_the_sum_of_both_uses(ref):
     """`embed` is looked up and is the head: its gradient through the
     trainer is the reference's gradient of the lookup alone plus that of

@@ -2,16 +2,21 @@
 (`lm_spec.FAMILIES`: layer kind -> module): a family defined HERE trains
 through the unedited trainer, a period stays inside one family, and a mesh
 axis a family has no form for is refused by the trainer's one check."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from mmlspark_tpu.models.dnn import lm_spec
-from mmlspark_tpu.models.dnn.lm_spec import LMSpec, gpt2_spec, qwen3_next_spec
+from mmlspark_tpu.models.dnn.lm_spec import (LMSpec, gpt2_spec,
+                                             lfm2_moe_spec, qwen3_next_spec)
 from mmlspark_tpu.models.dnn.pp_training import PipelinedLMTrainer
 from mmlspark_tpu.parallel import (DATA_AXIS, MODEL_AXIS, PIPE_AXIS,
                                    SEQ_AXIS, grid_mesh)
+from mmlspark_tpu.reliability.metrics import reliability_metrics
+from mmlspark_tpu.telemetry import names as tnames
 
 
 class fake_layers:
@@ -121,3 +126,173 @@ def test_a_family_refuses_a_mesh_axis_it_has_no_form_for(family, axis,
     with pytest.raises(ValueError, match=match) as e:
         PipelinedLMTrainer(model=SPECS[family](), mesh=mesh)
     assert family in str(e.value) and axis in str(e.value)
+
+
+# ------------------------------------------------------- what `remat` keeps
+# (`hybrid_layers.checkpoint_sublayers`, the one rule of both share families)
+TINY_LFM2 = dict(
+    hidden_size=16, intermediate_size=24, conv_L_cache=3, norm_eps=1e-5,
+    num_attention_heads=2, num_key_value_heads=1,
+    layer_types=["conv", "full_attention", "conv"], num_dense_layers=1,
+    rope_parameters={"rope_theta": 1e4}, num_experts=4,
+    num_experts_per_tok=2, moe_intermediate_size=8, norm_topk_prob=True,
+    use_expert_bias=True, routed_scaling_factor=1, vocab_size=16)
+SPECS["shortconv_layers"] = lambda: lfm2_moe_spec(TINY_LFM2, (0, 4))
+SHARE_FAMILIES = ("hybrid_layers", "shortconv_layers")
+KEEPS = (tnames.LM_REMAT_KEEP_FLASH, tnames.LM_REMAT_KEEP_ROUTING)
+# arrays a sublayer keeps under each name: the flash forward's out and lse;
+# the router's scores, ids and chosen scores and the plan's four vectors
+KEPT_ARRAYS = {tnames.KEEP_FLASH: 2, tnames.KEEP_ROUTING: 7}
+
+
+def family_trainer(family, **kw):
+    kw = {"n_microbatches": 1, "seed": 5, "attention": "flash", **kw}
+    return PipelinedLMTrainer(
+        model=SPECS[family](),
+        mesh=grid_mesh((1, 1), (DATA_AXIS, PIPE_AXIS)), **kw)
+
+
+def toy_tokens(seq=24):
+    return np.random.default_rng(2).integers(0, 16, (2, seq)).astype(np.int32)
+
+
+def counted(fn, names=KEEPS):
+    """(fn's result, what it added to each counter)."""
+    before = [reliability_metrics.get(n) for n in names]
+    out = fn()
+    return out, tuple(reliability_metrics.get(n) - b
+                      for n, b in zip(names, before))
+
+
+@pytest.fixture(scope="module", params=SHARE_FAMILIES)
+def without_remat(request):
+    """(family, loss, gradients) of a toy model with nothing checkpointed,
+    and no sublayer counted as keeping anything."""
+    trainer = family_trainer(request.param, remat=False)
+    (loss, grads), kept = counted(lambda: trainer.loss_and_grads(
+        toy_tokens()))
+    assert kept == (0, 0)
+    return request.param, loss, grads
+
+
+@pytest.mark.parametrize("remat,kept", [
+    (True, (1, 2)), ("full", (1, 2)), ("save_attn", (0, 2))])
+def test_a_remat_value_changes_what_is_kept_and_no_number(without_remat,
+                                                          remat, kept):
+    """The loss and every leaf's gradient of `remat=False`, whichever value
+    it has; and the trace counts one flash-keeping checkpoint an attention
+    layer of the period and one routing-keeping checkpoint an expert layer
+    (both toy periods: two layers, one of them attention), none for a mixer
+    that "save_attn" leaves unchecked."""
+    family, loss, grads = without_remat
+    trainer = family_trainer(family, remat=remat)
+    (got_loss, got), counts = counted(
+        lambda: trainer.loss_and_grads(toy_tokens()))
+    assert counts == kept
+    assert abs(got_loss - loss) < 1e-6
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(grads)):
+        apart = float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+        assert apart < 1e-5, (jax.tree_util.keystr(path), apart)
+
+
+def test_the_dense_family_counts_no_kept_residual():
+    trainer = PipelinedLMTrainer(
+        model=SPECS["dense_layers"](), attention="flash", remat="save_attn",
+        n_microbatches=1, mesh=grid_mesh((1, 1), (DATA_AXIS, PIPE_AXIS)))
+    tok = np.random.default_rng(2).integers(0, 16, (2, 8)).astype(np.int32)
+    _, kept = counted(lambda: trainer.loss_and_grads(tok))
+    assert kept == (0, 0)
+
+
+def one_layer(family, kind):
+    """(a function (h, lp, remat) -> h of one toy layer of `kind` with an
+    expert layer behind it, h, lp)."""
+    spec = SPECS[family]()
+    module = lm_spec.FAMILIES[kind]
+    i = spec.period.index(kind)
+    lp = jax.tree_util.tree_map(lambda a: jnp.asarray(a[0]),
+                                module.init(spec, 0)["layers"][i])
+    h = jnp.asarray(np.random.default_rng(0).standard_normal((2, 16, 16)),
+                    jnp.float32)
+    if family == "hybrid_layers":
+        def layer(h, lp, remat):
+            return module.hybrid_layer(h, lp, kind, spec, "flash", remat)[0]
+    else:
+        def layer(h, lp, remat):
+            return module.layer(h, lp, kind, "experts", spec, "flash",
+                                remat)[0]
+    return layer, h, lp
+
+
+def stored(layer, h, lp, remat):
+    """What reverse-mode stores of a layer beyond its arguments and
+    constants: [(shape, where it comes from)]."""
+    from jax._src.ad_checkpoint import saved_residuals
+    return [(aval.shape, why) for aval, why in saved_residuals(
+        lambda h, lp: layer(h, lp, remat), h, lp)
+        if not why.startswith(("from the argument", "from a constant",
+                               "from a literal"))]
+
+
+def names_of(residuals):
+    return {why.split("'")[1] for _, why in residuals
+            if why.startswith("named")}
+
+
+@pytest.mark.parametrize("family,kind,names", [
+    ("hybrid_layers", "attention", {tnames.KEEP_FLASH, tnames.KEEP_ROUTING}),
+    ("hybrid_layers", "gdn", {tnames.KEEP_ROUTING}),
+    ("shortconv_layers", "full_attention", {tnames.KEEP_FLASH,
+                                            tnames.KEEP_ROUTING}),
+    ("shortconv_layers", "conv", {tnames.KEEP_ROUTING})])
+def test_full_remat_stores_the_named_residuals_and_nothing_else(family, kind,
+                                                                names):
+    """Under True a layer stores its two sublayers' inputs (its own, an
+    argument, and the mixer's result) and the residuals of
+    `REMAT_RESIDUALS` that its sublayers make: no projection, no state.
+    (`saved_residuals` shows a named array that a `custom_vjp` also returns
+    as the rounding the name leaves behind.)"""
+    layer, h, lp = one_layer(family, kind)
+    residuals = stored(layer, h, lp, True)
+    unnamed = [why for _, why in residuals
+               if not why.startswith(("named", "output of reduce_precision"))]
+    assert len(unnamed) == 1 and unnamed[0].startswith("output of add"), \
+        unnamed
+    assert names_of(residuals) == names
+    assert len(residuals) == 1 + sum(KEPT_ARRAYS[n] for n in names)
+    assert stored(layer, h, lp, "full") == residuals
+
+
+@pytest.mark.parametrize("family,kind", [
+    ("hybrid_layers", "attention"), ("shortconv_layers", "full_attention")])
+def test_save_attn_stores_the_mixer_and_recomputes_the_feed_forward(family,
+                                                                    kind):
+    """`remat="save_attn"` (ROADMAP D13): the mixer sublayer keeps what
+    reverse-mode keeps (projections, norms, the kernels' q, k, v); the
+    expert sublayer is as under True."""
+    layer, h, lp = one_layer(family, kind)
+    residuals = stored(layer, h, lp, "save_attn")
+    assert any("(attention_mixer)" in why for _, why in residuals), residuals
+    assert len(residuals) > len(stored(layer, h, lp, True)) + 5
+    feed = [why for _, why in residuals if "/moe.py" in why]
+    assert len(feed) == KEPT_ARRAYS[tnames.KEEP_ROUTING] and all(
+        why.startswith(("named", "output of reduce_prec")) for why in feed)
+    # nothing checkpointed: the expert sublayer stores its own too
+    assert len(stored(layer, h, lp, False)) > len(residuals)
+
+
+@pytest.mark.parametrize("family", SHARE_FAMILIES)
+@pytest.mark.parametrize("remat", [False, True, "save_attn"])
+def test_the_compiled_step_sorts_and_chooses_once_an_expert_layer(family,
+                                                                  remat):
+    """Two expert layers a toy period: two sorts of the pairs and two
+    top-ks in the whole compiled step, forward and backward, whatever is
+    recomputed (a checkpoint that kept no routing would run four of
+    each)."""
+    trainer = family_trainer(family, remat=remat)
+    text = trainer._step.lower(
+        trainer.params, trainer.opt_state,
+        trainer._to_device(toy_tokens())).compile().as_text()
+    assert len(re.findall(r"\bsort\(", text)) == 2
+    assert len(re.findall(r'custom_call_target="TopK"', text)) == 2
