@@ -10,7 +10,12 @@ model may have LEADING layers (`leading`, for example the dense layers a
 sparse decoder starts with): they are not stacked, and the first pipe stage
 runs them with the embedding. A layer kind names the layer's mixer; where a
 family's layers differ in their feed-forward too, `period_ffn` and
-`leading_ffn` give each layer's ("dense" | "experts").
+`leading_ffn` give each layer's ("dense" | "experts"). A model whose
+layers change kind along its depth, and whose later layers read arrays an
+earlier layer made, is a sequence of RUNS (`runs`: each a period of its
+own, repeated, from a published layer index on); it is then ONE period of
+all its layers (`n_periods` 1), since what its layers share cannot
+cross a pipe-stage boundary.
 
 Layer kinds, each with the FAMILY (a module) that holds its layers and
 their embedding, head, layout and counters (docs/dnn.md "Model families"):
@@ -24,6 +29,14 @@ their embedding, head, layout and counters (docs/dnn.md "Model families"):
                norms and rotary embedding over the whole head; both with a
                dense SwiGLU or sigmoid-routed experts, a chunked head tied
                to the embedding                    (`shortconv_layers`)
+  "mamba" | "memory_mamba"  LayerNorm, a Mamba selective-scan mixer (the
+               second also hands its scan output on as the MEMORY)
+  "window_attention" | "kv_attention"  differential attention under a
+               sliding window, or full causal with its k, v kept
+  "gmu"        a Gated Memory Unit on the memory
+  "cross_attention"  differential attention over the KV layer's k, v; all
+               six with a dense SwiGLU, no positions, a chunked head tied
+               to the embedding                          (`ssm_layers`)
 
 What a description cannot say yet is in ROADMAP.md (queue R and D2).
 """
@@ -32,12 +45,13 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-from . import dense_layers, hybrid_layers, shortconv_layers
+from . import dense_layers, hybrid_layers, shortconv_layers, ssm_layers
 
 # layer kind -> the family that holds it; a model's kinds belong to one
 FAMILIES = {"dense": dense_layers, "gdn": hybrid_layers,
             "attention": hybrid_layers, "conv": shortconv_layers,
-            "full_attention": shortconv_layers}
+            "full_attention": shortconv_layers,
+            **{kind: ssm_layers for kind in ssm_layers.KINDS}}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +78,36 @@ class GatedDeltaNet:
 class ShortConv:
     """The gated short convolution's taps a channel (`conv_L_cache`)."""
     width: int = 3
+
+
+@dataclasses.dataclass(frozen=True)
+class Mamba:
+    """A Mamba mixer's sizes: channels, states a channel, the rank of the
+    step's projection, the convolution's taps."""
+    d_inner: int
+    d_state: int
+    dt_rank: int
+    conv_width: int = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class DiffAttention:
+    """Differential attention's sizes; `window`: how many positions back a
+    window layer's query sees, its own included."""
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    window: int
+
+
+@dataclasses.dataclass(frozen=True)
+class Run:
+    """`n` repetitions of a period of layer kinds; `first` is the
+    published index of the run's first layer (a layer keeps its published
+    index where a cut drops the layers before it)."""
+    period: tuple
+    n: int
+    first: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,6 +150,10 @@ class LMSpec:
     leading_ffn: tuple = ()
     period_ffn: tuple = ()
     short_conv: Optional[ShortConv] = None
+    # a model of several runs of periods (`period` lists every layer)
+    runs: tuple = ()
+    mamba: Optional[Mamba] = None
+    diff_attention: Optional[DiffAttention] = None
 
     def __post_init__(self):
         kinds = self.leading + self.period
@@ -233,3 +281,61 @@ def lfm2_moe_spec(cfg: dict, experts_held: tuple,
             renormalize=bool(cfg["norm_topk_prob"]),
             scoring="sigmoid_bias" if cfg["use_expert_bias"] else "sigmoid",
             scale=float(cfg["routed_scaling_factor"])))
+
+
+def phi4flash_kind(index: int, n_layers: int, mb_per_layer: int) -> str:
+    """The published rule for layer `index` of `n_layers`: the first half
+    alternates Mamba and window attention, the second half's first Mamba
+    is the memory layer and its first attention the KV layer, and after
+    them a Gated Memory Unit stands where a Mamba would and
+    cross-attention where attention would."""
+    half = n_layers // 2
+    ssm = index % mb_per_layer == 0
+    if index < half:
+        return "mamba" if ssm else "window_attention"
+    if index < half + mb_per_layer:
+        return "memory_mamba" if ssm else "kv_attention"
+    return "gmu" if ssm else "cross_attention"
+
+
+def phi4flash_spec(cfg: dict) -> LMSpec:
+    """A `phi4flash` config.json (Hugging Face keys) as a description: the
+    WHOLE published model from `num_hidden_layers` by the index rule, or
+    the layers `held_layers` = [first, last] (published indices, both
+    included) where the file gives that key of a cut's own; consecutive
+    layers fold into runs of one repeating period of `mb_per_layer`
+    layers. Mamba's sizes are the family's defaults unless the file
+    carries them, at its top level or under `assumed` (`mamba_expand` 2,
+    `mamba_d_state` 16, `mamba_dt_rank` hidden / 16, `mamba_d_conv` 4).
+    `vocab_size` is the vocabulary held here."""
+    def mamba_size(key, default):
+        return cfg.get(key, cfg.get("assumed", {}).get(key, default))
+
+    n_layers, per = cfg["num_hidden_layers"], cfg["mb_per_layer"]
+    first, last = cfg.get("held_layers", (0, n_layers - 1))
+    if not 0 <= first <= last < n_layers:
+        raise ValueError(f"held_layers {first}..{last} is no range of the "
+                         f"{n_layers} published layers")
+    kinds = [phi4flash_kind(i, n_layers, per) for i in range(first, last + 1)]
+    runs, at = [], 0
+    while at < len(kinds):
+        period = tuple(kinds[at:at + per])
+        n = 1
+        while tuple(kinds[at + n * per:at + (n + 1) * per]) == period:
+            n += 1
+        runs.append(Run(period=period, n=n, first=first + at))
+        at += n * len(period)
+    d = cfg["hidden_size"]
+    heads = cfg["num_attention_heads"]
+    return LMSpec(
+        vocab_size=cfg["vocab_size"], d_model=d,
+        period=tuple(kinds), n_periods=1, runs=tuple(runs),
+        d_ff=cfg["intermediate_size"], norm_eps=cfg["layer_norm_eps"],
+        init_std=cfg.get("initializer_range", 0.02),
+        mamba=Mamba(d_inner=mamba_size("mamba_expand", 2) * d,
+                    d_state=mamba_size("mamba_d_state", 16),
+                    dt_rank=mamba_size("mamba_dt_rank", -(-d // 16)),
+                    conv_width=mamba_size("mamba_d_conv", 4)),
+        diff_attention=DiffAttention(
+            n_heads=heads, n_kv_heads=cfg["num_key_value_heads"],
+            head_dim=d // heads, window=cfg["sliding_window"]))

@@ -60,7 +60,7 @@ def init_transformer(vocab_size: int, d_model: int = 256, n_heads: int = 8,
     return params
 
 
-def _layer_norm(x, p):
+def _layer_norm(x, p, eps: float = 1e-6):
     """Layer norm with f32 statistics regardless of activation dtype
     (bf16 mean/variance accumulation loses ~3 decimal digits at d>=1024);
     the result is cast back to the activation dtype. For f32 activations
@@ -69,7 +69,7 @@ def _layer_norm(x, p):
     xf = x.astype(jnp.float32)
     mu = xf.mean(-1, keepdims=True)
     var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-    out = (xf - mu) / jnp.sqrt(var + 1e-6) * p["scale"] + p["bias"]
+    out = (xf - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
     return out.astype(x.dtype)
 
 

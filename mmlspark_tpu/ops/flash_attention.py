@@ -44,6 +44,15 @@ overhead dominates below that (see _auto_blocks), interior cells run
 unmasked and untouched, and only the diagonal cells take the strips (16k
 causal forward + backward 19.2 -> 18.7 ms). Matmuls run in the input dtype.
 
+A SLIDING WINDOW AND A VALUE WIDTH (PR 35). `flash_attention(..., window=W)`
+bounds causal self-attention to the W positions up to the query's own; such
+a call runs kernels of its own (`flash_fwd_win`, `flash_dq_win`,
+`flash_dkv_win`, below the unwindowed ones in this file), whose grids hold
+only the cells a window reaches, so a call without a window traces and
+compiles to what it always did. V may be wider or narrower than q and k
+(differential attention reads two value heads side by side): every kernel
+takes V's, the output's and their cotangents' blocks at V's own width.
+
 PRECISION CONTRACT. Softmax statistics and every accumulation are f32.
 The matmul PRODUCTS follow the input dtype (`_mxu_dot`): bf16 q/k/v take
 the MXU's single bf16 pass; f32 q/k/v ask Mosaic for its fp32 contract
@@ -558,6 +567,7 @@ def _flash_forward_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     only extra residual the flash backward needs (FlashAttention's trick:
     P = exp(S - LSE) reconstructs the softmax block-by-block)."""
     d = q.shape[-1]
+    dv = v.shape[-1]      # the values' width may differ from q's and k's
     h = q.shape[0]
     q, k, v, s, sk, n_q, n_k = _pad_blocks(q, k, v, block_q, block_k)
     tile = _diag_tile(causal, block_q, block_k, s, sk)
@@ -576,19 +586,19 @@ def _flash_forward_lse(q, k, v, causal, scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda hh, qb, kb: (hh, qb, 0)),
             pl.BlockSpec((1, block_k, d), lambda hh, qb, kb: (hh, kb, 0)),
-            pl.BlockSpec((1, block_k, d), lambda hh, qb, kb: (hh, kb, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda hh, qb, kb: (hh, kb, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda hh, qb, kb: (hh, qb, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda hh, qb, kb: (hh, qb, 0)),
             pl.BlockSpec((1, block_q, 1), lambda hh, qb, kb: (hh, qb, 0)),
             pl.BlockSpec((1, block_q, 1), lambda hh, qb, kb: (hh, qb, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((h, q.shape[1], d), q.dtype),
+            jax.ShapeDtypeStruct((h, q.shape[1], dv), q.dtype),
             jax.ShapeDtypeStruct((h, q.shape[1], 1), jnp.float32),
             jax.ShapeDtypeStruct((h, q.shape[1], 1), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, dv), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         compiler_params=_compiler_params(),
@@ -756,6 +766,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
       recurrence, see _flash_stats_bwd for the derivation. q_offset/
       k_offset are the blocks' global positions (traced scalars OK)."""
     d = q.shape[-1]
+    dv = v.shape[-1]
     h = q.shape[0]
     s_q = q.shape[1]
     sk = k.shape[1]
@@ -781,13 +792,19 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     row_spec_q = pl.BlockSpec((1, block_q, d), lambda hh, qb, kb: (hh, qb, 0))
     col_spec_k = pl.BlockSpec((1, block_k, d), lambda hh, qb, kb: (hh, kb, 0))
     one_spec_q = pl.BlockSpec((1, block_q, 1), lambda hh, qb, kb: (hh, qb, 0))
+    # v and the output's cotangent at the values' own width (dv == d: the
+    # same specs as q's and k's)
+    row_spec_do = row_spec_q if dv == d else pl.BlockSpec(
+        (1, block_q, dv), lambda hh, qb, kb: (hh, qb, 0))
+    col_spec_v = col_spec_k if dv == d else pl.BlockSpec(
+        (1, block_k, dv), lambda hh, qb, kb: (hh, kb, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_k=n_k, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale,
                           k_end=sk, diag_tile=tile),
         grid=(h, n_q, n_k),
-        in_specs=[smem, smem, row_spec_q, col_spec_k, col_spec_k,
-                  row_spec_q, one_spec_q, one_spec_q],
+        in_specs=[smem, smem, row_spec_q, col_spec_k, col_spec_v,
+                  row_spec_do, one_spec_q, one_spec_q],
         out_specs=row_spec_q,
         out_shape=jax.ShapeDtypeStruct(q_p.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -800,19 +817,23 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     row_spec_kb = pl.BlockSpec((1, block_k, d), lambda hh, kb, qb: (hh, kb, 0))
     col_spec_qb = pl.BlockSpec((1, block_q, d), lambda hh, kb, qb: (hh, qb, 0))
     one_spec_qb = pl.BlockSpec((1, block_q, 1), lambda hh, kb, qb: (hh, qb, 0))
+    row_spec_vb = row_spec_kb if dv == d else pl.BlockSpec(
+        (1, block_k, dv), lambda hh, kb, qb: (hh, kb, 0))
+    col_spec_dob = col_spec_qb if dv == d else pl.BlockSpec(
+        (1, block_q, dv), lambda hh, kb, qb: (hh, qb, 0))
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, n_q=n_q, block_q=block_q, block_k=block_k,
         causal=causal, scale=scale, k_end=sk, diag_tile=tile)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(h, n_k, n_q),
-        in_specs=[smem, smem, row_spec_kb, row_spec_kb, col_spec_qb,
-                  col_spec_qb, one_spec_qb, one_spec_qb],
-        out_specs=[row_spec_kb, row_spec_kb],
+        in_specs=[smem, smem, row_spec_kb, row_spec_vb, col_spec_qb,
+                  col_spec_dob, one_spec_qb, one_spec_qb],
+        out_specs=[row_spec_kb, row_spec_vb],
         out_shape=[jax.ShapeDtypeStruct(k_p.shape, k.dtype),
                    jax.ShapeDtypeStruct(v_p.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
         name=KERNEL_DKV,
@@ -848,14 +869,326 @@ def _flash_bwd_vjp(causal, scale, block_q, block_k, bwd_block_q,
 _flash_shd.defvjp(_flash_fwd_vjp, _flash_bwd_vjp)
 
 
+# ------------------------------------------------- the sliding-window kernels
+# A causal self-attention call under a static window W (key s is visible to
+# query t iff t - W < s <= t) runs kernels of its own, so that a call
+# without a window compiles to what it always did. Their grids hold only the
+# cells a window can reach: a query block qb reads the `n_vis` key blocks
+# qb - n_vis + 1 .. qb (the dk/dv grid: key block kb is read by query blocks
+# kb .. kb + n_vis - 1), the index maps clamp at the sequence's ends and the
+# kernel skips what lies outside. Square blocks; the distance `delta` = qb -
+# kb of a grid step is one of n_vis static values, so every cell's geometry
+# is static: it is walked in row strips of `_WIN_STRIP`, each strip over the
+# 128-aligned columns its rows can see, masked only where an edge (the
+# diagonal above, the window below) crosses it. A row with no visible key in
+# a cell left of the diagonal carries garbage in its running sums until the
+# diagonal cell, the last of its row, wipes it (alpha = exp(-1e30 - m) = 0):
+# every row sees its own position. Padded keys lie above every real row's
+# diagonal, padded rows are dropped (forward) or carry lse = +inf (backward).
+KERNEL_FWD_WIN = "flash_fwd_win"
+KERNEL_DQ_WIN = "flash_dq_win"
+KERNEL_DKV_WIN = "flash_dkv_win"
+_WIN_BLOCK = 512
+_WIN_STRIP = 256
+
+
+def _window_cells(block: int, window: int) -> int:
+    """Key blocks a query block can see under the window, itself included."""
+    return (window + block - 2) // block + 1
+
+
+def _window_strips(block: int, window: int, delta: int) -> list:
+    """The walk of a cell whose query block lies `delta` blocks after its
+    key block: (r0, rows, c0, cols, masked) a strip, local coordinates."""
+    t = min(_WIN_STRIP, block)
+    shift = delta * block
+    out = []
+    for r0 in range(0, block, t):
+        lo = max(0, r0 + shift - window + 1)       # first column row r0 sees
+        hi = min(block, r0 + t + shift)            # past the last row's last
+        if lo >= hi:
+            continue
+        lo, hi = lo // 128 * 128, min(block, -(-hi // 128) * 128)
+        masked = (hi - 1 > r0 + shift                      # the diagonal
+                  or lo <= r0 + t - 1 + shift - window)    # the window's edge
+        out.append((r0, t, lo, hi - lo, masked))
+    return out
+
+
+def _count_window_tiles(n_q: int, block: int, window: int,
+                        kernels: int) -> None:
+    """`_count_tiles` for a windowed call, in sub-tiles of the strip's size:
+    what the strips cover, and what of the causal cells they leave out."""
+    t = min(_WIN_STRIP, block)
+    computed = 0
+    for delta in range(_window_cells(block, window)):
+        cell = sum(nr * nc for _, nr, _, nc, _ in
+                   _window_strips(block, window, delta))
+        computed += cell * max(n_q - delta, 0)
+    computed = -(-computed // (t * t))
+    causal = n_q * (n_q + 1) // 2 * (block // t) ** 2
+    reliability_metrics.inc(tnames.FLASH_TILES_COMPUTED, kernels * computed)
+    reliability_metrics.inc(tnames.FLASH_TILES_SKIPPED,
+                            kernels * max(causal - computed, 0))
+
+
+def _window_mask(s, r0: int, nr: int, c0: int, nc: int, shift: int,
+                 window: int):
+    """-1e30 where key c0 + c is not in query r0 + r's window, the key block
+    `shift` positions before the query block."""
+    rel = (c0 - r0 - shift
+           + jax.lax.broadcasted_iota(jnp.int32, (1, nc), 1)
+           - jax.lax.broadcasted_iota(jnp.int32, (nr, 1), 0))
+    return jnp.where((rel <= 0) & (rel > -window), s, -1e30)
+
+
+def _win_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_o, l_o, acc_ref, m_ref,
+                    l_ref, *, block: int, window: int, scale: float):
+    qb, j = pl.program_id(1), pl.program_id(2)
+    n_vis = _window_cells(block, window)
+    exact = q_ref.dtype == jnp.float32
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def attend(delta, r0, nr, c0, nc, masked):
+        rows, cols = pl.ds(r0, nr), pl.ds(c0, nc)
+        q = q_ref[0, rows, :] * jnp.asarray(scale, q_ref.dtype)
+        v = v_ref[0, cols, :]
+        s = _mxu_dot(q, k_ref[0, cols, :], (1, 1), exact)
+        if masked:
+            s = _window_mask(s, r0, nr, c0, nc, delta * block, window)
+        m_prev, l_prev = m_ref[rows, :], l_ref[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[rows, :] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[rows, :] = (acc_ref[rows, :] * alpha
+                            + _mxu_dot(p.astype(v.dtype), v, (1, 0), exact))
+        m_ref[rows, :] = m_new
+
+    for delta in range(n_vis):
+        @pl.when((j == n_vis - 1 - delta) & (qb >= delta))
+        def _cell(delta=delta):
+            for strip in _window_strips(block, window, delta):
+                attend(delta, *strip)
+
+    @pl.when(j == n_vis - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
+        m_o[0] = m_ref[...]
+        l_o[0] = l_ref[...]
+
+
+def _win_bwd_strip(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, delta,
+                   r0, nr, c0, nc, masked, *, block, window, scale):
+    """`_bwd_common` for one strip of a windowed cell -> (p, ds, do, q, k)."""
+    rows, cols = pl.ds(r0, nr), pl.ds(c0, nc)
+    q, k, do = q_ref[0, rows, :], k_ref[0, cols, :], do_ref[0, rows, :]
+    exact = q_ref.dtype == jnp.float32
+    s = _mxu_dot(q * jnp.asarray(scale, q_ref.dtype), k, (1, 1), exact)
+    if masked:
+        s = _window_mask(s, r0, nr, c0, nc, delta * block, window)
+    p = jnp.exp(s - lse_ref[0, rows, :])
+    dp = _mxu_dot(do, v_ref[0, cols, :], (1, 1), exact)
+    return p, p * (dp - dsum_ref[0, rows, :]), do, q, k
+
+
+def _win_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
+                   acc_ref, *, block: int, window: int, scale: float):
+    qb, j = pl.program_id(1), pl.program_id(2)
+    n_vis = _window_cells(block, window)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for delta in range(n_vis):
+        @pl.when((j == n_vis - 1 - delta) & (qb >= delta))
+        def _cell(delta=delta):
+            for strip in _window_strips(block, window, delta):
+                _, ds, _, _, k = _win_bwd_strip(
+                    q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, delta,
+                    *strip, block=block, window=window, scale=scale)
+                acc_ref[pl.ds(strip[0], strip[1]), :] += _mxu_dot(
+                    ds.astype(k.dtype), k, (1, 0), k.dtype == jnp.float32)
+
+    @pl.when(j == n_vis - 1)
+    def _finish():
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _win_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, *, n_q: int, block: int,
+                    window: int, scale: float):
+    kb, j = pl.program_id(1), pl.program_id(2)
+    n_vis = _window_cells(block, window)
+
+    @pl.when(j == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    for delta in range(n_vis):
+        @pl.when((j == delta) & (kb + delta < n_q))
+        def _cell(delta=delta):
+            for strip in _window_strips(block, window, delta):
+                p, ds, do, q, _ = _win_bwd_strip(
+                    q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, delta,
+                    *strip, block=block, window=window, scale=scale)
+                exact = q.dtype == jnp.float32
+                cols = pl.ds(strip[2], strip[3])
+                dv_acc[cols, :] += _mxu_dot(p.astype(do.dtype), do, (0, 0),
+                                            exact)
+                dk_acc[cols, :] += _mxu_dot(ds.astype(q.dtype), q, (0, 0),
+                                            exact)
+
+    @pl.when(j == n_vis - 1)
+    def _finish():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _win_forward_lse(q, k, v, window, scale, block, interpret):
+    """(H, S, D) q, k and (H, S, Dv) v -> (out (H, S, Dv), lse (H, S, 1))."""
+    h, _, d = q.shape
+    dv = v.shape[-1]
+    q, k, v, s, _, n_q, _ = _pad_blocks(q, k, v, block, block)
+    n_vis = _window_cells(block, window)
+    _count_window_tiles(n_q, block, window, 1)
+
+    def q_map(hh, qb, j):
+        return hh, qb, 0
+
+    def k_map(hh, qb, j):
+        return hh, jnp.maximum(qb - (n_vis - 1) + j, 0), 0
+
+    one = pl.BlockSpec((1, block, 1), q_map)
+    out, m, l = pl.pallas_call(
+        functools.partial(_win_fwd_kernel, block=block, window=window,
+                          scale=scale),
+        grid=(h, n_q, n_vis),
+        in_specs=[pl.BlockSpec((1, block, d), q_map),
+                  pl.BlockSpec((1, block, d), k_map),
+                  pl.BlockSpec((1, block, dv), k_map)],
+        out_specs=[pl.BlockSpec((1, block, dv), q_map), one, one],
+        out_shape=[jax.ShapeDtypeStruct((h, q.shape[1], dv), q.dtype),
+                   jax.ShapeDtypeStruct((h, q.shape[1], 1), jnp.float32),
+                   jax.ShapeDtypeStruct((h, q.shape[1], 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block, dv), jnp.float32),
+                        pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, 1), jnp.float32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name=KERNEL_FWD_WIN,
+    )(q, k, v)
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    return out[:, :s], lse[:, :s]
+
+
+def _win_backward(q, k, v, out, lse, g, window, scale, block, interpret):
+    h, s, d = q.shape
+    dv = v.shape[-1]
+    q_p, k_p, v_p, _, _, n_q, _ = _pad_blocks(q, k, v, block, block)
+    n_vis = _window_cells(block, window)
+    _count_window_tiles(n_q, block, window, 2)
+    pad = q_p.shape[1] - s
+    rows = ((0, 0), (0, pad), (0, 0))
+    dsum = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                   axis=-1, keepdims=True)
+    g_p, dsum = (jnp.pad(g, rows), jnp.pad(dsum, rows)) if pad else (g, dsum)
+    lse_p = jnp.pad(lse, rows, constant_values=jnp.inf) if pad else lse
+
+    def q_map(hh, qb, j):
+        return hh, qb, 0
+
+    def k_of_q(hh, qb, j):
+        return hh, jnp.maximum(qb - (n_vis - 1) + j, 0), 0
+
+    dq = pl.pallas_call(
+        functools.partial(_win_dq_kernel, block=block, window=window,
+                          scale=scale),
+        grid=(h, n_q, n_vis),
+        in_specs=[pl.BlockSpec((1, block, d), q_map),
+                  pl.BlockSpec((1, block, d), k_of_q),
+                  pl.BlockSpec((1, block, dv), k_of_q),
+                  pl.BlockSpec((1, block, dv), q_map),
+                  pl.BlockSpec((1, block, 1), q_map),
+                  pl.BlockSpec((1, block, 1), q_map)],
+        out_specs=pl.BlockSpec((1, block, d), q_map),
+        out_shape=jax.ShapeDtypeStruct(q_p.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name=KERNEL_DQ_WIN,
+    )(q_p, k_p, v_p, g_p, lse_p, dsum)[:, :s]
+
+    def k_map(hh, kb, j):
+        return hh, kb, 0
+
+    def q_of_k(hh, kb, j):
+        return hh, jnp.minimum(kb + j, n_q - 1), 0
+
+    dk, dv_out = pl.pallas_call(
+        functools.partial(_win_dkv_kernel, n_q=n_q, block=block,
+                          window=window, scale=scale),
+        grid=(h, n_q, n_vis),
+        in_specs=[pl.BlockSpec((1, block, d), k_map),
+                  pl.BlockSpec((1, block, dv), k_map),
+                  pl.BlockSpec((1, block, d), q_of_k),
+                  pl.BlockSpec((1, block, dv), q_of_k),
+                  pl.BlockSpec((1, block, 1), q_of_k),
+                  pl.BlockSpec((1, block, 1), q_of_k)],
+        out_specs=[pl.BlockSpec((1, block, d), k_map),
+                   pl.BlockSpec((1, block, dv), k_map)],
+        out_shape=[jax.ShapeDtypeStruct(k_p.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v_p.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                        pltpu.VMEM((block, dv), jnp.float32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name=KERNEL_DKV_WIN,
+    )(k_p, v_p, q_p, g_p, lse_p, dsum)
+    return dq, dk[:, :s], dv_out[:, :s]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_win(q, k, v, window, scale, block, interpret):
+    return _win_forward_lse(q, k, v, window, scale, block, interpret)[0]
+
+
+def _flash_win_fwd(q, k, v, window, scale, block, interpret):
+    out, lse = checkpoint_name(
+        _win_forward_lse(q, k, v, window, scale, block, interpret),
+        tnames.KEEP_FLASH)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_win_bwd(window, scale, block, interpret, res, g):
+    q, k, v, out, lse = res
+    return _win_backward(q, k, v, out, lse, g, window, scale, block,
+                         interpret)
+
+
+_flash_win.defvjp(_flash_win_fwd, _flash_win_bwd)
+
+
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
     """Exact attention without the (S, S) HBM score matrix.
 
-    q: (S, H, D); k/v: (Sk, H, D). Returns (S, H, D), same dtype as q.
+    q: (S, H, D); k: (Sk, H, D); v: (Sk, H, Dv), Dv = D unless the values
+    are wider or narrower than the keys. Returns (S, H, Dv), same dtype
+    as q. `window` (static; causal self-attention only): key s is visible
+    to query t iff t - window < s <= t; such a call runs the kernels
+    `flash_fwd_win` / `flash_dq_win` / `flash_dkv_win` over the cells the
+    window can reach, in square blocks of `block_q` (default 512). None,
+    or a window no shorter than the sequence, is the call without one.
     block_q/block_k default to a measured-on-v5e auto choice (the largest
     of 1024 / 512 / 256 that pads the sequence by under 20%; the BACKWARD
     internally caps at 512 for f32 operands, which exceed VMEM at 1024).
@@ -868,6 +1201,20 @@ def flash_attention(q, k, v, causal: bool = False,
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
     q = jnp.asarray(q)
+    if window is not None and window < q.shape[0]:
+        if not causal or k.shape[0] != q.shape[0] or window < 1 \
+                or block_q != block_k:
+            raise ValueError(
+                f"a window ({window}) bounds causal self-attention in "
+                f"square blocks, not causal={causal}, {q.shape[0]} queries "
+                f"over {k.shape[0]} keys, blocks {block_q} x {block_k}")
+        block = int(block_q) if block_q is not None \
+            else min(_pick_block(q.shape[0]), _WIN_BLOCK)
+        out = _flash_win(
+            jnp.moveaxis(q, 1, 0), jnp.moveaxis(jnp.asarray(k), 1, 0),
+            jnp.moveaxis(jnp.asarray(v), 1, 0), int(window), float(scale),
+            block, bool(interpret))
+        return jnp.moveaxis(out, 0, 1)
     a_bq, a_bk, a_bwd_bq, a_bwd_bk = _auto_blocks(
         q.shape[0], k.shape[0], q.dtype, q.shape[-1])
     bq = int(block_q) if block_q is not None else a_bq

@@ -89,6 +89,9 @@ GDN_MIXER_ROUTE_PALLAS = "gdn.mixer.route.pallas"
 GDN_MIXER_ROUTE_XLA = "gdn.mixer.route.xla"
 LM_REMAT_KEEP_FLASH = "lm.remat.keep.flash"
 LM_REMAT_KEEP_ROUTING = "lm.remat.keep.routing"
+SSM_SCAN_ROUTE_PALLAS = "ssm.scan.route.pallas"
+SSM_SCAN_ROUTE_XLA = "ssm.scan.route.xla"
+LM_SHARED_READERS = "lm.shared.readers"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -247,6 +250,23 @@ COUNTERS = {
     GDN_MIXER_ROUTE_XLA: "calls of the same two passes traced down their "
                          "plain jnp form on the slab: off the TPU, or head "
                          "sizes that are no multiple of 128 (never silent)",
+    SSM_SCAN_ROUTE_PALLAS: "calls of the selective state-space scan traced "
+                           "down the Pallas kernels (ssm_fwd, ssm_bwd): a "
+                           "TPU, channels in multiples of 128 and states "
+                           "of 8 (ops/selective_scan.py), or a test's own "
+                           "interpret-mode call; counted at trace time, "
+                           "once a call site",
+    SSM_SCAN_ROUTE_XLA: "calls of the selective scan traced down its XLA "
+                        "form (a lax.scan over chunks): off the TPU, or "
+                        "shapes the kernels do not fit (never silent)",
+    LM_SHARED_READERS: "sublayers of a traced state-space model "
+                       "(models/dnn/ssm_layers.py) that read an array an "
+                       "earlier layer made: a Gated Memory Unit reads the "
+                       "memory layer's scan output, a cross-attention "
+                       "layer the KV layer's keys and values; counted at "
+                       "trace time, once a reader of the description "
+                       "(a run's period is traced once and counts its "
+                       "readers times the run's repetitions)",
     LM_REMAT_KEEP_FLASH: "checkpointed mixer sublayers of the hybrid and "
                          "short-convolution families whose policy keeps a "
                          "flash call's output and row sums (flash.forward "
@@ -527,6 +547,9 @@ LM_MOE_EXPERTS = "lm.moe.experts"
 LM_MOE_SHARED = "lm.moe.shared"
 LM_CONV = "lm.conv"
 LM_CONV_GATE = "lm.conv.gate"
+LM_SSM = "lm.ssm"
+LM_SSM_SCAN = "lm.ssm.scan"
+LM_GMU = "lm.gmu"
 GBDT_HIST = "gbdt.hist"
 GBDT_SPLIT = "gbdt.split"
 GBDT_ROUTE = "gbdt.route"
@@ -537,7 +560,9 @@ DEVICE_REGIONS = {
     LM_EMBED: "token + position embedding lookup of a microbatch",
     LM_ATTN: "attention sublayer outside its kernels: ln1, q/k/v/o "
              "projections, residual",
-    LM_ATTN_FLASH: "the flash / ring attention call (kernels flash_fwd, "
+    LM_ATTN_FLASH: "(windowed calls: flash_fwd_win, flash_dq_win, "
+                   "flash_dkv_win) "
+                   "the flash / ring attention call (kernels flash_fwd, "
                    "flash_dq, flash_dkv, flash_stats_fwd and their glue)",
     LM_MLP: "feed-forward sublayer: ln2, gelu MLP, residual",
     LM_HEAD: "final layer norm, tied logits, log-softmax, NLL",
@@ -568,6 +593,15 @@ DEVICE_REGIONS = {
     LM_CONV_GATE: "the short convolution's gate pass on (B, S, d) slabs: "
                   "B * u, the causal depthwise taps, C *, float32 inside; "
                   "forward and backward",
+    LM_SSM: "state-space (Mamba) mixer outside its scan: input norm, W_in, "
+            "the causal depthwise convolution + SiLU, W_x, W_dt + "
+            "softplus, the gate, W_out, residual",
+    LM_SSM_SCAN: "the selective scan (ops/selective_scan.py): on a TPU the "
+                 "kernels ssm_fwd / ssm_bwd with the broadcast of B and C "
+                 "along the lanes before them and the sums after, else "
+                 "the XLA form",
+    LM_GMU: "Gated Memory Unit: input norm, W_1, memory * silu, W_2, "
+            "residual",
     GBDT_HIST: "node x feature x bin histogram build (and its psum)",
     GBDT_SPLIT: "best-split search of one level",
     GBDT_ROUTE: "advance rows to their child nodes",
@@ -586,6 +620,8 @@ DEVICE_REGIONS = {
 # inside its fwd rule, not at its call.
 KEEP_FLASH = "flash.forward"
 KEEP_ROUTING = "moe.routing"
+KEEP_SHARED = "lm.shared"
+KEEP_SSM = "ssm.forward"
 
 REMAT_RESIDUALS = {
     KEEP_FLASH: "the flash forward's output (heads, S, D), in the "
@@ -600,6 +636,20 @@ REMAT_RESIDUALS = {
                   "the tile plan's pair ids sorted by held expert (N k,) "
                   "with its starts, tile ends and counts (dispatch_plan: no "
                   "second sort)",
+    KEEP_SSM: "the selective scan's output (B, S, channels) in the "
+              "activations' dtype and the state each chunk started from "
+              "(B, chunks, states, channels) float32 "
+              "(ops/selective_scan.py _scan_pallas_fwd): all the backward "
+              "kernel needs of a second ssm_fwd; 105 MB a scan call at "
+              "8,192 x 5,120, 0.08 GB of the phi4flash cell's peak "
+              "(compiled for a described v5e, PR 35: 16.16 GB with, 16.08 "
+              "without) for 7 ms a step",
+    KEEP_SHARED: "what a layer of a state-space model hands to later "
+                 "layers (models/dnn/ssm_layers.py): the memory (the "
+                 "memory layer's scan output before its gate) and the KV "
+                 "layer's keys and values after projection and bias: the "
+                 "forward pass's arrays are what every reader's and the "
+                 "maker's own recomputation read",
 }
 
 LM_STEP_H2D = "lm.step.h2d"

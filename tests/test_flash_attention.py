@@ -461,3 +461,88 @@ def test_tiles_counter_reads_the_triangle():
         lambda q, k, v: flash_attention_stats(
             q, k, v, 0, 0, causal=True, scale=0.125))(x, x, x))
     assert seen == (1, 0)
+
+
+# ------------------------------------------------------- a sliding window
+def _windowed_dense(q, k, v, window):
+    """Dense masked softmax: key s visible to query t iff
+    t - window < s <= t (window None: causal alone)."""
+    s = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(q.shape[-1])
+    back = jnp.arange(q.shape[0])[:, None] - jnp.arange(k.shape[0])[None, :]
+    seen = back >= 0 if window is None else (back >= 0) & (back < window)
+    p = jax.nn.softmax(jnp.where(seen[None], s, -1e30), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", p, v)
+
+
+# (sequence, value width, window, block): windows of 1, inside one block,
+# of the cell's 512 over two strips of a 512 block, across a padded tail,
+# and no shorter than the sequence (the call without a window)
+_WINDOW_CASES = {"one": (300, 128, 1, None), "short": (300, 128, 77, None),
+                 "cell": (1024, 128, 512, 512),
+                 "ragged": (1100, 64, 300, 512),
+                 "whole": (300, 128, 300, None)}
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+def test_window_matches_dense_masked_softmax(case):
+    """Forward and the three gradients, float32, interpret mode; values as
+    wide as the keys and twice as wide."""
+    seq, dv, window, block = _WINDOW_CASES[case]
+    rng = np.random.default_rng(3)
+    q, k = (jnp.asarray(rng.normal(size=(seq, 2, 64)), jnp.float32)
+            for _ in range(2))
+    v, w = (jnp.asarray(rng.normal(size=(seq, 2, dv)), jnp.float32)
+            for _ in range(2))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=block, block_k=block)
+
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(
+            lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+        want, want_g = jax.value_and_grad(
+            lambda *a: (_windowed_dense(*a, window) * w).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+        assert float(jnp.abs(flash(q, k, v)
+                             - _windowed_dense(q, k, v, window)).max()) < 1e-5
+    assert abs(float(got - want)) < 1e-2
+    for name, a, b in zip("qkv", got_g, want_g):
+        assert float(jnp.abs(a - b).max()) < 2e-5, name
+
+
+def test_no_window_is_the_call_it_always_was():
+    """`window=None`, no `window` at all and a window no shorter than the
+    sequence trace to one jaxpr, whose kernels are the three unwindowed
+    ones; a shorter window traces the three `_win` kernels and none of the
+    others; tiles are counted either way."""
+    x = jax.ShapeDtypeStruct((2048, 2, 64), jnp.bfloat16)
+
+    def jaxpr(**kw):
+        return str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, causal=True, **kw
+                                            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))(x, x, x))
+
+    plain = jaxpr()
+    assert jaxpr(window=None) == plain == jaxpr(window=2048)
+    names = ["flash_fwd", "flash_dq", "flash_dkv"]
+    assert all(f"name={n}\n" in plain or f"name={n} " in plain
+               or f"name={n}]" in plain for n in names), plain[:400]
+    assert "_win" not in plain
+    (windowed, seen) = _tiles_delta(lambda: jaxpr(window=512))
+    assert all(f"name={n}_win" in windowed for n in names)
+    assert all(f"name={n}\n" not in windowed and f"name={n} " not in windowed
+               and f"name={n}]" not in windowed for n in names)
+    # 4 query blocks of 512: the diagonal cell 3 sub-tiles of 256, the one
+    # before it 3 (3 blocks have one); of the 10 causal cells' 40
+    assert seen == (3 * (4 * 3 + 3 * 3), 3 * (40 - 21))
+
+
+def test_a_window_wants_causal_self_attention():
+    q = jnp.zeros((64, 1, 8))
+    with pytest.raises(ValueError, match="bounds causal self-attention"):
+        flash_attention(q, q, q, causal=False, window=4)
+    with pytest.raises(ValueError, match="bounds causal self-attention"):
+        flash_attention(q, jnp.zeros((32, 1, 8)), jnp.zeros((32, 1, 8)),
+                        causal=True, window=4)
