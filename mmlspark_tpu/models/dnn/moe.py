@@ -9,16 +9,30 @@ carries tokens and results between them. Nothing here stands in for those
 chips or that exchange.
 
 Dropless, with no capacity factor. The (token, expert) pairs are sorted by
-expert and each held expert's run is cut into tiles of `TILE` rows; a loop
-with a DYNAMIC trip count (the tiles this step's routing made) gathers a
-tile's tokens, runs them through the tile's expert's gated MLP and
-scatter-adds the result. Work follows the pairs actually held (on a v5e
-0.8 to 1.0 us a pair, PR 28), no shape depends on the routing and no
-buffer grows with the skew.
+expert and each held expert's run is cut into tiles of `TILE` rows; a tile's
+tokens are gathered, run through the tile's expert's gated MLP and added
+into the output. Work follows the pairs actually held, no shape depends on
+the routing and no buffer grows with the skew. Two forms of that one walk,
+chosen by `moe_layer` from what it can observe (the platform and the
+shapes: `ops/moe_experts.pallas_fits`), with no knob, and counted
+(`moe.experts.route.pallas` / `moe.experts.route.xla`):
 
-The loop is a `while`, which reverse-mode cannot differentiate, so
-`_experts` carries its own backward: the same loop over the same tiles,
-recomputing a tile's hidden activations and accumulating the gradients.
+**The kernels** `moe_fwd` / `moe_bwd` (`ops/moe_experts.py`), on a TPU: ONE
+pipelined Pallas call a direction over a tile-aligned plan
+(`dispatch_plan(..., top_p)`: the sort pads each run to whole tiles and
+carries the pairs' weights), a grid as long as the dropless bound
+N k / TILE + E whose steps past the tiles this routing made do nothing,
+a tile's rows moved by DMA while the tile before's matrix products run,
+an expert's weights resident in VMEM across its tiles.
+
+**The XLA loop** (`_experts_xla`): a `fori_loop` with a DYNAMIC trip count
+(a `while`, which reverse-mode cannot differentiate, so it carries its own
+backward: the same loop over the same tiles, recomputing a tile's hidden
+activations and accumulating the gradients). One fusion a tile, its
+gather, weight fetch, products and scatter-add one after the other (on a
+v5e 114 to 171 us a tile and direction, PR 35's records). The fallback for
+every other platform and shape, and the plain form the kernels are tested
+against (tests/test_moe_kernel.py).
 """
 from __future__ import annotations
 
@@ -28,9 +42,16 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ...ops import moe_experts
+from ...reliability.metrics import reliability_metrics
 from ...telemetry import names as tnames
 
-TILE = 256
+# rows a tile. Swept on one layer alone on a v5e at both share cells' shapes
+# (PR 36): the kernels take 7.3 / 7.8 / 8.2 ms forward + backward at 128 /
+# 256 / 512 (32 experts of width 512, 320 pairs each) and 17.2 / 18.9 / 18.2
+# (8 experts of width 1536, 2,048 pairs each); the XLA loop likes them larger
+# (20.8 / 19.8 / 17.5 and 39.9 / 29.3 / 25.7 ms) and is no cell's hot path.
+TILE = 128
 # Telling XLA that a tile's ids are sorted and unique (they are) makes the
 # v5e's scatters 4x SLOWER (PR 28: 112 -> 559 us a forward tile), so the
 # scatters say nothing.
@@ -113,13 +134,20 @@ def route(x, w_router, top_k: int, renormalize: bool = True,
         return idx, top
 
 
-def dispatch_plan(idx, lo: int, hi: int):
+def dispatch_plan(idx, lo: int, hi: int, top_p=None):
     """The tile layout of one routing. idx (N, k) expert ids over all
     experts; [lo, hi) the experts held. Returns a dict of int32 arrays:
     `order` (N k,) pair ids sorted by held expert (absent pairs last),
     `starts` (E + 1,) the first sorted row of each held expert's run,
-    `tile_ends` (E,) cumulative tiles through each expert, `counts` (E,)."""
+    `tile_ends` (E,) cumulative tiles through each expert, `counts` (E,).
+    With `top_p` (N, k), the pairs' weights: the kernels' tile-aligned
+    layout instead (`ops/moe_experts.tile_plan`: the sort pads each run to
+    whole tiles and carries the weights), `counts` among its keys too."""
     with jax.named_scope(tnames.LM_MOE_DISPATCH):
+        if top_p is not None:
+            return checkpoint_name(
+                moe_experts.tile_plan(idx, jax.lax.stop_gradient(top_p), lo,
+                                      hi, TILE), tnames.KEEP_ROUTING)
         n_held = hi - lo
         flat = idx.reshape(-1)
         held = (flat >= lo) & (flat < hi)
@@ -164,8 +192,19 @@ def _gather_tile(t, x, p_flat, plan, top_k: int):
     return e, token, pair, xt, weight
 
 
-@jax.custom_vjp
 def _experts(x, top_p, w_gate, w_up, w_down, plan):
+    """The routed sum of the held experts over `plan`'s tiles, (N, d) ->
+    (N, d): the kernels where the plan is theirs, else the XLA loop."""
+    if "pair" in plan:
+        reliability_metrics.inc(tnames.MOE_EXPERTS_ROUTE_PALLAS)
+        return moe_experts.experts_pallas(x, top_p, w_gate, w_up, w_down,
+                                          plan)
+    reliability_metrics.inc(tnames.MOE_EXPERTS_ROUTE_XLA)
+    return _experts_xla(x, top_p, w_gate, w_up, w_down, plan)
+
+
+@jax.custom_vjp
+def _experts_xla(x, top_p, w_gate, w_up, w_down, plan):
     return _experts_fwd(x, top_p, w_gate, w_up, w_down, plan)[0]
 
 
@@ -234,7 +273,7 @@ def _experts_bwd(res, dout):
             dw_down.astype(w_down.dtype), None)
 
 
-_experts.defvjp(_experts_fwd, _experts_bwd)
+_experts_xla.defvjp(_experts_fwd, _experts_bwd)
 
 
 def gated_mlp(x, w_gate, w_up, w_down):
@@ -258,10 +297,13 @@ def moe_layer(x, p, top_k: int, experts_held: tuple,
     lo, hi = experts_held
     idx, top_p = route(x, p["router"], top_k, renormalize, scoring,
                        p.get("expert_bias"), scale)
-    plan = dispatch_plan(idx, lo, hi)
+    top_p = top_p.astype(jnp.float32)
+    kernels = (moe_experts.pallas_fits(x, p["w_gate"], top_k, TILE)
+               and jax.devices()[0].platform == "tpu")
+    plan = dispatch_plan(idx, lo, hi, top_p if kernels else None)
     with jax.named_scope(tnames.LM_MOE_EXPERTS):
-        routed = _experts(x, top_p.astype(jnp.float32), p["w_gate"],
-                          p["w_up"], p["w_down"], plan)
+        routed = _experts(x, top_p, p["w_gate"], p["w_up"], p["w_down"],
+                          plan)
     shared = None
     if "shared_gate" in p:
         with jax.named_scope(tnames.LM_MOE_SHARED):

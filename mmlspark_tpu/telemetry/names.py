@@ -92,6 +92,8 @@ LM_REMAT_KEEP_ROUTING = "lm.remat.keep.routing"
 SSM_SCAN_ROUTE_PALLAS = "ssm.scan.route.pallas"
 SSM_SCAN_ROUTE_XLA = "ssm.scan.route.xla"
 LM_SHARED_READERS = "lm.shared.readers"
+MOE_EXPERTS_ROUTE_PALLAS = "moe.experts.route.pallas"
+MOE_EXPERTS_ROUTE_XLA = "moe.experts.route.xla"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -259,6 +261,17 @@ COUNTERS = {
     SSM_SCAN_ROUTE_XLA: "calls of the selective scan traced down its XLA "
                         "form (a lax.scan over chunks): off the TPU, or "
                         "shapes the kernels do not fit (never silent)",
+    MOE_EXPERTS_ROUTE_PALLAS: "expert layers whose tile loop was traced "
+                              "down the Pallas kernels (moe_fwd, moe_bwd: "
+                              "ops/moe_experts.py): a TPU, model and "
+                              "expert widths in multiples of 128, "
+                              "bfloat16 or float32, whole tiles of pairs "
+                              "and a sorted order SMEM holds; counted at "
+                              "trace time, once a call of moe._experts",
+    MOE_EXPERTS_ROUTE_XLA: "expert layers whose tile loop was traced as "
+                           "the XLA while loop (models/dnn/moe.py): off "
+                           "the TPU, or shapes the kernels do not fit "
+                           "(never silent)",
     LM_SHARED_READERS: "sublayers of a traced state-space model "
                        "(models/dnn/ssm_layers.py) that read an array an "
                        "earlier layer made: a Gated Memory Unit reads the "
@@ -582,10 +595,16 @@ DEVICE_REGIONS = {
                  "forward and backward",
     LM_MOE_ROUTER: "expert layer: post norm, router logits, softmax or "
                    "sigmoid scores (+ selection bias), top-k",
-    LM_MOE_DISPATCH: "expert layer: sort of the pairs by held expert, the "
-                     "tile loop's gathers and scatter-adds",
+    LM_MOE_DISPATCH: "expert layer: the tile plan (sort of the pairs by "
+                     "held expert, counts, the tile table) and, round the "
+                     "kernels moe_fwd / moe_bwd, the slab copies of x and "
+                     "dout and the sort that puts the pairs' "
+                     "gradient back; in the XLA loop also its gathers and "
+                     "scatter-adds",
     LM_MOE_EXPERTS: "expert layer: the held experts' gated MLPs over the "
-                    "tiles of the pairs routed to them",
+                    "tiles of the pairs routed to them; on a TPU the "
+                    "kernels moe_fwd / moe_bwd (ops/moe_experts.py), which "
+                    "also move the tiles' rows by DMA",
     LM_MOE_SHARED: "expert layer: the shared expert, its sigmoid gate, the "
                    "sum with the routed part, residual",
     LM_CONV: "gated short-convolution mixer outside its gate pass: input "
@@ -634,8 +653,9 @@ REMAT_RESIDUALS = {
                   "experts' ids (N, k) int32 and their scores before they "
                   "are renormalised and scaled (_choose: no second top-k); "
                   "the tile plan's pair ids sorted by held expert (N k,) "
-                  "with its starts, tile ends and counts (dispatch_plan: no "
-                  "second sort)",
+                  "with its starts, tile ends and counts, or the kernels' "
+                  "tile-aligned plan (pair ids, weights, tile table) "
+                  "(dispatch_plan: no second sort)",
     KEEP_SSM: "the selective scan's output (B, S, channels) in the "
               "activations' dtype and the state each chunk started from "
               "(B, chunks, states, channels) float32 "

@@ -5,7 +5,8 @@ shape rule that chooses between kernel and XLA form, the counters that say
 which was taken, and the kernels' place in the compiled step's regions; and,
 because one file a process may describe the chip in, the same compiled
 checks for the mixer's own kernels (`ops/gdn_mixer.py`, whose parities are
-in test_gdn_mixer_kernels.py)."""
+in test_gdn_mixer_kernels.py) and for the expert layer's
+(`ops/moe_experts.py`, parities in test_moe_kernel.py)."""
 import importlib.util
 import os
 
@@ -16,6 +17,7 @@ import pytest
 
 from mmlspark_tpu.ops import gated_delta as gd
 from mmlspark_tpu.ops import gdn_mixer as gm
+from mmlspark_tpu.ops import moe_experts as me
 from mmlspark_tpu.reliability.metrics import reliability_metrics
 from mmlspark_tpu.telemetry import names as tnames
 
@@ -325,3 +327,88 @@ def test_compiled_layer_keeps_the_slab_and_the_regions(gdn_layer):
                 [int(n) for n in m.group(2).split(",") if n]) >= slab:
             moved.append(m.group(1))
     assert moved == []
+
+
+# ---- the expert layer's tile loop as kernels (ops/moe_experts.py)
+
+MOE_ROUTES = (tnames.MOE_EXPERTS_ROUTE_PALLAS, tnames.MOE_EXPERTS_ROUTE_XLA)
+# (model width, expert width, experts a token) as the two share cells run
+# them; fewer tokens and held experts (the grid's length and the number of
+# weight blocks are no part of a kernel)
+MOE_WIDTHS = {"qwen3-next": (2048, 512, 10), "lfm2": (2048, 1536, 4)}
+
+
+@pytest.mark.parametrize("model", sorted(MOE_WIDTHS))
+def test_expert_kernels_compile_for_v5e_at_the_published_widths(v5e, model):
+    """bfloat16, tiles of `moe.TILE` rows: the per-row copies, the resident
+    weight and gradient blocks and the VMEM they take are Mosaic's to
+    refuse here (LFM2's backward walks its width in two blocks)."""
+    from mmlspark_tpu.models.dnn import moe
+    _, chip = v5e
+    d, f, k = MOE_WIDTHS[model]
+    n, held = 1024, 4
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=chip)
+
+    assert me.pallas_fits(shape(n, d), shape(held, d, f), k, moe.TILE)
+
+    def loss(idx, x, top_p, wg, wu, wd):
+        plan = moe.dispatch_plan(idx, 0, held, top_p)
+        return me.experts_pallas(x, top_p, wg, wu, wd,
+                                 plan).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(1, 2, 3, 4, 5))).lower(
+        shape(n, k, dtype=jnp.int32), shape(n, d),
+        shape(n, k, dtype=jnp.float32), shape(held, d, f),
+        shape(held, d, f), shape(held, f, d)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert f"%{me.KERNEL_FWD}." in text and f"%{me.KERNEL_BWD}." in text
+
+
+def test_checkpointed_expert_sublayer_runs_each_kernel_once(v5e):
+    """An expert sublayer as the share families run it (`remat` true: a
+    checkpoint that keeps the routing), compiled for the chip: ONE
+    `moe_fwd` and ONE `moe_bwd` (the backward reads the layer's inputs and
+    the kept plan, so nothing runs the forward kernel again), both in
+    region `lm.moe.experts`, and the choice was counted."""
+    import re
+    from mmlspark_tpu.models.dnn import hybrid_layers, moe
+    from mmlspark_tpu.telemetry import perf
+    topo, chip = v5e
+    n, d, f, k, held, e_all = 1024, 256, 128, 2, 4, 8
+
+    def shape(*s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=chip)
+
+    lp = {"router": shape(d, e_all), "w_gate": shape(held, d, f),
+          "w_up": shape(held, d, f), "w_down": shape(held, f, d)}
+
+    def feed(h, lp):
+        out, stats = moe.moe_layer(h, lp, k, (0, held))
+        return h + out, stats
+
+    _, feed = hybrid_layers.checkpoint_sublayers(
+        lambda h, lp: h, feed, True, flash=False, routing=True)
+
+    def loss(h, lp):
+        return feed(h, lp)[0].astype(jnp.float32).sum()
+
+    # the choice of path asks the platform of jax.devices()[0]
+    real_devices = jax.devices
+    jax.devices = lambda *a: topo.devices
+    text = []
+    try:
+        counted = routes(lambda: text.append(jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1))).lower(shape(n, d), lp).compile(
+            ).as_text()), MOE_ROUTES)
+    finally:
+        jax.devices = real_devices
+    assert counted == (1, 0)
+    for kernel in (me.KERNEL_FWD, me.KERNEL_BWD):
+        assert len(re.findall(rf"%{kernel}\.\d+ = ", text[0])) == 1, kernel
+    scopes = perf.scope_map(text[0])
+    assert kernel_ways(scopes, me.KERNEL_FWD) == {
+        (tnames.LM_MOE_EXPERTS, "fwd")}
+    assert kernel_ways(scopes, me.KERNEL_BWD) == {
+        (tnames.LM_MOE_EXPERTS, "bwd")}
