@@ -32,6 +32,7 @@ from ...parallel.megatron import tp_g
 from ...reliability.metrics import reliability_metrics
 from ...telemetry import names as tnames
 from ...telemetry.perf import register_program
+from ...telemetry.profiler import StepRecorder
 from ...utils.tracing import annotate
 from .lm_spec import LMSpec, gpt2_spec
 from .lm_training import _build_multi_step
@@ -232,9 +233,12 @@ class PipelinedLMTrainer:
                     s_idx == 0,
                     lambda: embed_mb(mbs[jnp.clip(t, 0, M - 1)]),
                     lambda: act)
-                y, stats = family.stage(
-                    x_in, p["layers"], model, attention=attention,
-                    remat=remat, tp_axis=tp_axis, cp_axis=cp_axis)
+                # every sublayer scopes itself and the innermost region
+                # wins: what lands here is what the layer scans add
+                with jax.named_scope(tnames.LM_LAYERS):
+                    y, stats = family.stage(
+                        x_in, p["layers"], model, attention=attention,
+                        remat=remat, tp_axis=tp_axis, cp_axis=cp_axis)
                 # a stage counts the ticks in which it held a microbatch
                 live = (t >= s_idx) & (t - s_idx < M)
                 counts = jax.tree_util.tree_map(
@@ -255,8 +259,9 @@ class PipelinedLMTrainer:
                       jax.tree_util.tree_map(
                           lambda s: jnp.zeros(s.shape, s.dtype),
                           family.STATS))
-            (_, acc, counts), _ = jax.lax.scan(tick, carry0,
-                                               jnp.arange(M + S_P - 1))
+            with jax.named_scope(tnames.LM_TICKS):
+                (_, acc, counts), _ = jax.lax.scan(tick, carry0,
+                                                   jnp.arange(M + S_P - 1))
             # loss lives on the last stage; g-operator (psum forward,
             # IDENTITY backward) over BOTH pipe and seq shards — a bare
             # psum's transpose under check_vma=False is another psum, which
@@ -329,6 +334,7 @@ class PipelinedLMTrainer:
         self._step = jax.jit(train_step, donate_argnums=donate)
         self._multi = _build_multi_step(loss_only, donate)
         self._step_shape = None   # token shape of the last step() call
+        self._record = StepRecorder()
 
     def run(self, tokens: np.ndarray, n_steps: int) -> float:
         """n_steps chained updates with ONE host sync; returns the final
@@ -372,12 +378,19 @@ class PipelinedLMTrainer:
         """One dp x pp (x tp) (x cp) update; returns the batch loss.
 
         Three host spans at the step's layer boundaries (`lm.step.h2d`,
-        `lm.step.dispatch`, `lm.step.wait`; none adds a device sync) and
-        the `lm.step.compiles` counter: what the step program itself
-        compiled during the dispatch, by its own jit cache."""
+        `lm.step.dispatch`, `lm.step.wait`; none adds a device sync), a
+        fourth between two calls (`lm.step.gap`: the caller's batch), the
+        `lm.step.compiles` counter (what the step program itself compiled
+        during the dispatch, by its own jit cache), and one record a step
+        of the four with what the host did meanwhile
+        (`telemetry.profiler.step_records`; docs/observability.md "The
+        step record")."""
         self._check_batch(tokens)
+        record = self._record
+        record.start()
         with annotate(tnames.LM_STEP_H2D):
             d_tokens = self._to_device(tokens)
+        record.mark()
         if d_tokens.shape != self._step_shape:
             self._step_shape = d_tokens.shape
             self._register_step_program()
@@ -388,8 +401,10 @@ class PipelinedLMTrainer:
         compiled = self._step._cache_size() - held
         if compiled:
             reliability_metrics.inc(tnames.LM_STEP_COMPILES, compiled)
+        record.mark()
         with annotate(tnames.LM_STEP_WAIT):
             out = np.asarray(loss).ravel()
+        record.stop(compiled)
         # what the family's stages counted came with the loss
         self._family.report(out[1:])
         return float(out[0])

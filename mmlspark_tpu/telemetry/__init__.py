@@ -103,13 +103,13 @@ _LAZY_NAMES = {
     "psi": "quality", "js_divergence": "quality",
     "quality_watch_rules": "quality", "record_label": "quality",
     "StepClock": "goodput", "StragglerDetector": "goodput",
-    "flops_from_compile_log": "goodput",
     "ProfileSession": "profiler", "RooflineLedger": "profiler",
     "get_profile_session": "profiler",
     "configure_profile_session": "profiler",
     "capture_profile": "profiler", "parse_trace": "profiler",
     "get_roofline": "profiler", "resolve_peaks": "profiler",
-    "region_stats": "profiler",
+    "region_stats": "profiler", "step_records": "profiler",
+    "slow_steps": "profiler", "by_instruction": "profiler",
     "WatchRule": "watch", "TelemetryWatcher": "watch",
     "CompileLog": "perf", "FlightRecorder": "perf", "AotCache": "perf",
     "collective_traffic": "perf", "scope_map": "perf",
@@ -119,7 +119,6 @@ _LAZY_NAMES = {
     "compile_stats": "perf", "hbm_utilization": "perf",
     "sample_resource_gauges": "perf", "sample_resource_stats": "perf",
     "get_flight_recorder": "perf", "configure_flight_recorder": "perf",
-    "trigger_bundle": "perf",
 }
 
 
@@ -152,15 +151,16 @@ __all__ = ["Tracer", "Span", "SpanContext", "get_tracer", "configure",
            "configure_quality", "export_quality", "refresh_quality_gauges",
            "merge_quality_exports", "drift_scores", "psi", "js_divergence",
            "quality_watch_rules", "record_label",
-           "StepClock", "StragglerDetector", "flops_from_compile_log",
+           "StepClock", "StragglerDetector",
            "CompileLog", "FlightRecorder", "AotCache", "collective_traffic",
            "scope_map", "register_program", "scope_maps",
            "compile_with_analysis",
            "executable_analysis", "record_plan_compile", "get_compile_log",
            "compile_stats", "hbm_utilization", "sample_resource_gauges",
            "sample_resource_stats", "get_flight_recorder",
-           "configure_flight_recorder", "trigger_bundle",
+           "configure_flight_recorder",
            "ProfileSession", "RooflineLedger", "get_profile_session",
            "configure_profile_session", "capture_profile", "parse_trace",
-           "get_roofline", "resolve_peaks", "region_stats",
+           "get_roofline", "resolve_peaks", "region_stats", "step_records",
+           "slow_steps", "by_instruction",
            "WatchRule", "TelemetryWatcher"]

@@ -76,25 +76,6 @@ def peak_flops_from_env() -> Optional[float]:
     return tflops * 1e12 if tflops > 0 else None
 
 
-def flops_from_compile_log(fingerprint_prefix: str, log=None
-                           ) -> Optional[float]:
-    """Per-step flops from the newest compile record whose fingerprint
-    starts with `fingerprint_prefix` and carries a cost analysis — how a
-    trainer that compiled through `telemetry.perf` feeds its own MFU.
-    None when no matching record reported flops (CPU backends report
-    cost; a backend that omits it degrades MFU to absent)."""
-    from .perf import get_compile_log
-    records = (log if log is not None else get_compile_log()).records()
-    for rec in reversed(records):
-        if not str(rec.get("fingerprint", "")).startswith(fingerprint_prefix):
-            continue
-        analysis = rec.get("analysis") or {}
-        flops = analysis.get("flops")
-        if isinstance(flops, (int, float)) and flops > 0:
-            return float(flops)
-    return None
-
-
 class StepClock:
     """Phase-decomposed training-step accounting (see module docstring).
 

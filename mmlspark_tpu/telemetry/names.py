@@ -79,6 +79,8 @@ TELEMETRY_PROFILE_CAPTURES = "telemetry.profile.captures"
 TELEMETRY_PROFILE_SUPPRESSED = "telemetry.profile.suppressed"
 TELEMETRY_PROFILE_STAMP_ERRORS = "telemetry.profile.stamp_errors"
 LM_STEP_COMPILES = "lm.step.compiles"
+LM_STEP_SLOW = "lm.step.slow"
+LM_STEP_LOST_SECONDS = "lm.step.lost_seconds"
 FLASH_TILES_COMPUTED = "flash.tiles.computed"
 FLASH_TILES_SKIPPED = "flash.tiles.skipped"
 MOE_PAIRS_ROUTED = "moe.pairs.routed"
@@ -217,6 +219,15 @@ COUNTERS = {
     LM_STEP_COMPILES: "compilations of PipelinedLMTrainer's own step "
                       "program, seen during lm.step.dispatch (a new shape, "
                       "or the second step's donated layouts)",
+    LM_STEP_SLOW: "steps of PipelinedLMTrainer that telemetry.profiler."
+                  "slow_steps called slow when they were recorded: no "
+                  "compile, and a period (gap + h2d + dispatch + wait) "
+                  "over 1.03 of the median period of the step records "
+                  "then in the ring",
+    LM_STEP_LOST_SECONDS: "seconds those slow steps took beyond that "
+                          "median period (a float; which phase held them "
+                          "and what the host did meanwhile is in "
+                          "telemetry.profiler.step_records)",
     FLASH_TILES_COMPUTED: "sub-tiles a traced flash kernel call can execute, "
                           "per (batch, head), recorded at trace time: the "
                           "lower triangle of each diagonal cell plus every "
@@ -563,6 +574,8 @@ LM_CONV_GATE = "lm.conv.gate"
 LM_SSM = "lm.ssm"
 LM_SSM_SCAN = "lm.ssm.scan"
 LM_GMU = "lm.gmu"
+LM_LAYERS = "lm.layers"
+LM_TICKS = "lm.ticks"
 GBDT_HIST = "gbdt.hist"
 GBDT_SPLIT = "gbdt.split"
 GBDT_ROUTE = "gbdt.route"
@@ -621,6 +634,17 @@ DEVICE_REGIONS = {
                  "the XLA form",
     LM_GMU: "Gated Memory Unit: input norm, W_1, memory * silu, W_2, "
             "residual",
+    LM_LAYERS: "the family's stage of stacked periods "
+               "(PipelinedLMTrainer's call of family.stage) outside every "
+               "sublayer's own region, which wins as the innermost: what "
+               "the layer / period scans add themselves (stacking and "
+               "slicing of the residuals kept for the backward pass, "
+               "carries, the lm.shared hand-overs) and any operation of "
+               "the family that no sublayer scopes",
+    LM_TICKS: "the GPipe tick scan outside the stage, embedding and head: "
+              "microbatch slicing, the two conds, the carry and the "
+              "ppermute, and in the scan's transpose the sum of the "
+              "parameters' cotangents over the ticks",
     GBDT_HIST: "node x feature x bin histogram build (and its psum)",
     GBDT_SPLIT: "best-split search of one level",
     GBDT_ROUTE: "advance rows to their child nodes",
@@ -675,6 +699,10 @@ REMAT_RESIDUALS = {
 LM_STEP_H2D = "lm.step.h2d"
 LM_STEP_DISPATCH = "lm.step.dispatch"
 LM_STEP_WAIT = "lm.step.wait"
+LM_STEP_GAP = "lm.step.gap"
+# the key of PipelinedLMTrainer's ring of step records
+# (telemetry.profiler.step_records): a record a step, not a metric
+LM_STEP = "lm.step"
 GBDT_FIT_FIT_BINS = "gbdt.fit.fit_bins"
 GBDT_FIT_BIN_DISPATCH = "gbdt.fit.bin_dispatch"
 GBDT_FIT_INIT_SCORE = "gbdt.fit.init_score"
@@ -691,6 +719,12 @@ HOST_REGIONS = {
                       "buffer shows here)",
     LM_STEP_WAIT: "PipelinedLMTrainer.step: float(loss), the wait for the "
                   "device",
+    LM_STEP_GAP: "between two PipelinedLMTrainer.step calls, from the "
+                 "exit of lm.step.wait to the next entry of lm.step.h2d: "
+                 "family.report, the return, the caller's batch making, "
+                 "_check_batch. A noted duration from the second call on "
+                 "and no TraceAnnotation (it crosses a call boundary): in "
+                 "a capture it is the hole between the two annotations",
     GBDT_FIT_FIT_BINS: "fit_booster: quantile bin fit (what data.fit_bins "
                        "times)",
     GBDT_FIT_BIN_DISPATCH: "fit_booster default path: apply_bins_device + "
@@ -911,16 +945,6 @@ FAULT_SITES = {
                            "incumbent is untouched because install_model "
                            "only ever sees a whole fitted model)",
 }
-
-# ------------------------------------------- benchdiff record names
-# Not registry metrics (nothing inc()s or gauges them): these are the
-# canonical names of the JSON records benchdiff gates (ROADMAP D4b).
-# They live here so a writer and the gate assertions share one
-# spelling (docs/observability.md "MULTICHIP rounds gate like bench
-# rounds" describes the record shape benchdiff gates).
-COMM_GBDT_VOTE_OPS = "comm.gbdt.vote.ops"
-COMM_GBDT_VOTE_BYTES = "comm.gbdt.vote.bytes"
-
 
 # ------------------------------------------------- patterned-name helpers
 def data_pool_maps(mode: str) -> str:

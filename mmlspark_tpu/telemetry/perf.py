@@ -939,12 +939,3 @@ def configure_flight_recorder(**kwargs) -> FlightRecorder:
     """Configure the process-default flight recorder (see
     `FlightRecorder.configure`)."""
     return get_flight_recorder().configure(**kwargs)
-
-
-def trigger_bundle(reason: str, verdict: Optional[dict] = None
-                   ) -> Optional[dict]:
-    """Dump a bundle from the process-default recorder — the public
-    one-liner for application code (`trigger_bundle("deploy-canary")`).
-    Same contract as `FlightRecorder.dump`: None when disabled or
-    rate-limited, OSError on an unwritable bundle dir."""
-    return get_flight_recorder().dump(reason, verdict=verdict)

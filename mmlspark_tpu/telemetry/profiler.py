@@ -49,6 +49,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
+import gc
 import glob
 import json
 import os
@@ -58,7 +59,13 @@ import statistics
 import sys
 import threading
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
+
+try:
+    import resource
+    _RUSAGE_WHO = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+except ImportError:      # no getrusage on this platform: the counts read 0
+    resource = None
 
 from ..reliability.metrics import reliability_metrics
 from . import names as tnames
@@ -185,6 +192,8 @@ _INSTRUCTION_RE = re.compile(r"^%?([^\s=]+) = ")
 def _xplane_files(log_dir: str) -> list:
     """The capture's ``*.xplane.pb`` files, newest profile run first (jax
     writes ``plugins/profile/<timestamp>/<host>.xplane.pb``)."""
+    if os.path.isfile(log_dir):      # one recorded file, handed in itself
+        return [log_dir]
     runs = sorted(glob.glob(os.path.join(
         log_dir, "plugins", "profile", "*")), reverse=True)
     for run in runs:
@@ -227,11 +236,12 @@ def self_times(events) -> dict:
 
 
 def parse_trace(log_dir: str, scopes: Optional[dict] = None,
-                registry=None) -> list:
+                registry=None, limit: Optional[int] = _MAX_OP_RECORDS
+                ) -> list:
     """Per-op records from a captured profile's DEVICE planes (the
     ``.xplane.pb`` the profiler writes, line `XLA Ops`):
     ``[{op, region, direction, occurrences, self_time_us}]``, largest
-    self time first, bounded. `op` is the HLO instruction's name; `region`
+    self time first, the first `limit`. `op` is the HLO instruction's name; `region`
     and `direction` come from the scope maps of the registered programs
     (`telemetry.perf.scope_maps()`, or `scopes`, a `{label: scope map}`
     kept from the process that ran, to read a capture elsewhere); an
@@ -278,7 +288,7 @@ def parse_trace(log_dir: str, scopes: Optional[dict] = None,
          ).set_gauge(tnames.TELEMETRY_PROFILE_UNSCOPED_SHARE,
                      unscoped / total)
     records.sort(key=lambda r: (-r["self_time_us"], r["op"]))
-    return records[:_MAX_OP_RECORDS]
+    return records[:limit]
 
 
 def region_totals(records: list) -> dict:
@@ -291,6 +301,68 @@ def region_totals(records: list) -> dict:
         ent["self_time_us"] += float(r.get("self_time_us", 0.0))
         ent["occurrences"] += int(r.get("occurrences", 0))
     return out
+
+
+_MOVERS = ("sort", "gather", "scatter")
+
+
+def by_instruction(log_dir: str, scopes: Optional[dict] = None,
+                   steps: int = 1, top: int = 40) -> list:
+    """A capture read by HLO instruction, as lines to print: the `top`
+    largest instructions with region and direction, every `sort` /
+    `gather` / `scatter` (a scalar one costs a millisecond on a v5e
+    whatever its size: PERF.md section 7), and the regions' totals by
+    direction; ms a step over `steps` traced steps. `log_dir` is a capture
+    directory of `utils.tracing.trace` (or one `.xplane.pb`); `scopes` as
+    for `parse_trace`: None in the process that ran the steps, else the
+    `{label: scope map}` it kept (`telemetry.perf.scope_maps()`)."""
+    records = parse_trace(log_dir, scopes=scopes, limit=None)
+    per = 1e3 * max(int(steps), 1)
+
+    def show(r):
+        return (f"{r['self_time_us'] / per:9.3f} ms/step  "
+                f"x{r['occurrences'] / max(int(steps), 1):<6g} "
+                f"{r['region']:<16} {str(r['direction']):<6} {r['op']}")
+
+    lines = [f"--- {min(top, len(records))} largest of {len(records)} "
+             f"instructions"]
+    lines += [show(r) for r in records[:top]]
+    lines.append("--- every " + " / ".join(_MOVERS))
+    lines += [show(r) for r in records
+              if any(m in r["op"] for m in _MOVERS)]
+    totals: dict = {}
+    for r in records:
+        key = (r["region"], str(r["direction"]))
+        totals[key] = totals.get(key, 0.0) + r["self_time_us"] / per
+    lines.append("--- regions by direction")
+    lines += [f"{ms:9.3f} ms/step  {region:<16} {direction}"
+              for (region, direction), ms in
+              sorted(totals.items(), key=lambda kv: -kv[1])]
+    lines.append(f"{sum(totals.values()):9.3f} ms/step  busy")
+    return lines
+
+
+def main(argv=None) -> int:
+    """python -m mmlspark_tpu.telemetry.profiler <capture> [--scopes
+    <json>] [--steps N] [--top N]: `by_instruction` printed."""
+    import argparse
+    ap = argparse.ArgumentParser(
+        prog="python -m mmlspark_tpu.telemetry.profiler",
+        description="a device capture by HLO instruction")
+    ap.add_argument("capture", help="capture directory of "
+                    "utils.tracing.trace, or one .xplane.pb")
+    ap.add_argument("--scopes", help="JSON {label: scope map} kept from "
+                    "the process that ran (telemetry.perf.scope_maps())")
+    ap.add_argument("--steps", type=int, default=1)
+    ap.add_argument("--top", type=int, default=40)
+    args = ap.parse_args(argv)
+    scopes = None
+    if args.scopes:
+        with open(args.scopes) as f:
+            scopes = json.load(f)
+    print("\n".join(by_instruction(args.capture, scopes, args.steps,
+                                   args.top)))
+    return 0
 
 
 # -------------------------------------------------------- roofline ledger
@@ -314,6 +386,7 @@ class RooflineLedger:
         self._lock = threading.Lock()
         self._host: dict = {}     # region -> [seconds, occurrences, source]
         self._rings: dict = {}    # region -> the last RING single durations
+        self._steps: dict = {}    # name -> the last RING step records
         self._device: dict = {}   # region -> {"self_time_us", "occurrences"}
         self._ops: list = []      # last parsed per-op table (bounded)
         self._costs: dict = {}    # region -> {"flops", "bytes_accessed"}
@@ -344,6 +417,19 @@ class RooflineLedger:
         first."""
         with self._lock:
             return list(self._rings.get(region, ()))
+
+    def note_step(self, name: str, record: "StepRecord") -> None:
+        """Keep one step's record in `name`'s ring."""
+        with self._lock:
+            ring = self._steps.get(name)
+            if ring is None:
+                ring = self._steps[name] = collections.deque(maxlen=RING)
+            ring.append(record)
+
+    def step_records(self, name: str) -> list:
+        """The last `RING` step records kept under `name`, oldest first."""
+        with self._lock:
+            return list(self._steps.get(name, ()))
 
     def region_stats(self, region: str) -> Optional[dict]:
         """{"count", "seconds", "median", "p95"} of a region's host notes
@@ -386,6 +472,7 @@ class RooflineLedger:
         with self._lock:
             self._host.clear()
             self._rings.clear()
+            self._steps.clear()
             self._device.clear()
             self._costs.clear()
             self._ops = []
@@ -508,6 +595,158 @@ def note_region(region: str, seconds: float) -> None:
 def region_stats(region: str) -> Optional[dict]:
     """`RooflineLedger.region_stats` of the process-default ledger."""
     return _default_ledger.region_stats(region)
+
+
+# ------------------------------------------------------------ step records
+PHASES = ("gap", "h2d", "dispatch", "wait")
+SLOW_RATIO = 1.03     # a step is slow over this much of the median period
+SLOW_MIN_STEADY = 8   # non-compiling records a median wants before it judges
+
+
+class StepRecord(NamedTuple):
+    """One step as its host saw it. The four phases are seconds between
+    five clock readings, so they add up to the step's period with nothing
+    outside them; `gap` is None where there was no step before (or, for a
+    reader, where the time before the step is not the loop's: the first
+    step of a window). What stood beside the step is read once, at the
+    record's end, as the difference since the record before it."""
+    gap: Optional[float]
+    h2d: float
+    dispatch: float
+    wait: float
+    compiled: int        # programs the step compiled: never a slow step
+    preemptions: int     # involuntary context switches of the stepping
+    #                      thread (ru_nivcsw): the host ran something else
+    faults: int          # its major page faults (ru_majflt)
+    gc_s: float          # seconds inside generation-2 garbage collections
+
+    @property
+    def period(self) -> float:
+        return (self.gap or 0.0) + self.h2d + self.dispatch + self.wait
+
+
+def slow_steps(records) -> list:
+    """THE slow-step rule (PR 35's `slow_steps`, and the only place it
+    lives): of `records` (oldest first), those that compiled nothing and
+    whose period is over `SLOW_RATIO` of the median period of the
+    non-compiling records given. Each as ``{"index", "record", "period",
+    "median", "loss", "lost"}``: `loss` is the period less that median,
+    and `lost` puts it down to the phases by each phase's excess over its
+    own median (the parts add up to the loss). A record without a gap is
+    given the median one, so it can be slow in its other phases only.
+    Fewer than `SLOW_MIN_STEADY` non-compiling records judge nothing."""
+    steady = [r for r in records if not r.compiled]
+    if len(steady) < SLOW_MIN_STEADY:
+        return []
+    typical = []             # the phases are a record's first fields
+    for i in range(len(PHASES)):
+        seen = [r[i] for r in steady if r[i] is not None]
+        typical.append(statistics.median(seen) if seen else 0.0)
+    took = [[m if t is None else t for t, m in zip(r, typical)]
+            for r in records]
+    periods = [sum(t) for t in took]
+    median = statistics.median(
+        p for p, r in zip(periods, records) if not r.compiled)
+    out = []
+    for index, r in enumerate(records):
+        if r.compiled or periods[index] <= SLOW_RATIO * median:
+            continue
+        loss = periods[index] - median
+        excess = [max(t - m, 0.0) for t, m in zip(took[index], typical)]
+        scale = loss / (sum(excess) or 1.0)
+        out.append({"index": index, "record": r, "period": periods[index],
+                    "median": median, "loss": loss,
+                    "lost": {p: e * scale for p, e in zip(PHASES, excess)}})
+    return out
+
+
+_gc_seconds = 0.0      # inside generation-2 collections, this process
+_gc_started = None
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` entry: time generation-2 collections (the ones that
+    walk every tracked object: tens of ms to seconds on a host that holds
+    a model's arrays); younger generations return at once."""
+    global _gc_seconds, _gc_started
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_started = time.perf_counter()
+    elif _gc_started is not None:
+        _gc_seconds += time.perf_counter() - _gc_started
+        _gc_started = None
+
+
+def _beside():
+    """(involuntary context switches, major faults) of the calling thread
+    so far, and the process's generation-2 collection seconds."""
+    if resource is None:
+        return 0, 0, _gc_seconds
+    use = resource.getrusage(_RUSAGE_WHO)
+    return use.ru_nivcsw, use.ru_majflt, _gc_seconds
+
+
+class StepRecorder:
+    """What `PipelinedLMTrainer.step` keeps of itself: a `StepRecord` a
+    call in the default ledger's ring under "lm.step", the `lm.step.gap`
+    span from the second call on, and the `lm.step.slow` /
+    `lm.step.lost_seconds` counters when `slow_steps` calls the step just
+    recorded slow against the ring as it then stands. `start()` at the
+    step's entry, `mark()` at the two boundaries between its spans,
+    `stop(compiled)` at its end: four clock readings, one `getrusage`,
+    one tuple and one append a step. Always on; `clock` is an attribute
+    for tests to replace."""
+
+    def __init__(self):
+        self.clock = time.perf_counter
+        self._marks: list = []
+        self._gap = None
+        self._end = None         # clock at the last stop()
+        self._was = None         # _beside() at the last stop()
+        self._periods = collections.deque(maxlen=RING)
+
+    def start(self) -> None:
+        now = self.clock()
+        if self._end is None:
+            if _on_gc not in gc.callbacks:
+                gc.callbacks.append(_on_gc)
+            self._was = _beside()
+        else:
+            self._gap = now - self._end
+            note_region(tnames.LM_STEP_GAP, self._gap)
+        self._marks = [now]
+
+    def mark(self) -> None:
+        self._marks.append(self.clock())
+
+    def stop(self, compiled: int = 0) -> StepRecord:
+        t0, t1, t2 = self._marks
+        self._end = now = self.clock()
+        beside = _beside()
+        was, self._was = self._was, beside
+        record = StepRecord(self._gap, t1 - t0, t2 - t1, now - t2,
+                            int(compiled), beside[0] - was[0],
+                            beside[1] - was[1], beside[2] - was[2])
+        _default_ledger.note_step(tnames.LM_STEP, record)
+        if compiled:
+            return record
+        # a cheap gate (one sort of floats) before the rule, which decides
+        self._periods.append(record.period)
+        if record.period > SLOW_RATIO * statistics.median(self._periods):
+            slow = slow_steps(step_records(tnames.LM_STEP))
+            if slow and slow[-1]["record"] is record:
+                reliability_metrics.inc(tnames.LM_STEP_SLOW)
+                reliability_metrics.inc(tnames.LM_STEP_LOST_SECONDS,
+                                        slow[-1]["loss"])
+        return record
+
+
+def step_records(name: str = tnames.LM_STEP) -> list:
+    """The process-default ledger's last `RING` step records under `name`
+    (`PipelinedLMTrainer.step` keeps its own under "lm.step"), oldest
+    first."""
+    return _default_ledger.step_records(name)
 
 
 @contextlib.contextmanager
@@ -766,3 +1005,7 @@ def capture_profile(ms: Optional[float] = None, reason: str = "manual",
     """One-liner timed capture on the process-default session (the
     public application API; triggers use the same path)."""
     return get_profile_session().capture(ms=ms, reason=reason, force=force)
+
+
+if __name__ == "__main__":
+    sys.exit(main())
