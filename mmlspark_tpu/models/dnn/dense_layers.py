@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ...ops import embedding
 from ...parallel import DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS
 from ...parallel.megatron import tp_f, tp_g
 from ...telemetry import names as tnames
@@ -74,7 +75,7 @@ def embed(p, tokens, seq_off):
     """(mb, S) -> (mb, S, d); `seq_off`: this shard's first position."""
     pos = jax.lax.dynamic_slice_in_dim(p["pos"], seq_off, tokens.shape[-1],
                                        axis=0)
-    return p["embed"][tokens] + pos
+    return embedding.lookup(p["embed"], tokens) + pos
 
 
 def _block_attn(x, lp, h: int, dh: int, attention: str = "dense",

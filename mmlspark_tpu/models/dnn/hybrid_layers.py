@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops import embedding
 from ...ops.gated_delta import gated_delta_slab
 from ...ops.gdn_mixer import gdn_finish, gdn_prepare
 from ...parallel import DATA_AXIS, PIPE_AXIS
@@ -85,7 +86,7 @@ def cast(p, dtype):
 
 def embed(p, tokens, seq_off):
     """(mb, S) -> (mb, S, d); positions are rotary, inside attention."""
-    return p["embed"][tokens]
+    return embedding.lookup(p["embed"], tokens)
 
 
 def rms_norm(x, w, eps: float):

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops import embedding
 from ...parallel import DATA_AXIS, PIPE_AXIS
 from ...telemetry import names as tnames
 from .hybrid_layers import (STATS, check_experts, checkpoint_sublayers,
@@ -211,7 +212,7 @@ def layer(h, lp, kind: str, ffn: str, spec, attention: str, remat):
 def embed(p, tokens, seq_off, spec):
     """(mb, S) -> (mb, S, d): the lookup (positions are rotary, inside
     attention), then the leading layers."""
-    h = p["embed"][tokens]
+    h = embedding.lookup(p["embed"], tokens)
     for kind, ffn, lp in zip(spec.leading, spec.leading_ffn, p["leading"]):
         h, _ = layer(h, lp, kind, ffn, spec, "dense", True)
     return h

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ...ops import embedding
 from ...ops.selective_scan import selective_scan
 from ...parallel import DATA_AXIS, PIPE_AXIS
 from ...reliability.metrics import reliability_metrics
@@ -146,7 +147,7 @@ def cast(p, dtype):
 
 def embed(p, tokens, seq_off):
     """(mb, S) -> (mb, S, d): the lookup; the model has no positions."""
-    return p["embed"][tokens]
+    return embedding.lookup(p["embed"], tokens)
 
 
 def causal_conv(u, taps, bias):

@@ -96,6 +96,8 @@ SSM_SCAN_ROUTE_XLA = "ssm.scan.route.xla"
 LM_SHARED_READERS = "lm.shared.readers"
 MOE_EXPERTS_ROUTE_PALLAS = "moe.experts.route.pallas"
 MOE_EXPERTS_ROUTE_XLA = "moe.experts.route.xla"
+EMBED_GRAD_ROUTE_PALLAS = "embed.grad.route.pallas"
+EMBED_GRAD_ROUTE_XLA = "embed.grad.route.xla"
 TELEMETRY_WATCH_TRIPS = "telemetry.watch.trips"
 QUALITY_LABELS_JOINED = "quality.labels.joined"
 QUALITY_LABELS_LATE = "quality.labels.late"
@@ -283,6 +285,18 @@ COUNTERS = {
                            "the XLA while loop (models/dnn/moe.py): off "
                            "the TPU, or shapes the kernels do not fit "
                            "(never silent)",
+    EMBED_GRAD_ROUTE_PALLAS: "embedding lookups whose gradient was traced "
+                             "down the Pallas kernel embed_grad "
+                             "(ops/embedding.py: one sort of the ids, a "
+                             "one-hot sum a vocabulary block in VMEM): a "
+                             "TPU, a bfloat16 or float32 table whose width "
+                             "is a multiple of 128 and int32 ids, or a "
+                             "test's own interpret-mode call; counted at "
+                             "trace time, once a call of embedding.lookup",
+    EMBED_GRAD_ROUTE_XLA: "embedding lookups whose gradient was traced as "
+                          "XLA's scatter-add (the gather's own transpose): "
+                          "off the TPU, or tables the kernel does not fit "
+                          "(never silent)",
     LM_SHARED_READERS: "sublayers of a traced state-space model "
                        "(models/dnn/ssm_layers.py) that read an array an "
                        "earlier layer made: a Gated Memory Unit reads the "

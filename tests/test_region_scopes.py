@@ -279,9 +279,10 @@ def test_lm_step_program_is_for_the_last_shape_and_holds_no_trainer():
     assert "s32[2,8]" in text and "s32[2,16]" not in text
     counts = tperf.region_instruction_counts(tperf.scope_map(text))
     # dense attention: every LM region but the flash call (and no cast
-    # in float32)
+    # in float32), with the two scan shells' own (PR 37)
     assert set(counts) == {tnames.LM_EMBED, tnames.LM_ATTN, tnames.LM_MLP,
-                           tnames.LM_HEAD, tnames.LM_OPT}
+                           tnames.LM_HEAD, tnames.LM_OPT, tnames.LM_LAYERS,
+                           tnames.LM_TICKS}
 
 
 def test_executable_analysis_counts_instructions_per_region(lm):
