@@ -8,6 +8,8 @@ families".
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,10 +80,75 @@ def embed(p, tokens, seq_off):
     return embedding.lookup(p["embed"], tokens) + pos
 
 
+def _norm(x, ln, tp_axis):
+    y = _layer_norm(x, ln)
+    return y if tp_axis is None else tp_f(y, tp_axis)
+
+
+def _matmuls(y, ws):
+    return tuple(y @ w for w in ws)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def normed_matmuls(x, ln, ws, tp_axis=None):
+    """(ln(x) @ w for w in ws) on (mb, S, d) slabs, whose backward
+    recomputes ln(x) from x: reverse mode would keep ln(x) and three
+    float32 arrays of x's shape (x - mean, the normalised x) for every
+    layer of the scan, 14 bytes a number beside x's 2 (docs/dnn.md "What a
+    dense layer keeps"). `tp_axis`: the f operator between the norm and
+    the column-parallel matrices."""
+    return _matmuls(_norm(x, ln, tp_axis), ws)
+
+
+def _normed_matmuls_fwd(x, ln, ws, tp_axis):
+    return normed_matmuls(x, ln, ws, tp_axis), (x, ln, ws)
+
+
+def _normed_matmuls_bwd(tp_axis, res, cts):
+    x, ln, ws = res
+    y, norm_vjp = jax.vjp(lambda x, ln: _norm(x, ln, tp_axis), x, ln)
+    # ln(x) made once, in a pass of its own: fused into each weight
+    # gradient's product as a prologue it slows every one of them
+    y = jax.lax.optimization_barrier(y)
+    dy, dws = jax.vjp(_matmuls, y, ws)[1](cts)
+    return (*norm_vjp(dy), dws)
+
+
+normed_matmuls.defvjp(_normed_matmuls_fwd, _normed_matmuls_bwd)
+
+
+def _attend(q, k, v, dh: int, attention: str, cp_axis):
+    """Causal attention of one sequence's (S, h, dh) q, k, v. The kernels'
+    region is opened HERE, inside the caller's `vmap`: an instruction is
+    named after the innermost element of its name stack, and the kernels
+    must stay `flash_fwd` / `flash_dq` / `flash_dkv`, not
+    `vmap_flash_fwd_`, for the readers that find them by name."""
+    if cp_axis is not None:
+        # context parallelism: the sequence is SHARDED over cp_axis; ring
+        # attention rotates K/V blocks around that axis with the global
+        # causal geometry carried by block offsets (attention="flash":
+        # each block through the Pallas kernel)
+        from ...parallel.ring_attention import _ring_attention_sharded
+        with jax.named_scope(tnames.LM_ATTN_FLASH):
+            return _ring_attention_sharded(
+                q, k, v, axis_name=cp_axis, causal=True,
+                scale=1.0 / float(np.sqrt(dh)),
+                block_impl="flash" if attention == "flash" else "dense")
+    if attention == "flash":
+        from ...ops.flash_attention import flash_attention
+        with jax.named_scope(tnames.LM_ATTN_FLASH):
+            return flash_attention(q, k, v, causal=True)
+    from ...parallel.ring_attention import reference_attention
+    return reference_attention(q, k, v, causal=True)
+
+
 def _block_attn(x, lp, h: int, dh: int, attention: str = "dense",
                 tp_axis=None, cp_axis=None):
-    """Attention sublayer of one transformer block on a (S, d) sequence:
-    ln1 -> qkv -> (ring/flash/dense) attention -> wo -> residual add.
+    """Attention sublayer of one transformer block on (mb, S, d) slabs:
+    ln1 -> qkv -> (ring/flash/dense) attention -> wo -> residual add. Only
+    the attention itself goes a sequence at a time (`jax.vmap`): the
+    kernels then read q, k, v (mb, h, S, dh) as the projections wrote them
+    (docs/dnn.md "What a dense layer keeps").
 
     attention="flash" routes through the Pallas kernel (with its flash
     BACKWARD — O(block) training memory): legal here because shard_map
@@ -93,46 +160,24 @@ def _block_attn(x, lp, h: int, dh: int, attention: str = "dense",
     leaves arrive column-sliced (wq/wk/wv/w1 on outputs, wo/w2 on inputs
     — h must be the LOCAL head count), activations stay replicated, and
     one psum over tp_axis closes each of the two row-parallel matmuls."""
-    from ...parallel.ring_attention import reference_attention
-
-    seq, d = x.shape
+    mb, seq, _ = x.shape
     with jax.named_scope(tnames.LM_ATTN):
-        y = _layer_norm(x, lp["ln1"])
-        if tp_axis is not None:
-            y = tp_f(y, tp_axis)
-        q = (y @ lp["wq"]).reshape(seq, h, dh)
-        k = (y @ lp["wk"]).reshape(seq, h, dh)
-        v = (y @ lp["wv"]).reshape(seq, h, dh)
-        if cp_axis is not None:
-            # context parallelism: the sequence is SHARDED over cp_axis;
-            # ring attention rotates K/V blocks around that axis with the
-            # global causal geometry carried by block offsets
-            # (attention="flash": each block through the Pallas kernel)
-            from ...parallel.ring_attention import _ring_attention_sharded
-            with jax.named_scope(tnames.LM_ATTN_FLASH):
-                a = _ring_attention_sharded(
-                    q, k, v, axis_name=cp_axis, causal=True,
-                    scale=1.0 / float(np.sqrt(dh)),
-                    block_impl="flash" if attention == "flash" else "dense")
-        elif attention == "flash":
-            from ...ops.flash_attention import flash_attention
-            with jax.named_scope(tnames.LM_ATTN_FLASH):
-                a = flash_attention(q, k, v, causal=True)
-        else:
-            a = reference_attention(q, k, v, causal=True)
-        att = a.reshape(seq, h * dh) @ lp["wo"]
+        q, k, v = (t.reshape(mb, seq, h, dh) for t in normed_matmuls(
+            x, lp["ln1"], (lp["wq"], lp["wk"], lp["wv"]), tp_axis))
+        a = jax.vmap(lambda q, k, v: _attend(
+            q, k, v, dh, attention, cp_axis))(q, k, v)
+        att = a.reshape(mb, seq, h * dh) @ lp["wo"]
         if tp_axis is not None:
             att = tp_g(att, tp_axis)
         return x + att
 
 
 def _block_ff(x, lp, tp_axis=None):
-    """Feed-forward sublayer: ln2 -> gelu MLP -> residual add."""
+    """Feed-forward sublayer on (mb, S, d) slabs: ln2 -> gelu MLP ->
+    residual add."""
     with jax.named_scope(tnames.LM_MLP):
-        y = _layer_norm(x, lp["ln2"])
-        if tp_axis is not None:
-            y = tp_f(y, tp_axis)
-        ff = jax.nn.gelu(y @ lp["w1"] + lp["b1"]) @ lp["w2"]
+        u, = normed_matmuls(x, lp["ln2"], (lp["w1"],), tp_axis)
+        ff = jax.nn.gelu(u + lp["b1"]) @ lp["w2"]
         if tp_axis is not None:
             ff = tp_g(ff, tp_axis)
         # b2 is replicated across tp: OUTSIDE the psum or it counts tp x
@@ -146,16 +191,15 @@ def stage(x, layers, spec, attention: str, remat, tp_axis=None,
     remat=True (= "full") has the backward recompute a block from its
     (mb, S, d) input instead of keeping qkv/scores/gelu residents;
     remat="save_attn" recomputes only the FF sublayer and stores the
-    attention sublayer's residuals (q/k/v/out/lse), because re-running the
-    flash FORWARD is the costliest thing to recompute at long context
-    (parity: test_remat_is_loss_invariant)."""
+    attention sublayer's residuals (x, the kernels' q/k/v/out/lse, the wo
+    input), because re-running the flash FORWARD is the costliest thing to
+    recompute at long context (parity: test_remat_is_loss_invariant)."""
     dh = spec.d_model // spec.n_heads
     h_loc = layers["wq"].shape[-1] // dh     # local heads per model shard
-    attn = lambda h_x, lp: jax.vmap(lambda xx: _block_attn(
-        xx, lp, h_loc, dh, attention=attention,
-        tp_axis=tp_axis, cp_axis=cp_axis))(h_x)
-    ff = lambda h_x, lp: jax.vmap(lambda xx: _block_ff(
-        xx, lp, tp_axis=tp_axis))(h_x)
+    attn = lambda h_x, lp: _block_attn(h_x, lp, h_loc, dh,
+                                       attention=attention, tp_axis=tp_axis,
+                                       cp_axis=cp_axis)
+    ff = lambda h_x, lp: _block_ff(h_x, lp, tp_axis=tp_axis)
     if remat == "save_attn":
         ff = jax.checkpoint(ff)
     blk = lambda h_x, lp: ff(attn(h_x, lp), lp)

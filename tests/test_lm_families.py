@@ -296,3 +296,70 @@ def test_the_compiled_step_sorts_and_chooses_once_an_expert_layer(family,
         trainer._to_device(toy_tokens())).compile().as_text()
     assert len(re.findall(r"\bsort\(", text)) == 2
     assert len(re.findall(r'custom_call_target="TopK"', text)) == 2
+
+
+# ------------------------------------------------- what a dense layer keeps
+# (`dense_layers.stage`: docs/dnn.md "What a dense layer keeps")
+def dense_layer(d=1024, heads=16, d_ff=4096, seq=128, n_layers=1):
+    """(a function (h, lp, remat) -> h of `n_layers` dense layers through
+    the family's stage, h (2, seq, d) float32, lp): gpt2-medium's widths
+    by default."""
+    from mmlspark_tpu.models.dnn import dense_layers
+    spec = gpt2_spec(64, d, heads, n_layers, d_ff, seq)
+    lp = jax.tree_util.tree_map(jnp.asarray,
+                                dense_layers.init(spec, 0)["layers"])
+    h = jnp.asarray(np.random.default_rng(0).standard_normal((2, seq, d)),
+                    jnp.float32)
+
+    def layer(h, lp, remat):
+        return dense_layers.stage(h, lp, spec, "flash", remat)[0]
+    return layer, h, lp, spec
+
+
+@pytest.mark.parametrize("remat,slabs,kernels", [
+    (True, 1, 0), ("full", 1, 0), ("save_attn", 3, 5)])
+def test_a_dense_layer_keeps_its_slabs_and_the_kernels_operands(remat, slabs,
+                                                                kernels):
+    """At gpt2-medium's widths (d 1024, 16 heads of 64; 2 x 128 tokens) a
+    layer keeps (mb, S, d) slabs, lane-dense, and under "save_attn" the
+    five arrays the flash kernels read back in their own layout (q, k, v,
+    out as (mb, h, S, dh), the row sums): eight arrays, where reverse
+    mode's own residuals of the layer norm made fifteen (three of them
+    float32 copies of x). No (mb, S, d_ff) activation is kept: the
+    feed-forward sublayer is recomputed."""
+    layer, h, lp, _ = dense_layer()
+    residuals = stored(layer, h, lp, remat)
+    shapes = [shape[1:] for shape, _ in residuals]     # (layers, ...) stacks
+    slab = [s for s in shapes if s == h.shape]
+    kernel = [s for s in shapes if s in ((2, 16, 128, 64), (2, 16, 128, 1))]
+    assert (len(slab), len(kernel)) == (slabs, kernels), shapes
+    assert len(shapes) == slabs + kernels
+    assert all(s[-1] % 128 == 0 for s in slab)
+
+
+@pytest.mark.parametrize("remat", [False, True, "save_attn"])
+def test_the_dense_stage_is_a_loop_over_its_sublayers(remat):
+    """The stage's loss and gradients (input and every layer leaf) equal a
+    plain Python loop of `_block_attn` then `_block_ff` over the layers,
+    float32, whatever `remat` keeps."""
+    from mmlspark_tpu.models.dnn import dense_layers
+    layer, h, lp, spec = dense_layer(d=128, heads=2, d_ff=256, seq=128,
+                                     n_layers=2)
+    w = jnp.asarray(np.random.default_rng(1).standard_normal(h.shape),
+                    jnp.float32)
+
+    def looped(h, lp):
+        for i in range(spec.n_periods):
+            lp_i = jax.tree_util.tree_map(lambda a: a[i], lp)
+            h = dense_layers._block_attn(h, lp_i, 2, 64, attention="flash")
+            h = dense_layers._block_ff(h, lp_i)
+        return jnp.sum(h * w)
+
+    want, want_g = jax.value_and_grad(looped, argnums=(0, 1))(h, lp)
+    got, got_g = jax.value_and_grad(
+        lambda h, lp: jnp.sum(layer(h, lp, remat) * w), argnums=(0, 1))(h, lp)
+    assert abs(float(got - want)) <= 1e-6 * abs(float(want))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_g),
+                            jax.tree_util.tree_leaves(want_g)):
+        apart = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+        assert apart <= 1e-6, (jax.tree_util.keystr(path), apart)
